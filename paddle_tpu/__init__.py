@@ -10,7 +10,6 @@ from __future__ import annotations
 
 __version__ = "0.1.0"
 
-from . import jax_compat  # noqa: F401  (must precede any jax-API use)
 from . import core
 from .core import (CPUPlace, CUDAPinnedPlace, CUDAPlace, TPUPlace,
                    XPUPlace, get_device,

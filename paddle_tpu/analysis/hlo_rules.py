@@ -37,6 +37,7 @@ produced it.
 """
 from __future__ import annotations
 
+import re
 from typing import List
 
 from .engine import ProgramAudit, _SHAPE_RE, finding, rule
@@ -110,9 +111,21 @@ def baked_constants(audit: ProgramAudit) -> List[Finding]:
     return out
 
 
-_OPERAND_DTYPE_RE = _SHAPE_RE  # first shape in the operand segment
+_OPERAND_NAME_RE = re.compile(r"%?([\w.\-]+)")
 
 _LOW_PRECISION = ("bf16", "f16")
+
+
+def _first_operand_dtype(ins, dtype_of):
+    """dtype of an instruction's first operand. Older XLA printed
+    operand shapes inline (``convert(bf16[8,8]{1,0} %x)``); the
+    installed one prints names only (``convert(%x)``), so the dtype is
+    looked up at the operand's defining instruction."""
+    m = _SHAPE_RE.search(ins.operands)
+    if m:
+        return m.group(1)
+    m = _OPERAND_NAME_RE.search(ins.operands)
+    return dtype_of.get(m.group(1)) if m else None
 
 
 @rule("dtype-promotion")
@@ -122,6 +135,7 @@ def dtype_promotion(audit: ProgramAudit) -> List[Finding]:
     contract — loss_scale, optimizer, grad_sync — are exempt)."""
     cfg = audit.config
     out: List[Finding] = []
+    dtype_of = {i.name: i.dtype for i in audit.instructions()}
     for ins in audit.instructions():
         if ins.opcode != "convert":
             continue
@@ -129,15 +143,15 @@ def dtype_promotion(audit: ProgramAudit) -> List[Finding]:
             continue
         if ins.nbytes < cfg.promotion_bytes:
             continue
-        m = _OPERAND_DTYPE_RE.search(ins.operands)
-        if not m or m.group(1) not in _LOW_PRECISION:
+        src = _first_operand_dtype(ins, dtype_of)
+        if src not in _LOW_PRECISION:
             continue
         sc = ins.scope()
         if sc in cfg.amp_exempt_scopes:
             continue
         out.append(finding(
             ins.location,
-            f"{m.group(1)} -> {ins.dtype} upcast materializes "
+            f"{src} -> {ins.dtype} upcast materializes "
             f"{_mib(ins.nbytes)} "
             f"({ins.dtype}{list(ins.dims)}) inside "
             f"{'scope ' + sc if sc else 'an unattributed region'} — "

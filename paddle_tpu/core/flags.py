@@ -114,55 +114,25 @@ define_flag("tpu_matmul_precision", "default",
 define_flag("use_bf16_compute", True,
             "Prefer bfloat16 compute in AMP lists (TPU MXU native).")
 define_flag("log_level", 0, "Verbosity (glog VLOG analogue).")
-define_flag("compile_cache_dir",
-            os.environ.get("PD_COMPILE_CACHE_DIR", ""),
-            "Persistent XLA compilation-cache directory (PERF_PLAN "
-            "staged lever #6: cached executables give the 20-40 s "
-            "per-program compile back to reruns). Set via "
-            "PD_COMPILE_CACHE_DIR or FLAGS_compile_cache_dir; empty "
-            "disables. Applied to jax.config at import when the env "
-            "is set, or on demand via apply_compile_cache().")
 
 
-def apply_compile_cache(path: Optional[str] = None,
-                        min_compile_secs: Optional[float] = None) -> bool:
-    """Point jax's persistent compilation cache at the configured
-    directory. Returns True when a cache was enabled. `path` overrides
-    the flag; `min_compile_secs` optionally lowers the admission
-    threshold (jax default only persists compiles slower than ~1 s —
-    CPU test programs need 0.0 to observe hits). Cache *hits* are
-    observable through the sentinel's jax.monitoring listener
-    (jax.compile_cache.requests / jax.compile_cache.hits counters)."""
-    p = path if path is not None else (
-        flag_value("compile_cache_dir")
-        # env read again at call time: entry points (bench.py) set
-        # PD_COMPILE_CACHE_DIR after this module's import snapshot
-        or os.environ.get("PD_COMPILE_CACHE_DIR", ""))
-    if not p:
-        return False
+def apply_compile_cache() -> str:
+    """Place jax's persistent compilation cache, by ONE rule, and
+    return the directory in use. Where ``JAX_COMPILATION_CACHE_DIR`` is
+    set jax already has it, and no ``jax_compilation_cache_dir`` update
+    is made here at all; otherwise the cache is ``<checkout>/.jax_cache``
+    — a fixed path (never a temporary, pid- or time-derived one), since
+    the next run only finds what this one compiled if it looks in the
+    same place. Entry points (chip_smoke.py, bench.py, the examples)
+    call it before their first compile; cache hits are observable
+    through the sentinel's jax.monitoring listener
+    (``jax.compile_cache.requests`` / ``jax.compile_cache.hits``)."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    checkout = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    path = os.path.join(checkout, ".jax_cache")
     import jax
-    jax.config.update("jax_compilation_cache_dir", p)
-    # jax latches the cache-disabled verdict at the FIRST compile
-    # (compilation_cache._cache_checked/_cache_initialized): enabling
-    # the dir after anything compiled leaves a permanently-None cache
-    # that silently never reads or writes. Reset the latches so
-    # mid-process enabling (bench probes compile before main() flips
-    # the flag) actually takes effect.
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover — internal API drift
-        pass
-    if min_compile_secs is not None:
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              float(min_compile_secs))
-        except Exception:  # pragma: no cover — older config name
-            pass
-    return True
-
-
-if os.environ.get("PD_COMPILE_CACHE_DIR"):
-    # startup wiring: the env var alone turns the cache on for every
-    # entry point (bench, tools, user scripts) without code changes
-    apply_compile_cache()
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -101,6 +101,33 @@ def current_axis_name(preferred: str = None) -> Optional[str]:
     return axes[0]
 
 
+# -- step mesh: the mesh a GSPMD-sharded step is being traced for ------------
+
+class step_mesh:
+    """Marks a region as tracing one jit whose arrays are GSPMD-sharded
+    over `mesh`, batch dim over `batch_axes` (static.TrainStep enters
+    it around the forward). F.flash_attention hands it to its op, which
+    shard_maps the Mosaic kernel — something the compiler cannot
+    partition by itself — over the same mesh; everything else ignores
+    it."""
+
+    def __init__(self, mesh: Mesh, batch_axes: Sequence[str]):
+        self.mesh = mesh
+        self.batch_axes = tuple(batch_axes)
+
+    def __enter__(self):
+        self._prev = getattr(_state, "step_mesh", None)
+        _state.step_mesh = self
+        return self
+
+    def __exit__(self, *exc):
+        _state.step_mesh = self._prev
+
+
+def current_step_mesh() -> Optional[step_mesh]:
+    return getattr(_state, "step_mesh", None)
+
+
 # -- process-level rank info (multi-host; single-host => rank 0/1) ----------
 
 def get_rank() -> int:

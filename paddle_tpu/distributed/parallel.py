@@ -36,8 +36,6 @@ def init_parallel_env(mesh_shape=None):
     nproc = int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
     rank = int(os.environ.get("PADDLE_TRAINER_ID", "0"))
     if coord and nproc > 1 and jax.process_count() == 1:
-        from ..jax_compat import enable_cpu_collectives
-        enable_cpu_collectives()  # older-jax CPU meshes need gloo
         port = os.environ.get("MASTER_PORT", "8476")
         jax.distributed.initialize(f"{coord}:{port}", num_processes=nproc,
                                    process_id=rank)

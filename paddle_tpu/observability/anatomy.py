@@ -157,7 +157,7 @@ def scope_of_op_name(op_name: str,
 
 # one instruction line: `  [ROOT] %name = <type> opcode(...), ...`
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*"
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
     r"(?P<type>\(?[a-z0-9]+\[[\d,]*\][^\s]*)\s+"
     r"(?P<op>[\w\-]+)\(")
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([\d,]*)\]")
@@ -224,20 +224,28 @@ def _prod(dims) -> float:
     return out
 
 
-def _operand_shapes(line: str, op: str):
-    """Shapes inside the operand parens of `op(...)` on this line."""
+def _operand_shapes(line: str, op: str, dims_of: Dict[str, tuple]):
+    """Shapes of the operands of `op(...)` on this line. Older XLA
+    printed them inline (``dot(f32[8,4]{1,0} %a, ...)``); the installed
+    one prints names only (``dot(%a, %b)``), which resolve through
+    `dims_of` — every instruction's result dims by name."""
     i = line.find(op + "(")
     if i < 0:
         return []
     j = line.find(")", i)
     seg = line[i + len(op) + 1: j if j > 0 else len(line)]
-    return [tuple(int(d) for d in m.group(2).split(",") if d)
-            for m in _SHAPE_RE.finditer(seg)]
+    inline = [tuple(int(d) for d in m.group(2).split(",") if d)
+              for m in _SHAPE_RE.finditer(seg)]
+    if inline:
+        return inline
+    names = [t.strip().lstrip("%") for t in seg.split(",")]
+    return [dims_of[n] for n in names if n in dims_of]
 
 
-def _instr_flops(op: str, line: str, result_dims) -> float:
+def _instr_flops(op: str, line: str, result_dims,
+                 dims_of: Dict[str, tuple]) -> float:
     if op == "dot":
-        ops = _operand_shapes(line, "dot")
+        ops = _operand_shapes(line, "dot", dims_of)
         m = _LHS_CONTRACT_RE.search(line)
         if ops and m is not None:
             lhs = ops[0]
@@ -246,7 +254,7 @@ def _instr_flops(op: str, line: str, result_dims) -> float:
             return 2.0 * _prod(result_dims) * contracted
         return 2.0 * _prod(result_dims)
     if op == "convolution":
-        ops = _operand_shapes(line, "convolution")
+        ops = _operand_shapes(line, "convolution", dims_of)
         if len(ops) >= 2:
             kernel = ops[1]
             groups = 1
@@ -263,7 +271,7 @@ def _instr_flops(op: str, line: str, result_dims) -> float:
             return 2.0 * _prod(result_dims) * per_out
         return 2.0 * _prod(result_dims)
     if op in ("reduce", "reduce-window"):
-        ops = _operand_shapes(line, op)
+        ops = _operand_shapes(line, op, dims_of)
         return _prod(ops[0]) if ops else _prod(result_dims)
     if op in _ELEMENTWISE:
         return _prod(result_dims)
@@ -286,17 +294,18 @@ def attribute_hlo_text(text: str,
     per: Dict[str, Dict[str, float]] = {}
     total_flops = 0.0
     total_bytes = 0.0
-    for line in text.splitlines():
-        m = _INSTR_RE.match(line)
-        if not m:
-            continue
+    parsed = [(line, m) for line in text.splitlines()
+              for m in (_INSTR_RE.match(line),) if m]
+    dims_of = {m.group("name"): _first_shape(m.group("type"))[1]
+               for _, m in parsed}
+    for line, m in parsed:
         op = m.group("op")
         if op in _CONTAINERS:
             continue
         dtype, dims = _first_shape(m.group("type"))
         if dtype is None:
             continue
-        flops = _instr_flops(op, line, dims)
+        flops = _instr_flops(op, line, dims, dims_of)
         nbytes = _prod(dims) * _ITEMSIZE.get(dtype, 4)
         meta = _META_RE.search(line)
         sc = scope_of_op_name(meta.group(1), scopes) if meta else None
@@ -346,14 +355,12 @@ def compile_uncached(lowered):
     op_names silently attribute everything to ``unattributed`` (found
     live: a stale .jax_cache from a pre-anatomy round zeroed bench's
     share table). Attribution pays one fresh compile instead; the
-    restore path resets jax's cache latches (the core.flags
-    apply_compile_cache lesson) so the trainer's cache keeps working.
+    restore path resets jax's cache latches (jax latches the
+    cache-disabled verdict at the first compile it sees) so the
+    trainer's cache keeps working.
     """
     import jax
-    try:
-        prev = bool(jax.config.jax_enable_compilation_cache)
-    except AttributeError:  # pragma: no cover — very old runtimes
-        return lowered.compile()
+    prev = bool(jax.config.jax_enable_compilation_cache)
     try:
         jax.config.update("jax_enable_compilation_cache", False)
         return lowered.compile()

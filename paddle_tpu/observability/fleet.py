@@ -4,8 +4,8 @@ Each host's metrics registry sees only its own process. For pod-level
 health (total examples/sec, total collective bytes, did ANY host
 recompile) the snapshots must be reduced across hosts. This rides the
 same multi-controller runtime the trainers already stand up
-(jax.distributed.initialize + the gloo CPU collectives
-jax_compat.enable_cpu_collectives scopes in): snapshots are serialized
+(jax.distributed.initialize + jax's gloo CPU collectives): snapshots
+are serialized
 to JSON, padded to the pod-wide max length, all-gathered through
 jax.experimental.multihost_utils (device collectives under the hood —
 no side-channel socket protocol to operate), and merged:

@@ -9,59 +9,38 @@ public TPU specs table (override: PD_PEAK_FLOPS), times the device
 count the executable spans.
 
 ``ThroughputMeter`` is the per-step accumulator engines/callbacks feed;
-it publishes ``throughput.examples_per_sec``, ``throughput.mfu`` and
+it publishes ``throughput.examples_per_sec``, ``throughput.mfu`` (on an
+accelerator only — the CPU has no peak, so no MFU) and
 ``throughput.model_flops_per_step`` gauges plus an
 ``examples_total`` counter through the metrics registry.
 """
 from __future__ import annotations
 
-import logging
 import os
 import time
-from typing import Optional, Set
+from typing import Optional
 
 from . import metrics
-
-logger = logging.getLogger("paddle_tpu.observability")
 
 __all__ = ["chip_peak_flops", "flops_of_compiled", "step_flops",
            "ThroughputMeter", "PEAK_FLOPS_BY_KIND"]
 
-# bf16 peak FLOP/s per chip by TPU generation (public cloud specs);
-# override with PD_PEAK_FLOPS for unlisted hardware. bench.py imports
-# THIS table — one copy of the hardware truth.
+# bf16 peak FLOP/s per chip by TPU generation (public cloud specs;
+# "TPU v5 lite" is what a v5e reports). PD_PEAK_FLOPS overrides for
+# unlisted hardware. ONE copy of the hardware truth.
 PEAK_FLOPS_BY_KIND = {
     "TPU v2": 45e12, "TPU v3": 123e12, "TPU v4": 275e12,
     "TPU v5 lite": 197e12, "TPU v5e": 197e12, "TPU v5p": 459e12,
     "TPU v6 lite": 918e12, "TPU v6e": 918e12,
 }
 
-# CPU fallback: order-of-magnitude per-core AVX f32 peak so the demo /
-# CI path still yields a finite MFU *estimate*; real MFU numbers come
-# from TPU runs (or PD_PEAK_FLOPS pinning the truth for other chips).
-_CPU_CORE_PEAK = 5e10
 
-# the v4-class default assumed for accelerators the spec table can't
-# name — every use is LOUD (warn-once + always-on counter below): an
-# MFU built on a guessed denominator is off by up to 3.3x across the
-# table, and a silent guess skews hardware receipts undetectably
-_UNKNOWN_CHIP_GUESS = 275e12
-_warned_kinds: Set[str] = set()
-
-
-def chip_peak_flops(device=None, fallback: Optional[float] = None) -> float:
-    """Peak FLOP/s for one device: PD_PEAK_FLOPS > spec table >
-    `fallback` when given (bench.py pins 275e12 so CPU BENCH artifacts
-    stay comparable across rounds) > CPU core estimate > v4-class
-    default for unidentifiable accelerators. The ONE lookup both the
-    MFU reporter and bench.py use.
-
-    The unidentifiable-accelerator guess is never silent: it bumps the
-    always-on ``mfu.peak_flops_guess_total`` counter (rides every
-    exporter whether or not the metrics gate is up) and logs one
-    warning per unknown device_kind, naming the kind and the override
-    knob — a skewed MFU receipt must be traceable to its denominator.
-    """
+def chip_peak_flops(device=None) -> Optional[float]:
+    """Peak FLOP/s for one device: PD_PEAK_FLOPS > spec table. The
+    CPU has no peak here — None, and callers report no MFU: a
+    utilization is a device number. An accelerator whose device_kind
+    the table cannot name is an error, never a guess — an MFU built on
+    a guessed denominator is off by up to 3.3x across the table."""
     env = os.environ.get("PD_PEAK_FLOPS")
     if env:
         return float(env)
@@ -72,20 +51,12 @@ def chip_peak_flops(device=None, fallback: Optional[float] = None) -> float:
     for k, v in PEAK_FLOPS_BY_KIND.items():
         if kind.lower().startswith(k.lower()):
             return v
-    if fallback is not None:
-        return fallback
     if getattr(device, "platform", "") == "cpu":
-        return _CPU_CORE_PEAK * (os.cpu_count() or 1)
-    metrics.counter("mfu.peak_flops_guess_total", _always=True).add(1)
-    if kind not in _warned_kinds:
-        _warned_kinds.add(kind)
-        logger.warning(
-            "chip_peak_flops: unrecognized device_kind %r — assuming "
-            "v4-class %.0e FLOP/s; MFU figures from this device are "
-            "estimates. Pin the truth with PD_PEAK_FLOPS=<per-chip "
-            "peak> (or extend PEAK_FLOPS_BY_KIND).",
-            kind, _UNKNOWN_CHIP_GUESS)
-    return _UNKNOWN_CHIP_GUESS
+        return None
+    raise ValueError(
+        f"chip_peak_flops: device_kind {kind!r} is not in "
+        "PEAK_FLOPS_BY_KIND — add its published per-chip peak there "
+        "(or pin PD_PEAK_FLOPS=<FLOP/s>)")
 
 
 def flops_of_compiled(compiled) -> float:
@@ -143,7 +114,9 @@ class ThroughputMeter:
                 n_devices = len(devs)
             if peak_flops is None:
                 peak_flops = chip_peak_flops(devs[0])
-        self.peak_flops_total = float(peak_flops) * int(n_devices)
+        # None on the CPU: examples/sec still reports, MFU does not
+        self.peak_flops_total = (None if peak_flops is None
+                                 else float(peak_flops) * int(n_devices))
         self.n_devices = int(n_devices)
         self._steps_s = []
         self._t_last = None
@@ -181,7 +154,10 @@ class ThroughputMeter:
         med = self._median_step()
         return self.examples_per_step / med if med > 0 else -1.0
 
-    def mfu(self) -> float:
+    def mfu(self) -> Optional[float]:
+        """None where there is no peak to divide by (the CPU)."""
+        if self.peak_flops_total is None:
+            return None
         med = self._median_step()
         if med <= 0 or not self.flops_per_step \
                 or self.flops_per_step <= 0:
@@ -192,14 +168,16 @@ class ThroughputMeter:
         """Publish gauges and return the rollup dict."""
         eps = self.examples_per_sec()
         mfu = self.mfu()
+        if mfu is not None:
+            mfu = round(mfu, 6)
+            metrics.gauge("throughput.mfu").set(mfu)
         metrics.gauge("throughput.examples_per_sec").set(round(eps, 3))
-        metrics.gauge("throughput.mfu").set(round(mfu, 6))
         if self.flops_per_step and self.flops_per_step > 0:
             metrics.gauge("throughput.model_flops_per_step").set(
                 float(self.flops_per_step))
         return {
             "examples_per_sec": round(eps, 3),
-            "mfu": round(mfu, 6),
+            "mfu": mfu,
             "model_flops_per_step": self.flops_per_step,
             "peak_flops_total": self.peak_flops_total,
             "n_devices": self.n_devices,

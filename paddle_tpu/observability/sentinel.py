@@ -166,7 +166,7 @@ _COMPILE_EVENT_PREFIX = "/jax/core/compile"
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # persistent-cache bookkeeping (jax _src/compiler.py): excluded from
 # the compile odometer above, but counted on their OWN meters — the
-# hit ratio is the receipt that PD_COMPILE_CACHE_DIR actually pays
+# hit ratio is the receipt that the persistent cache actually pays
 _CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 

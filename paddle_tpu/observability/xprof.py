@@ -20,8 +20,7 @@ kernel names back to the anatomy scope taxonomy, and produces
     whether bucketed grad sync actually overlaps backward.
 
 One parser, one glob contract: ``find_xplane`` owns the
-``**/*.xplane.pb`` discovery every consumer previously inlined
-(tools/tpu_first_light.py's PROFILE_SNIPPET now routes here, like
+``**/*.xplane.pb`` discovery every consumer previously inlined (like
 PR 4 unified dump paths through ``flight_recorder.default_dump_path``).
 Inputs accepted: a profiler logdir, a ``.xplane.pb`` file (parsed via
 ``jax.profiler.ProfileData`` when this runtime ships it), or a chrome
@@ -350,14 +349,12 @@ def publish(result: dict, prefix: str = "anatomy"):
 
 
 # ---------------------------------------------------------------------------
-# the first-light top-list (supersedes the inline one-off)
+# the top-list of device ops
 # ---------------------------------------------------------------------------
 
 def top_ops(events: List[dict], n: int = 15,
             steps: int = 1) -> List[Tuple[str, float]]:
-    """Heaviest device ops as (name, ms/step) — what
-    tools/tpu_first_light.py's PROFILE_SNIPPET used to compute inline
-    from raw ProfileData planes."""
+    """Heaviest device ops as (name, ms/step)."""
     steps = max(int(steps), 1)
     tot: Dict[str, float] = {}
     for ev in events:

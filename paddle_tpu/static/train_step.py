@@ -15,6 +15,7 @@ all-gather over ICI instead of graph surgery).
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -188,11 +189,19 @@ class TrainStep:
             self.params = {
                 k: plan.place(v, plan.param_spec(k, state.get(k)))
                 for k, v in self.params.items()}
+            # scalars (beta-pow accumulators) and buffers are placed
+            # too, replicated as step_shardings declares them: left
+            # uncommitted they come back from step 1 committed to the
+            # mesh, a new jit signature — a retrace at step 2 and a
+            # second cache entry
+            rep = jax.sharding.PartitionSpec()
             self.opt_state = {
-                k: {n: (plan.place(v, plan.state_spec(k, state.get(k)))
-                        if np.ndim(v) > 0 else v)
+                k: {n: plan.place(v, plan.state_spec(k, state.get(k))
+                                  if np.ndim(v) > 0 else rep)
                     for n, v in st.items()}
                 for k, st in self.opt_state.items()}
+            self.buffers = {k: plan.place(v, rep)
+                            for k, v in self.buffers.items()}
 
     # -- pure step ----------------------------------------------------------
     def _forward_loss(self, params, buffers, key, inputs, labels):
@@ -206,7 +215,7 @@ class TrainStep:
                 state[k]._data = a
             ctx = key_scope(key)
             from ..amp.auto_cast import auto_cast
-            with no_grad(), ctx:
+            with no_grad(), ctx, self._mesh_scope():
                 if self.amp_level:
                     with auto_cast(level=self.amp_level,
                                    dtype=self.amp_dtype):
@@ -221,6 +230,14 @@ class TrainStep:
         finally:
             for k, a in saved.items():
                 state[k]._data = a
+
+    def _mesh_scope(self):
+        """The forward traces under the step's mesh so kernels GSPMD
+        cannot partition shard_map themselves; a no-op off-mesh."""
+        if self.mesh is None or self.sharding_plan is None:
+            return contextlib.nullcontext()
+        from ..distributed.env import step_mesh
+        return step_mesh(self.mesh, self.sharding_plan.data_axes)
 
     def _build(self, in_arrays, lbl_arrays):
         optimizer = self.optimizer
@@ -346,14 +363,17 @@ class TrainStep:
         return jax.jit(step, **jit_kwargs)
 
     # -- AOT lowering (memory receipts) -------------------------------------
-    def aot_lower(self, inputs, labels=()):
+    def aot_lower(self, inputs, labels=(), lowering_platforms=None):
         """Lower (and let the caller .compile()) the full training step
         from avals alone — no parameter, optimizer-state, or activation
         bytes are ever allocated. Pairs with
         utils.abstract_init.abstract_parameters() for models too big to
         materialize; `compiled.memory_analysis()` then yields the
         per-device peak the step would need — the hardware-independent
-        fits-in-HBM receipt (tests/test_memory_receipts.py)."""
+        fits-in-HBM receipt (tests/test_memory_receipts.py).
+        `lowering_platforms=("tpu",)` over a mesh of compile-only
+        topology devices lowers for the chip from a CPU host
+        (tests/test_pallas_mosaic_compile.py)."""
         def aval(x):
             if isinstance(x, jax.ShapeDtypeStruct):
                 return x
@@ -369,8 +389,10 @@ class TrainStep:
         buf_avals = jax.tree_util.tree_map(aval, self.buffers)
         opt_avals = jax.tree_util.tree_map(aval, self.opt_state)
         param_avals = jax.tree_util.tree_map(aval, self.params)
-        return step.lower(param_avals, opt_avals, buf_avals, strat_avals,
-                          key_aval, lr_aval, in_avals, lbl_avals)
+        return step.trace(
+            param_avals, opt_avals, buf_avals, strat_avals, key_aval,
+            lr_aval, in_avals, lbl_avals).lower(
+                lowering_platforms=lowering_platforms)
 
     # -- eval / predict -----------------------------------------------------
     def build_eval_fn(self):

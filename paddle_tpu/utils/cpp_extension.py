@@ -110,9 +110,8 @@ def _make_op(lib, op_name: str, has_backward: bool):
 
     def _dispatch(host_fn, out_like, *arrays):
         # concrete arrays (eager): call the C++ kernel directly — works on
-        # every backend, including TPU tunnels without host-callback
-        # support. Tracers (inside jit/grad): emit a pure_callback (runs
-        # where the backend supports host send/recv).
+        # every backend. Tracers (inside jit/grad): emit a pure_callback
+        # (runs where the backend supports host send/recv).
         if any(isinstance(a, jax.core.Tracer) for a in arrays):
             return jax.pure_callback(
                 host_fn, jax.ShapeDtypeStruct(out_like.shape, jnp.float32),

@@ -13,8 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddle_tpu import jax_compat  # noqa: F401  (jax_num_cpu_devices shim)
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
@@ -35,8 +33,6 @@ def main():
         world, timeout=60.0)
     assert blob == b"comm-hier-v1", blob
 
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(
         f"127.0.0.1:{os.environ['PD_TEST_COORD_PORT']}",
         num_processes=world, process_id=rank)

@@ -10,14 +10,9 @@ import os
 import sys
 
 # PD_TEST_TPU=1 opts OUT of the CPU forcing so the TPU-gated tests
-# (tests/test_pallas_attention.py -k tpu) can reach the real chip
-# (tools/tpu_first_light.py sets it).
+# (tests/test_pallas_attention.py) reach the chip when run through the
+# chip tool.
 _USE_TPU = os.environ.get("PD_TEST_TPU") == "1"
-
-# the suite asserts the kernel-dropout self-check's own behavior; a
-# PD_KERNEL_DROPOUT pin inherited from a bench/first-light shell would
-# invert those assertions
-os.environ.pop("PD_KERNEL_DROPOUT", None)
 
 if not _USE_TPU:
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -37,11 +32,6 @@ if not _USE_TPU:
     jax.config.update("jax_platforms", "cpu")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# jax version shims (jax.shard_map / lax.axis_size / jax_num_cpu_devices
-# on older runtimes) must be live BEFORE test modules run their own
-# `from jax import shard_map` imports at collection time.
-from paddle_tpu import jax_compat  # noqa: E402,F401
 
 
 def pytest_configure(config):

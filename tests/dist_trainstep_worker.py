@@ -10,8 +10,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddle_tpu import jax_compat  # noqa: F401  (jax_num_cpu_devices shim)
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
@@ -25,10 +23,6 @@ def main():
     world = int(os.environ["PADDLE_TRAINERS_NUM"])
     coord_port = os.environ["PD_TEST_COORD_PORT"]
     out_dir = os.environ["PD_TEST_OUT"]
-
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-
-    enable_cpu_collectives()  # older-jax CPU meshes need gloo
 
     jax.distributed.initialize(f"127.0.0.1:{coord_port}",
                                num_processes=world, process_id=rank)

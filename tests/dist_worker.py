@@ -42,8 +42,6 @@ def main():
     assert blob == b"cluster-topology-v1", blob
 
     # phase 2: multi-controller init + cross-process allreduce
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-    enable_cpu_collectives()  # older-jax CPU meshes need gloo
     jax.distributed.initialize(f"127.0.0.1:{coord_port}",
                                num_processes=world, process_id=rank)
     assert jax.process_count() == world

@@ -30,8 +30,6 @@ def main():
                                world, timeout=60.0)
     assert blob == b"doctor-div-v1", blob
 
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(f"127.0.0.1:{coord_port}",
                                num_processes=world, process_id=rank)
     assert jax.process_count() == world

@@ -16,8 +16,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paddle_tpu import jax_compat  # noqa: F401  (shard_map shim)
-
 import jax
 
 jax.config.update("jax_platforms", "cpu")
@@ -38,8 +36,6 @@ def main():
         world, timeout=60.0)
     assert blob == b"graph-lint-sched-v1", blob
 
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(
         f"127.0.0.1:{os.environ['PD_TEST_COORD_PORT']}",
         num_processes=world, process_id=rank)

@@ -188,11 +188,10 @@ def test_compile_uncached_carries_scopes_and_restores_config(tmp_path):
     # executable and zeroes the share table. compile_uncached must
     # bypass the cache for the attributed compile and leave the
     # trainer's cache config exactly as it found it.
-    from paddle_tpu.core.flags import apply_compile_cache
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_en = bool(jax.config.jax_enable_compilation_cache)
     try:
-        apply_compile_cache(str(tmp_path), min_compile_secs=0.0)
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
 
         def f(w):
             with anatomy.scope("attn"):

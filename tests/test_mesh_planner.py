@@ -300,7 +300,7 @@ class TestParamSynchronizer:
         from paddle_tpu.distributed.comm import (CommConfig,
                                                  ParamSynchronizer)
         from jax.sharding import Mesh
-        shard_map = jax.shard_map  # installed by paddle_tpu.jax_compat
+        shard_map = jax.shard_map
 
         params = _psync_params()
         mesh = Mesh(np.array(jax.devices()[:4]), ("fsdp",))

@@ -49,7 +49,8 @@ def test_demo_full_surface_and_forced_recompile(tmp_path):
     assert any(v > 0 for v in s["collective_bytes"].values()), s
     assert s["step_ms_p99"] >= s["step_ms_p50"] > 0
     assert s["examples_per_sec"] > 0
-    assert s["mfu"] != 0 and s["model_flops_per_step"] > 0
+    # the demo runs on the CPU, which has no peak: no MFU is reported
+    assert s["mfu"] is None and s["model_flops_per_step"] > 0
     assert s["fleet_host_count"] == 1
 
     # steady-shape leg: zero recompiles in the exported artifacts
@@ -60,7 +61,7 @@ def test_demo_full_surface_and_forced_recompile(tmp_path):
     assert "paddle_tpu_collective_bytes" in prom
     assert 'paddle_tpu_pipeline_step_ms{quantile="0.5"}' in prom
     assert "paddle_tpu_throughput_examples_per_sec" in prom
-    assert "paddle_tpu_throughput_mfu" in prom
+    assert "paddle_tpu_throughput_mfu" not in prom
     rec = json.loads(open(s["jsonl"]).read().splitlines()[-1])
     m = rec["metrics"]
     assert m["train_recompiles_total"] == 0
@@ -68,7 +69,7 @@ def test_demo_full_surface_and_forced_recompile(tmp_path):
     assert any(k.startswith("collective.bytes") for k in m)
     assert m["pipeline.step_ms"]["p50"] > 0
     assert m["throughput.examples_per_sec"] > 0
-    assert "throughput.mfu" in m
+    assert "throughput.mfu" not in m
     # metric marks merged into the host chrome trace
     tr = json.load(open(s["trace"]))
     assert any(e.get("ph") == "C" for e in tr["traceEvents"])

@@ -91,9 +91,8 @@ class TestKernelDropout:
     prng_random_bits to zeros, so only the dropout_p=0 equivalence runs
     under interpret mode; the RNG-dependent checks (determinism, mean
     preservation, the fixed-seed numeric grad check that pins backward
-    mask regeneration) run on real TPU hardware, where
-    pallas_kernels.kernel_dropout_available() also gates the production
-    dispatch."""
+    mask regeneration) run on real TPU hardware
+    (PD_TEST_TPU=1 through the chip tool)."""
 
     def _qkv(self, b=1, s=16, n=2, h=8, seed=0):
         rng = np.random.RandomState(seed)
@@ -108,13 +107,6 @@ class TestKernelDropout:
         np.testing.assert_allclose(np.asarray(base), np.asarray(drop0),
                                    rtol=1e-6, atol=1e-6)
 
-    @pytest.mark.skipif(pallas_available(), reason="CPU-only check")
-    def test_selfcheck_gates_cpu(self):
-        # on CPU the self-check must refuse the kernel path, making the
-        # functional fall back to SDPA-with-dropout (on TPU the inverse
-        # is asserted by test_tpu_deterministic_per_seed)
-        assert not pk.kernel_dropout_available()
-
     @pytest.mark.skipif(not pallas_available(), reason="needs TPU")
     def test_tpu_deterministic_per_seed(self):
         q, k, v = self._qkv()
@@ -123,7 +115,6 @@ class TestKernelDropout:
         c = pk.flash_attention_mha(q, k, v, dropout_p=0.4, seed=8)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b2))
         assert np.abs(np.asarray(a) - np.asarray(c)).max() > 1e-6
-        assert pk.kernel_dropout_available()
 
     @pytest.mark.skipif(not pallas_available(), reason="needs TPU")
     def test_tpu_mean_preserved(self):
@@ -163,6 +154,101 @@ class TestKernelDropout:
                 np.testing.assert_allclose(
                     float(np.asarray(g)[pos]), num, rtol=1e-1,
                     atol=1e-2)
+
+    @pytest.mark.skipif(not pallas_available() or jax.device_count() < 4,
+                        reason="needs four TPU chips")
+    def test_tpu_sharded_dropout_drops_the_unsharded_links(self):
+        # masks key on GLOBAL batch·heads rows, so dp2 x tp2 must
+        # reproduce the one-chip output link for link
+        from jax.sharding import Mesh
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("dp", "tp"))
+        q, k, v = self._qkv(b=4, s=256, n=4, h=64)
+        one = pk.flash_attention_mha(q, k, v, dropout_p=0.3, seed=5)
+        four = pk.flash_attention_mha_sharded(
+            q, k, v, mesh, ("dp",), "tp", dropout_p=0.3, seed=5)
+        np.testing.assert_allclose(np.asarray(four), np.asarray(one),
+                                   rtol=1e-6, atol=1e-6)
+
+
+class TestMeshWrapper:
+    """flash_attention_mha_sharded on the virtual CPU mesh (interpret
+    mode; Mosaic's verdict on it is tests/test_pallas_mosaic_compile)."""
+
+    def test_global_rows_tile_the_unsharded_grid(self):
+        b, n, dp, tp = 4, 6, 2, 3
+        b_l, n_l = b // dp, n // tp
+        seen = {}
+        for bi in range(dp):
+            for hi in range(tp):
+                first = bi * b_l * n + hi * n_l
+                for r in range(b_l * n_l):
+                    want = (bi * b_l + r // n_l) * n + hi * n_l + r % n_l
+                    assert pk._global_row(r, first, n_l, n) == want
+                    seen[want] = seen.get(want, 0) + 1
+        assert seen == {r: 1 for r in range(b * n)}
+
+    @pytest.mark.skipif(jax.device_count() < 4,
+                        reason="needs four devices")
+    def test_sharded_matches_unsharded(self):
+        from jax.sharding import Mesh
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("dp", "tp"))
+        q, k, v = _data(2, 128, 2, 32)
+
+        def f(attn):  # jitted: one compile per side, not one per op
+            return jax.jit(jax.value_and_grad(
+                lambda q: jnp.sum(jnp.sin(attn(q)))))(q)
+        want, gwant = f(lambda q: flash_attention_mha(
+            q, k, v, causal=True, interpret=True))
+        got, ggot = f(lambda q: pk.flash_attention_mha_sharded(
+            q, k, v, mesh, ("dp",), "tp", causal=True, interpret=True))
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(ggot), np.asarray(gwant),
+                                   rtol=1e-5, atol=1e-5)
+
+
+    @pytest.mark.skipif(jax.device_count() < 4,
+                        reason="needs four devices")
+    def test_sharded_trainstep_hands_its_mesh_to_the_kernel(
+            self, monkeypatch):
+        # a TrainStep over a mesh must reach the kernel through the
+        # wrapper, with ITS mesh — even when the same op at the same
+        # shapes was traced mesh-less first (per-op jit caches key on
+        # attributes, and the mesh rides as one)
+        import paddle_tpu as paddle
+        import paddle_tpu.distributed as dist
+        import paddle_tpu.nn.functional as F
+        from paddle_tpu.models import ErnieConfig, ErnieForPretraining
+        from paddle_tpu.static import TrainStep
+        seen = []
+
+        def fake_sharded(q, k, v, mesh, batch_axes, head_axis, **kw):
+            seen.append((mesh, tuple(batch_axes), head_axis))
+            return _sdpa_impl(q, k, v, None, 0.0, kw["causal"], None)
+        monkeypatch.setattr(pk, "pallas_available", lambda: True)
+        monkeypatch.setattr(pk, "flash_attention_mha_sharded",
+                            fake_sharded)
+        monkeypatch.setattr(
+            pk, "flash_attention_mha",
+            lambda q, k, v, causal=False, **kw: _sdpa_impl(
+                q, k, v, None, 0.0, causal, None))
+        paddle.seed(0)
+        cfg = ErnieConfig.tiny(attention_probs_dropout_prob=0.0)
+        q = paddle.randn([4, 16, cfg.num_attention_heads,
+                          cfg.hidden_size // cfg.num_attention_heads])
+        F.flash_attention(q, q, q)          # mesh-less trace, cached
+        assert seen == []
+        mesh = dist.build_mesh({"dp": 2, "tp": 2})
+        model = ErnieForPretraining(cfg)
+        opt = paddle.optimizer.SGD(learning_rate=0.1,
+                                   parameters=model.parameters())
+        step = TrainStep(model, ErnieForPretraining.pretraining_loss,
+                         opt, mesh=mesh,
+                         sharding_plan=dist.ShardingPlan(mesh))
+        ids = paddle.to_tensor(np.zeros((4, 16), np.int32))
+        assert np.isfinite(float(step(ids, ids).item()))
+        assert seen and all(s == (mesh, ("dp",), "tp") for s in seen)
 
 
 class TestModelAttentionDropout:
@@ -210,8 +296,8 @@ class TestBlockwiseDropoutTier:
     """The middle dispatch tier (attention.py _flash_dropout_blockwise):
     pure-JAX flash-dropout — flash semantics (denominator over ALL
     links, dropout on the normalized probs, per-block regenerated
-    masks) with no Mosaic RNG. Selected on TPU when the kernel RNG
-    probe fails; forceable via PD_ATTN_DROPOUT_IMPL=blockwise."""
+    masks) with no Mosaic RNG. What PD_ATTN_DROPOUT_IMPL=blockwise
+    selects, and what varlen (kv_lens) batches run."""
 
     def _qkv(self, b=2, s=64, n=2, h=16, seed=0):
         rng = np.random.RandomState(seed)
@@ -308,8 +394,10 @@ class TestBlockwiseDropoutTier:
         monkeypatch.setenv("PD_ATTN_DROPOUT_IMPL", "sdpa")
         assert am.attention_dropout_impl() == "sdpa"
         monkeypatch.delenv("PD_ATTN_DROPOUT_IMPL")
-        # CPU default: no pallas backend -> sdpa
-        assert am.attention_dropout_impl() == "sdpa"
+        # unforced, the platform alone decides: kernel on a TPU, the
+        # sdpa reference elsewhere
+        assert am.attention_dropout_impl() == (
+            "kernel" if pallas_available() else "sdpa")
 
     def test_functional_routes_blockwise(self, monkeypatch):
         import paddle_tpu as paddle

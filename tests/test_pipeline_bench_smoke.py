@@ -1,27 +1,19 @@
 """Tier-1 pipeline-bench smoke: guards against reintroducing per-tick
 dispatch into the train step.
 
-Runs the bench.py pipeline leg (tools/pipeline_bench.py) in a
-subprocess with small shapes and fails if
+Runs tools/pipeline_bench.py in a subprocess with small shapes and
+fails if
   - compile_count exceeds the config count (exactly ONE train
     executable per config is the spmd_1f1b contract), or
   - dispatches_per_step leaves 1 (the single-program contract), or
-  - speedup_vs_single regresses below the seed value recorded in
-    BENCH_r05.json (0.167 — the host-driven engine's floor before the
-    single-dispatch mode landed), or
   - the orchestration_fraction field disappears from the JSON.
 
-The structural asserts are single-shot. The timing bar takes the best
-of up to 3 runs: a loaded CI host can slow ANY single run, but a
-schedule regression (per-tick dispatch back in the hot path) slows
-every run — best-of-N separates the two.
+Counts only: a CPU wall-clock ratio is not a speed, so no timing bar.
 """
 import json
 import os
 import subprocess
 import sys
-
-import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -41,14 +33,6 @@ _ENV = {
 _ENV.pop("XLA_FLAGS", None)
 
 
-def _seed_floor():
-    path = os.path.join(ROOT, "BENCH_r05.json")
-    with open(path) as f:
-        seed = json.load(f)
-    return float(
-        seed["parsed"]["extras"]["pipeline"]["speedup_vs_single"])
-
-
 def _run_bench(jsonl=None):
     env = _ENV if jsonl is None else {**_ENV, "PD_OBS_JSONL": jsonl}
     p = subprocess.run(
@@ -60,8 +44,7 @@ def _run_bench(jsonl=None):
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
-def test_pipeline_bench_single_dispatch_and_speedup_floor(tmp_path):
-    floor = _seed_floor()
+def test_pipeline_bench_single_dispatch(tmp_path):
     jsonl = str(tmp_path / "bench.jsonl")
     stats = _run_bench(jsonl=jsonl)
 
@@ -86,14 +69,3 @@ def test_pipeline_bench_single_dispatch_and_speedup_floor(tmp_path):
     assert stats["tick_ms_p99"] >= stats["tick_ms_p50"]
     assert stats["step_ms_p99"] >= stats["step_ms_p50"] > 0.0
     assert stats["stages"] == 2 and stats["num_micro"] == 4
-
-    # timing floor — best of up to 3 runs
-    best = stats["speedup_vs_single"]
-    for _ in range(2):
-        if best > floor:
-            break
-        best = max(best, _run_bench()["speedup_vs_single"])
-    assert best > floor, (
-        f"spmd_1f1b speedup_vs_single {best} regressed to/below the "
-        f"seed host-engine value {floor} — per-tick dispatch is back "
-        "in the hot path?")

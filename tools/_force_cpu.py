@@ -4,11 +4,7 @@ graph_lint, memory_anatomy and memory_receipts all need the same
 dance, and before this module each carried a drifting hand-rolled
 variant (tests/conftest.py keeps its own: it must run as a pytest
 plugin before any tool imports). The dance: act BEFORE the jax
-backend initializes — ``XLA_FLAGS=--xla_force_host_platform_
-device_count`` is the mechanism that exists on every jaxlib, while
-the ``jax_num_cpu_devices`` config option only exists on newer ones
-(AttributeError on e.g. 0.4.37), so a jax version bump is absorbed
-here instead of in four places.
+backend initializes.
 """
 import os
 
@@ -22,19 +18,13 @@ def force_cpu_devices(n: int, strict: bool = False):
     tolerates an already-initialized backend (pytest's conftest
     forced 8, the lint tools use what's there).
     """
-    import paddle_tpu.jax_compat  # noqa: F401 (shard_map shim first)
     import jax
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
+    os.environ["JAX_PLATFORMS"] = "cpu"
     try:
         jax.config.update("jax_platforms", "cpu")
         jax.config.update("jax_num_cpu_devices", n)
-    except Exception:
-        pass  # older jax (no jax_num_cpu_devices) or backend already up
+    except RuntimeError:
+        pass  # backend already up: use what is there
     if strict:
         assert len(jax.devices()) >= n
     return jax

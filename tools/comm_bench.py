@@ -37,8 +37,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from paddle_tpu import jax_compat  # noqa: E402,F401 (shims first)
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
@@ -200,8 +198,6 @@ def dist_worker():
         world, timeout=60.0)
     assert blob == b"comm-bench-v1", blob
 
-    from paddle_tpu.jax_compat import enable_cpu_collectives
-    enable_cpu_collectives()
     jax.distributed.initialize(
         f"127.0.0.1:{os.environ['PD_TEST_COORD_PORT']}",
         num_processes=world, process_id=rank)

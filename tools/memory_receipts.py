@@ -61,9 +61,7 @@ def _stats(lowered):
     """Per-device sizes from XLA buffer assignment — a shim over the
     memory plane (`observability.memory.memory_analysis_dict`), legacy
     JSON keys preserved so MEMORY_RECEIPTS.json regenerates
-    byte-compatible modulo new fields. The plane also carries the
-    `peak_bytes` fallback for runtimes whose CompiledMemoryStats has
-    no `peak_memory_in_bytes` (this tool used to crash there).
+    byte-compatible modulo new fields.
 
     `argument` (params + optimizer moments + AMP masters + data shard)
     and `output` (their updated twins; donation aliases them onto the
@@ -79,12 +77,12 @@ def _stats(lowered):
     from paddle_tpu.observability.memory import memory_analysis_dict
     ma = memory_analysis_dict(lowered.compile())
     # the budget check's peak: state residency, never the CPU-bound
-    # temp (the fallback reconstruction FOLDS temp in — strip it back
-    # out so old and new runtimes budget the same quantity)
-    peak = (ma["peak_bytes"] if ma["peak_is_exact"]
-            else max(ma["argument_bytes"],
-                     ma["argument_bytes"] + ma["output_bytes"]
-                     - ma["alias_bytes"]))
+    # temp. XLA's own `peak_memory_in_bytes` (exact on this runtime)
+    # counts that temp, so the budget quantity is always rebuilt from
+    # the argument/output/alias sizes.
+    peak = max(ma["argument_bytes"],
+               ma["argument_bytes"] + ma["output_bytes"]
+               - ma["alias_bytes"])
     return {
         "argument_gib": ma["argument_bytes"] / GIB,
         "output_gib": ma["output_bytes"] / GIB,

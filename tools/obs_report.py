@@ -107,7 +107,6 @@ def _jax_setup():
         os.environ["XLA_FLAGS"] = (
             _flags + f" --xla_force_host_platform_device_count={N_DEV}"
         ).strip()
-    from paddle_tpu import jax_compat  # noqa: F401 (shims first)
     import jax as _jax
     _jax.config.update("jax_platforms", "cpu")
     _jax.config.update("jax_num_cpu_devices", N_DEV)
@@ -267,7 +266,6 @@ def run_anatomy(args):
     global jax, np
     if jax is None:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from paddle_tpu import jax_compat  # noqa: F401 (shims first)
         import jax as _jax
         import numpy as _np
         jax, np = _jax, _np
@@ -325,7 +323,6 @@ def run_memory(args):
     global jax, np
     if jax is None:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from paddle_tpu import jax_compat  # noqa: F401 (shims first)
         import jax as _jax
         import numpy as _np
         jax, np = _jax, _np
@@ -395,7 +392,6 @@ def run_serving(args):
     global jax, np
     if jax is None:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from paddle_tpu import jax_compat  # noqa: F401 (shims first)
         import jax as _jax
         import numpy as _np
         jax, np = _jax, _np
@@ -511,7 +507,6 @@ def run_pulse(args):
     global jax, np
     if jax is None:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
-        from paddle_tpu import jax_compat  # noqa: F401 (shims first)
         import jax as _jax
         import numpy as _np
         jax, np = _jax, _np

@@ -9,16 +9,12 @@ with a DIRECTION (higher-better tokens/s and goodput, lower-better
 p99 TTFT and wire bytes, exact-better compile/recompile counts) and a
 TOLERANCE. Imports no jax — ingest/check/trend run on any triage host.
 
-Modes (combinable; order: ingest/backfill -> inflate -> write-baseline
+Modes (combinable; order: ingest -> inflate -> write-baseline
 -> check -> trend):
   --ingest FILE...    append records from receipt artifacts (driver
                       wrappers with "parsed", multichip probes, or raw
                       emit_report JSON / last line of a log). Skips
                       runs whose id is already ledgered (idempotent).
-  --backfill          ingest the repo's checked-in BENCH_r0*.json +
-                      MULTICHIP_r0*.json so --trend shows the real
-                      historical trajectory (run once; the ledger is
-                      committed).
   --check [RECEIPT]   gate a receipt (or, with no file, the NEWEST
                       ledger record per fingerprint) against the
                       baseline: exit 1 naming metric + run + delta.
@@ -40,7 +36,6 @@ Usage:
   python tools/perf_ledger.py --check --inflate value:0.5  # must rc 1
 """
 import argparse
-import glob
 import json
 import os
 import re
@@ -143,14 +138,6 @@ def ingest(paths, ledger_path: str, verbose: bool = True):
     return added
 
 
-def backfill_paths():
-    pats = ("BENCH_r0*.json", "MULTICHIP_r0*.json")
-    out = []
-    for pat in pats:
-        out.extend(sorted(glob.glob(os.path.join(REPO, pat))))
-    return out
-
-
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description=__doc__,
@@ -159,9 +146,6 @@ def main(argv=None) -> int:
     ap.add_argument("--baseline", default=DEFAULT_BASELINE)
     ap.add_argument("--ingest", nargs="+", default=None,
                     metavar="FILE", help="append receipt artifacts")
-    ap.add_argument("--backfill", action="store_true",
-                    help="ingest the checked-in BENCH_r0*/MULTICHIP_r0* "
-                         "artifacts")
     ap.add_argument("--check", nargs="?", const="", default=None,
                     metavar="RECEIPT",
                     help="gate a receipt (default: newest ledger "
@@ -182,8 +166,6 @@ def main(argv=None) -> int:
 
     if args.ingest:
         ingest(args.ingest, args.ledger)
-    if args.backfill:
-        ingest(backfill_paths(), args.ledger)
 
     records = pl.load_ledger(args.ledger)
 

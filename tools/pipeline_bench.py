@@ -36,16 +36,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 N_DEV = int(os.environ.get("PD_PIPE_BENCH_DEVICES", 2))
 
-# the CPU device-count flag must be pinned BEFORE the backend exists;
-# the config option alone does not exist on older jax runtimes
+# the CPU device count must be pinned BEFORE the backend exists
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + f" --xla_force_host_platform_device_count={N_DEV}"
-    ).strip()
-
-from paddle_tpu import jax_compat  # noqa: E402,F401 (shims first)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
