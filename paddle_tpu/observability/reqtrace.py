@@ -36,12 +36,29 @@ marks are points:
   span  swap_flip    a hot-weight-swap pause on the request's replica
   mark  submit / dispatch / evict / retire / shed / drop / swap_flip
 
+Engine steps are on the same ring (``open_step`` and the handle it
+returns, the ONE writer): every ``ServingEngine.step()`` that has work
+is one ``step`` span (rid None; step, replica, and ``executables``,
+the engine's compile count at that boundary) over contiguous phase
+spans (``STEP_PHASES``; step, replica, parent="step", and the
+program's ``kind`` on a dispatch's phases). The same call that writes
+a phase to the ring enters ``jax.profiler.TraceAnnotation(
+"serve:<phase>", step=n)``, so a profiler capture shows the phases on
+the device ops' clock and ring and capture join by (name, step). Step
+events are not requests: ``timelines`` (so ``attribute`` and
+``explain_tail``) skips them and ``chrome_trace_events`` draws them on
+lanes of their own. They share the ring, 9 to 18 slots a step, which
+is why its default capacity is what it is.
+
 Cost discipline is the flight recorder's, verbatim: one module bool
-(``_enabled``) gates everything; a disabled ``record_span()`` is a
-function call plus a bool read (<1 µs, tier-1-guarded); enabled writes
-claim a ring slot from an ``itertools.count`` (atomic under the GIL —
-no hot-path lock). The module imports no jax and no numpy: traces must
-be readable while jax is wedged, exactly like the flight recorder.
+(``_enabled``) gates everything; a disabled ``record_span()`` or
+``open_step()`` is a function call plus a bool read, a ``phase()`` of
+the ``NO_STEP`` it returned an empty method (<1 µs, tier-1-guarded);
+enabled writes claim a ring slot from an ``itertools.count`` (atomic
+under the GIL — no hot-path lock). The module imports no numpy, and no
+jax until ``enable()`` turns tracing on (for the annotation): the ring
+must be readable while jax is wedged, exactly like the flight
+recorder.
 """
 from __future__ import annotations
 
@@ -55,11 +72,14 @@ __all__ = [
     "ReqTracer", "enable", "disable", "enabled", "reset", "get_tracer",
     "record_span", "mark", "events", "timelines", "attribute",
     "explain_tail", "chrome_trace_events", "BurnMeter", "COMPONENTS",
+    "STEP_PHASES", "NO_STEP", "open_step",
 ]
 
 _enabled = False            # the one-bool hot-path gate
 
-_DEFAULT_CAPACITY = 8192
+# requests' events AND engine steps': a decode step is 9 slots plus one
+# per live request, so few live requests leave the ring mostly steps
+_DEFAULT_CAPACITY = 1 << 16
 
 # the disjoint latency components attribution decomposes into;
 # "other" is the closure (wall time no span claimed). "draft" is the
@@ -70,6 +90,12 @@ COMPONENTS: Tuple[str, ...] = ("queue", "admission", "prefix_match",
                                "prefill", "draft", "decode", "requeue",
                                "swap_flip")
 _TERMINAL_MARKS = ("retire", "shed", "drop")
+# the phases of one engine step, in the order a step runs them (alloc
+# to accept once per dispatch; alloc before a prefill only)
+STEP_PHASES: Tuple[str, ...] = ("retire", "admit", "keys", "alloc",
+                                "build", "dispatch", "sync", "accept",
+                                "observe")
+_STEP_COMPS = frozenset(("step",) + STEP_PHASES)
 
 
 class ReqTracer:
@@ -139,9 +165,13 @@ def get_tracer() -> ReqTracer:
 def enable(on: bool = True, capacity: Optional[int] = None):
     """Turn request tracing on (off by default — serving never pays
     for spans nobody reads)."""
-    global _enabled
+    global _enabled, _TraceAnnotation
     if capacity is not None and capacity != _tracer.capacity:
         _tracer.resize(capacity)
+    if on and _TraceAnnotation is None:
+        # the step writer's second clock; the one jax import of this
+        # module, and not of a process that only reads a ring
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
     _enabled = bool(on)
     return _enabled
 
@@ -173,6 +203,83 @@ def mark(rid, event: str, t: Optional[float] = None, **meta) -> int:
     return _tracer.mark(rid, event, t=t, **meta)
 
 
+# -- engine steps: one writer, two clocks -------------------------------------
+
+_TraceAnnotation = None     # jax.profiler.TraceAnnotation, from enable()
+
+
+class _Step:
+    """One engine step being written. ``phase`` closes the open phase
+    and opens the next at ONE stamp, so the phases are contiguous; each
+    is a ring span on ``perf_counter`` and, from the same call, a
+    ``serve:<phase>`` annotation on the profiler's clock. ``close``
+    ends the step; the engine calls it in a ``finally``, so a step
+    that raised leaves no annotation entered."""
+
+    __slots__ = ("step", "replica", "t0", "_whole", "_open")
+
+    def __init__(self, step: int, replica):
+        self.step, self.replica = step, replica
+        self._open = None       # (name, t0, meta, annotation)
+        self._whole = _TraceAnnotation("serve:step", step=step)
+        self._whole.__enter__()
+        self.t0 = time.perf_counter()
+
+    def _close_phase(self, t: Optional[float]) -> float:
+        now = time.perf_counter() if t is None else t
+        if self._open is not None:
+            name, t0, meta, annotation = self._open
+            annotation.__exit__(None, None, None)
+            _tracer.record_span(None, name, t0, now, step=self.step,
+                                replica=self.replica, parent="step",
+                                **meta)
+            self._open = None
+        return now
+
+    def phase(self, name: str, kind: Optional[str] = None,
+              t: Optional[float] = None):
+        """Open phase ``name`` (closing the one before it) at ``t``, or
+        now. ``kind`` names the program of a dispatch's phases."""
+        now = self._close_phase(t)
+        meta = {} if kind is None else {"kind": kind}
+        annotation = _TraceAnnotation("serve:" + name, step=self.step,
+                                      **meta)
+        annotation.__enter__()
+        self._open = (name, now, meta, annotation)
+
+    def close(self, executables: Optional[int] = None):
+        """End the step: its last phase, then the ``step`` span with
+        the engine's compile count at this boundary."""
+        now = self._close_phase(None)
+        self._whole.__exit__(None, None, None)
+        _tracer.record_span(None, "step", self.t0, now, step=self.step,
+                            replica=self.replica,
+                            executables=executables)
+
+
+class _NoStep:
+    """The step nobody traces: every call does nothing."""
+
+    __slots__ = ()
+
+    def phase(self, name, kind=None, t=None):
+        pass
+
+    def close(self, executables=None):
+        pass
+
+
+NO_STEP = _NoStep()
+
+
+def open_step(step: int, replica=None):
+    """Begin one engine step's spans: the handle whose ``phase`` and
+    ``close`` write them, or ``NO_STEP`` (a bool read) when disabled."""
+    if not _enabled:
+        return NO_STEP
+    return _Step(step, replica)
+
+
 # -- timelines ----------------------------------------------------------------
 
 def timelines(evts: Optional[List[dict]] = None) -> Dict[Any, dict]:
@@ -182,11 +289,14 @@ def timelines(evts: Optional[List[dict]] = None) -> Dict[Any, dict]:
     arrival = the ``submit`` mark (fleet arrival clock; the
     ``dispatch`` mark or earliest span is the fallback), done = the
     terminal mark (retire/shed/drop; latest span end as fallback).
-    Requests with no time base yet (in flight) carry ``done=None``."""
+    Requests with no time base yet (in flight) carry ``done=None``.
+    Engine-step events are not requests and are left out."""
     if evts is None:
         evts = _tracer.events()
     out: Dict[Any, dict] = {}
     for e in evts:
+        if e.get("comp") in _STEP_COMPS:
+            continue
         tl = out.setdefault(e["rid"], {"arrival": None, "done": None,
                                        "spans": [], "marks": []})
         if "comp" in e:
@@ -342,6 +452,10 @@ _CNAME = {
 }
 
 
+# engine-step lanes sit above every replica's request lane
+_STEP_LANE_BASE = 1 << 16
+
+
 def _lane(replica) -> int:
     # one lane per replica; replica-less (single-engine) spans share
     # lane 0 with replica 0
@@ -351,18 +465,31 @@ def _lane(replica) -> int:
 def chrome_trace_events(evts: Optional[List[dict]] = None) -> list:
     """Request lanes for chrome://tracing: one lane (tid) per replica,
     spans as complete ("ph":"X") events colored by component, marks as
-    instant events. Timestamps share the perf_counter µs base the
-    exporters' metric counter marks use, so the lanes line up with the
-    host trace profiler.export_chrome_tracing writes."""
+    instant events; engine steps and their phases nest on a lane of
+    their own per replica (``engine steps``). Timestamps share the
+    perf_counter µs base the exporters' metric counter marks use, so
+    the lanes line up with the host trace
+    profiler.export_chrome_tracing writes."""
     if evts is None:
         evts = _tracer.events()
     pid = os.getpid()
     out = []
-    lanes = set()
+    lanes = {}
     for e in evts:
+        tid = _lane(e.get("replica"))
+        if e.get("comp") in _STEP_COMPS:
+            tid += _STEP_LANE_BASE
+            lanes[tid] = f"engine steps {tid - _STEP_LANE_BASE}"
+            out.append({"name": e["comp"], "ph": "X",
+                        "ts": e["t0"] * 1e6,
+                        "dur": max(e["t1"] - e["t0"], 0.0) * 1e6,
+                        "pid": pid, "tid": tid, "cat": "reqtrace",
+                        "args": {k: v for k, v in e.items()
+                                 if k not in ("i", "t0", "t1", "comp",
+                                              "rid")}})
+            continue
+        lanes[tid] = f"serving replica {tid}"
         if "comp" in e:
-            tid = _lane(e.get("replica"))
-            lanes.add(tid)
             args = {k: v for k, v in e.items()
                     if k not in ("i", "t0", "t1", "comp")}
             ev = {"name": f"{e['comp']}:{e['rid']}", "ph": "X",
@@ -375,8 +502,6 @@ def chrome_trace_events(evts: Optional[List[dict]] = None) -> list:
                 ev["cname"] = cname
             out.append(ev)
         else:
-            tid = _lane(e.get("replica"))
-            lanes.add(tid)
             out.append({"name": f"{e['mark']}:{e['rid']}", "ph": "i",
                         "s": "t", "ts": e["t"] * 1e6, "pid": pid,
                         "tid": tid, "cat": "reqtrace",
@@ -384,8 +509,7 @@ def chrome_trace_events(evts: Optional[List[dict]] = None) -> list:
                                  if k not in ("i", "t", "mark")}})
     for tid in sorted(lanes):
         out.append({"name": "thread_name", "ph": "M", "pid": pid,
-                    "tid": tid,
-                    "args": {"name": f"serving replica {tid}"}})
+                    "tid": tid, "args": {"name": lanes[tid]}})
     return out
 
 

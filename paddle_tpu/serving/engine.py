@@ -18,8 +18,9 @@ the per-step dispatch is the price of in-flight admission, and the
 bench shows the batch-shape wins dominate it.
 
 Metrics ride the gated serving.* series (queue depth, active slots,
-free pages, admitted/retired/evicted totals, TTFT + per-step
-histograms); ``serving_recompiles_total`` is always-on via the
+free pages, admitted/retired/evicted totals); a step's durations are
+the request-trace spans of ``step()``, not histograms;
+``serving_recompiles_total`` is always-on via the
 RecompileSentinel. ``serving.retired_total`` counts FINISHED requests;
 ``serving.evicted_total`` counts requests pulled off the engine for
 requeue (``evict_requests`` / fleet requeue) — nothing else.
@@ -510,327 +511,333 @@ class ServingEngine:
     # -- one token boundary --------------------------------------------------
     def step(self) -> List[Request]:
         """Retire, admit, decode — returns the requests that FINISHED
-        at this boundary (their pages already freed)."""
+        at this boundary (their pages already freed). With request
+        tracing on, a step that has work is one ``step`` span over
+        contiguous phases (DESIGN.md "Request anatomy")."""
         import jax
         cfg = self.config
         rec = _obs._enabled
-        finished = self.sched.retire_finished()
-        for r in finished:
-            self.cache.free(r.rid)
-            if self.draft_cache is not None:
-                self.draft_cache.free(r.rid)
-            r.done_ts = time.perf_counter()
-        if _rt._enabled:
-            for r in finished:
-                _rt.mark(r.rid, "retire", t=r.done_ts,
-                         reason=r.finish_reason,
-                         replica=self.trace_replica)
-        if rec and finished:
-            _obs.counter("serving.retired_total").add(len(finished))
-
-        batch = self.sched.take_admissible(
-            self.cache,
-            () if self.draft_cache is None else (self.draft_cache,))
         self._step_no += 1
-        # one fresh key per boundary, then DISTINCT subkeys for the
-        # two programs: prefill's _pick consumes its key directly while
-        # decode splits its own per chunk step — handing both the same
-        # key would correlate the sampled draws (greedy is unaffected)
-        key = jax.random.fold_in(self._key, self._step_no)
-        pf_key = jax.random.fold_in(key, 0)
-        dec_key = jax.random.fold_in(key, 1)
-        prefill_sig = decode_sig = None
-        chunk_sigs: List[Tuple[int, int]] = []
-        if batch:
-            t0 = time.perf_counter()
-            a = self.sched.max_admit
-            rids: List[object] = []
-            if cfg.prefix_sharing:
-                # radix admission: longest indexed prompt prefix rides
-                # shared pages (refcount++), fresh pages cover the rest
-                for r in batch:
-                    _, r.shared_tokens = self.cache.alloc_shared(
-                        r.rid, r.total_tokens, r.ids)
-                    rids.append(r.rid)
-            else:
-                for r in batch:
-                    self.cache.alloc(r.rid, r.total_tokens)
-                    rids.append(r.rid)
-            t_match = time.perf_counter()
-            rids += [None] * (a - len(batch))
-            if self.draft_cache is not None:
-                # the draft mirrors the target position-for-position;
-                # its cache never shares, so it prefills the FULL
-                # prompt regardless of the target's prefix hits
-                for r in batch:
-                    self.draft_cache.alloc(r.rid, r.total_tokens)
-                sd = self.ladder.pick_prefill(
-                    max(r.prompt_len for r in batch))
-                d_ids = np.zeros((a, sd), np.int32)
-                d_lens = np.ones((a,), np.int32)
-                for i, r in enumerate(batch):
-                    d_ids[i, :r.prompt_len] = r.ids
-                    d_lens[i] = r.prompt_len
-                self.draft_cache.pools, _ = self._draft_prefill(
-                    self.draft_cache.pools,
-                    self.draft_cache.table_array(rids, cfg.table_width),
-                    d_ids, d_lens, self.draft_params, pf_key)
-            if cfg.prefix_sharing:
-                # suffix prefill through the chunk program: each row
-                # forwards ONLY its unshared tail, starting at its
-                # shared-token offset and attending the shared pages
-                # through the same table gather decode uses (a full
-                # miss is starts=0 — a dense prefill with junk routed
-                # to scratch instead of page-scattered)
-                s = self.ladder.pick_prefill(
-                    max(r.prompt_len - r.shared_tokens for r in batch))
-                ids = np.zeros((a, s), np.int32)
-                lens = np.ones((a,), np.int32)
-                starts = np.zeros((a,), np.int32)
-                for i, r in enumerate(batch):
-                    sfx = r.ids[r.shared_tokens:]
-                    ids[i, :sfx.size] = sfx
-                    lens[i] = sfx.size
-                    starts[i] = r.shared_tokens
-                tables = self.cache.table_array(rids, cfg.table_width)
-                try:
-                    self.cache.pools, _, tok = self._chunk(
-                        self.cache.pools, tables, ids, starts, lens,
-                        self.params, pf_key)
-                except Exception as e:
-                    _mem.handle_dispatch_oom(
-                        "serving_prefill", e, bucket=s, width=a,
-                        replica=self.trace_replica, step=self._step_no)
-                    raise
-                chunk_sigs.append((a, s))
-            else:
-                s = self.ladder.pick_prefill(
-                    max(r.prompt_len for r in batch))
-                ids = np.zeros((a, s), np.int32)
-                lens = np.ones((a,), np.int32)
-                for i, r in enumerate(batch):
-                    ids[i, :r.prompt_len] = r.ids
-                    lens[i] = r.prompt_len
-                tables = self.cache.table_array(rids, cfg.table_width)
-                try:
-                    self.cache.pools, tok = self._prefill(
-                        self.cache.pools, tables, ids, lens,
-                        self.params, pf_key)
-                except Exception as e:
-                    # OOM sentry (zero cost on the success path): a
-                    # RESOURCE_EXHAUSTED here leaves the breadcrumb +
-                    # post-mortem receipt before the engine dies
-                    _mem.handle_dispatch_oom(
-                        "serving_prefill", e, bucket=s, width=a,
-                        replica=self.trace_replica, step=self._step_no)
-                    raise
-                prefill_sig = (a, s)
-            tok = np.asarray(tok)
-            now = time.perf_counter()
-            for i, r in enumerate(batch):
-                r.admitted_ts = t0
-                r.first_token_ts = now
-                r.pos = r.prompt_len
-                r.accept(int(tok[i]))
-            if cfg.prefix_sharing:
-                # adopt this prompt's full-chunk pages into the radix
-                # index AFTER the prefill landed their K/V — the NEXT
-                # request with this prefix shares them
-                for r in batch:
-                    self.cache.register_prefix(r.rid, r.ids)
+        tr = (_rt.open_step(self._step_no, self.trace_replica)
+              if _rt._enabled and self.sched.has_work() else _rt.NO_STEP)
+        n_exec = None
+        try:
+            tr.phase("retire")
+            finished = self.sched.retire_finished()
+            for r in finished:
+                self.cache.free(r.rid)
+                if self.draft_cache is not None:
+                    self.draft_cache.free(r.rid)
+                r.done_ts = time.perf_counter()
             if _rt._enabled:
-                tick = (self._step_no if self.trace_tick is None
-                        else self.trace_tick)
-                for r in batch:
-                    if r.shared_tokens:
-                        # the radix-match + shared-alloc slice of
-                        # admission, so tail attribution sees sharing
-                        # cost (and benefit) by name
-                        _rt.record_span(
-                            r.rid, "prefix_match", t0, t_match,
-                            shared_tokens=r.shared_tokens,
-                            replica=self.trace_replica, tick=tick)
-                    _rt.record_span(r.rid, "prefill",
-                                    t_match if r.shared_tokens else t0,
-                                    now, bucket=s, width=a,
-                                    replica=self.trace_replica,
-                                    tick=tick)
-            if rec:
-                _obs.counter("serving.admitted_total").add(len(batch))
-                _obs.histogram("serving.prefill_ms").observe(
-                    (now - t0) * 1e3)
-                for r in batch:
-                    if r.arrival is not None:
-                        _obs.histogram("serving.ttft_ms").observe(
-                            (now - r.arrival) * 1e3)
+                for r in finished:
+                    _rt.mark(r.rid, "retire", t=r.done_ts,
+                             reason=r.finish_reason,
+                             replica=self.trace_replica)
+            if rec and finished:
+                _obs.counter("serving.retired_total").add(len(finished))
+
+            tr.phase("admit")
+            batch = self.sched.take_admissible(
+                self.cache,
+                () if self.draft_cache is None else (self.draft_cache,))
+            tr.phase("keys")
+            # one fresh key per boundary, then DISTINCT subkeys for
+            # the two programs: prefill's _pick consumes its key
+            # directly while decode splits its own per chunk step —
+            # handing both the same key would correlate the sampled
+            # draws (greedy is unaffected)
+            key = jax.random.fold_in(self._key, self._step_no)
+            pf_key = jax.random.fold_in(key, 0)
+            dec_key = jax.random.fold_in(key, 1)
+            prefill_sig = decode_sig = None
+            chunk_sigs: List[Tuple[int, int]] = []
+            if batch:
+                shape = (self.sched.max_admit,
+                         self._prefill_batch(batch, pf_key, tr))
                 if cfg.prefix_sharing:
-                    hits = sum(1 for r in batch if r.shared_tokens)
-                    if hits:
-                        _obs.counter("serving.prefix_hits_total").add(
-                            hits)
-                        _obs.counter(
-                            "serving.prefix_shared_pages_total").add(
-                            sum(r.shared_tokens // cfg.block_size
-                                for r in batch))
+                    chunk_sigs.append(shape)
+                else:
+                    prefill_sig = shape
+            active = self.sched.active()
+            if active and self._spec_k:
+                decode_sig = (self._speculate(active, dec_key, tr),)
+                chunk_sigs.append((decode_sig[0], self._spec_k + 1))
+            elif active:
+                decode_sig = (self._decode_chunk(active, dec_key, tr),)
 
-        active = self.sched.active()
-        if active and self._spec_k:
-            # speculative boundary: draft proposes k tokens in one
-            # scan dispatch, target scores anchor + proposals in one
-            # chunk dispatch, host keeps the longest agreeing prefix.
-            # Every emitted token is a TARGET argmax over a cache
-            # prefix that held only accepted tokens — bit-identical to
-            # sequential greedy by induction; speculation can only
-            # change how many such tokens land per boundary.
-            k = self._spec_k
-            t0 = time.perf_counter()
-            b = self.ladder.pick_decode(len(active))
-            toks = np.zeros((b,), np.int32)
-            positions = np.zeros((b,), np.int32)
-            rids = []
-            for i, r in enumerate(active):
-                toks[i] = r.out[-1]
-                positions[i] = r.pos
-                rids.append(r.rid)
-            rids += [None] * (b - len(active))
-            try:
-                self.draft_cache.pools, props = self._draft_decode(
-                    self.draft_cache.pools,
-                    self.draft_cache.table_array(rids,
-                                                 cfg.table_width),
-                    toks, positions, self.draft_params, dec_key)
-            except Exception as e:
-                _mem.handle_dispatch_oom(
-                    "serving_draft", e, bucket=b,
-                    replica=self.trace_replica, step=self._step_no)
-                raise
-            props = np.asarray(props)             # [k, B]
-            t_draft = time.perf_counter()
-            ids = np.zeros((b, k + 1), np.int32)
-            lens = np.ones((b,), np.int32)
-            for i, r in enumerate(active):
-                # emission cap: proposals past the budget are junk the
-                # chunk program routes to scratch (lens masks them)
-                cap = min(k, r.max_new_tokens - len(r.out))
-                ids[i, 0] = r.out[-1]
-                ids[i, 1:] = props[:, i]
-                lens[i] = cap + 1
-            tables = self.cache.table_array(rids, cfg.table_width)
-            try:
-                self.cache.pools, all_tok, _ = self._chunk(
-                    self.cache.pools, tables, ids, positions, lens,
-                    self.params, dec_key)
-            except Exception as e:
-                _mem.handle_dispatch_oom(
-                    "serving_verify", e, bucket=b,
-                    replica=self.trace_replica, step=self._step_no)
-                raise
-            all_tok = np.asarray(all_tok)         # [B, k+1]
-            proposed = accepted = 0
-            for i, r in enumerate(active):
-                cap = int(lens[i]) - 1
-                proposed += cap
-                n = 0
-                while n < cap:
-                    tokv = int(all_tok[i, n])     # target argmax
-                    r.pos += 1
-                    r.accept(tokv)
-                    n += 1
-                    if r.done or n >= cap:
-                        break
-                    if int(props[n - 1, i]) != tokv:
-                        break   # draft diverged: later scores are
-                        #         junk-conditioned, stop here
-                accepted += n
-            chunk_sigs.append((b, k + 1))
-            decode_sig = (b,)
-            if _rt._enabled:
-                t1 = time.perf_counter()
-                tick = (self._step_no if self.trace_tick is None
-                        else self.trace_tick)
-                for r in active:
-                    _rt.record_span(r.rid, "draft", t0, t_draft,
-                                    bucket=b, k=k,
-                                    replica=self.trace_replica,
-                                    tick=tick)
-                    _rt.record_span(r.rid, "decode", t_draft, t1,
-                                    bucket=b, chunk=k + 1,
-                                    replica=self.trace_replica,
-                                    tick=tick)
+            tr.phase("observe")
+            if batch or active or tr is not _rt.NO_STEP:
+                n_exec = self.executable_count()
+            if batch or active:
+                self.sentinel.observe(
+                    n_exec, expected=self.expected_executables,
+                    signature=self._shape_signature(prefill_sig,
+                                                    decode_sig,
+                                                    chunk_sigs))
             if rec:
-                dt = (time.perf_counter() - t0) * 1e3
-                _obs.histogram("serving.decode_step_ms").observe(dt)
-                _obs.counter("serving.tokens_total").add(accepted)
-                _obs.counter("serving.spec_proposed_total").add(
-                    proposed)
-                _obs.counter("serving.spec_accepted_total").add(
-                    accepted)
-                if proposed:
-                    _obs.gauge("serving.spec_acceptance_rate").set(
-                        accepted / proposed)
-        elif active:
-            t0 = time.perf_counter()
-            b = self.ladder.pick_decode(len(active))
-            toks = np.zeros((b,), np.int32)
-            positions = np.zeros((b,), np.int32)
-            rids = []
-            for i, r in enumerate(active):
-                toks[i] = r.out[-1]
-                positions[i] = r.pos
-                rids.append(r.rid)
-            rids += [None] * (b - len(active))
-            tables = self.cache.table_array(rids, cfg.table_width)
-            try:
-                self.cache.pools, toks_out = self._decode(
-                    self.cache.pools, tables, toks, positions,
-                    self.params, dec_key)
-            except Exception as e:
-                _mem.handle_dispatch_oom(
-                    "serving_decode", e, bucket=b,
-                    replica=self.trace_replica, step=self._step_no)
-                raise
-            toks_out = np.asarray(toks_out)     # [decode_chunk, B]
-            accepted = 0
-            for i, r in enumerate(active):
-                for s in range(toks_out.shape[0]):
-                    if r.done:
-                        break   # over-decoded junk: host trims
-                    r.pos += 1
-                    r.accept(int(toks_out[s, i]))
-                    accepted += 1
-            decode_sig = (b,)
-            if _rt._enabled:
-                t1 = time.perf_counter()
-                tick = (self._step_no if self.trace_tick is None
-                        else self.trace_tick)
-                for r in active:
-                    _rt.record_span(r.rid, "decode", t0, t1,
-                                    bucket=b,
-                                    chunk=int(toks_out.shape[0]),
-                                    replica=self.trace_replica,
-                                    tick=tick)
-            if rec:
-                dt = (time.perf_counter() - t0) * 1e3
-                _obs.histogram("serving.decode_step_ms").observe(dt)
-                _obs.counter("serving.tokens_total").add(accepted)
-
-        if batch or active:
-            self.sentinel.observe(
-                self.executable_count(),
-                expected=self.expected_executables,
-                signature=self._shape_signature(prefill_sig,
-                                                decode_sig,
-                                                chunk_sigs))
-        if rec:
-            _obs.gauge("serving.queue_depth").set(self.sched.queue_depth)
-            _obs.gauge("serving.active_slots").set(
-                len(self.sched.active()))
-            _obs.gauge("serving.pages_free").set(self.cache.n_free)
-            _obs.gauge("serving.pages_live").set(self.cache.n_live)
-            if cfg.prefix_sharing:
-                _obs.gauge("serving.pages_shared").set(
-                    self.cache.n_shared)
+                _obs.gauge("serving.queue_depth").set(
+                    self.sched.queue_depth)
+                _obs.gauge("serving.active_slots").set(
+                    len(self.sched.active()))
+                _obs.gauge("serving.pages_free").set(self.cache.n_free)
+                _obs.gauge("serving.pages_live").set(self.cache.n_live)
+                if cfg.prefix_sharing:
+                    _obs.gauge("serving.pages_shared").set(
+                        self.cache.n_shared)
+        finally:
+            # also when a dispatch raised: no annotation stays entered
+            tr.close(n_exec)
         return finished
+
+    def _dispatch(self, tr, kind, bucket, width, fn, cache, args,
+                  params, key, take):
+        """Call one compiled program (``kind``; ``bucket`` x ``width``
+        is its shape) over ``cache``'s pools and fetch output ``take``
+        (None: nothing is fetched — the draft's prompt prefill). The
+        one place that writes the ``dispatch`` (the jitted call, until
+        it returns) and ``sync`` (the fetch that waits for the device)
+        phases and holds the OOM sentry (zero cost on the success
+        path): a RESOURCE_EXHAUSTED leaves the breadcrumb + post-mortem
+        receipt before the engine dies."""
+        tr.phase("dispatch", kind)
+        try:
+            out = fn(cache.pools, *args, params, key)
+        except Exception as e:
+            _mem.handle_dispatch_oom(
+                "serving_" + kind, e, bucket=bucket, width=width,
+                replica=self.trace_replica, step=self._step_no)
+            raise
+        cache.pools = out[0]
+        if take is None:
+            return None
+        tr.phase("sync", kind)
+        return np.asarray(out[take])
+
+    def _pack_prompts(self, batch, bucket: int, skip=None):
+        """[max_admit, bucket] ids and true lengths of each request's
+        prompt (past its first ``skip[i]`` tokens), zero-padded."""
+        a = self.sched.max_admit
+        ids = np.zeros((a, bucket), np.int32)
+        lens = np.ones((a,), np.int32)
+        for i, r in enumerate(batch):
+            tail = r.ids if skip is None else r.ids[skip[i]:]
+            ids[i, :tail.size] = tail
+            lens[i] = tail.size
+        return ids, lens
+
+    def _pack_slots(self, active, b: int):
+        """Last token, position and rid of every active slot, padded to
+        the decode bucket."""
+        toks = np.zeros((b,), np.int32)
+        positions = np.zeros((b,), np.int32)
+        for i, r in enumerate(active):
+            toks[i] = r.out[-1]
+            positions[i] = r.pos
+        rids = [r.rid for r in active] + [None] * (b - len(active))
+        return toks, positions, rids
+
+    def _span_tick(self) -> int:
+        return self._step_no if self.trace_tick is None \
+            else self.trace_tick
+
+    def _prefill_batch(self, batch, key, tr) -> int:
+        """Pages for the admit batch, ONE bucketed prefill of it, and
+        each request's first token. Returns the bucket."""
+        cfg = self.config
+        a = self.sched.max_admit
+        W = cfg.table_width
+        t0 = time.perf_counter()
+        tr.phase("alloc", t=t0)
+        if cfg.prefix_sharing:
+            # radix admission: longest indexed prompt prefix rides
+            # shared pages (refcount++), fresh pages cover the rest
+            for r in batch:
+                _, r.shared_tokens = self.cache.alloc_shared(
+                    r.rid, r.total_tokens, r.ids)
+        else:
+            for r in batch:
+                self.cache.alloc(r.rid, r.total_tokens)
+        t_match = time.perf_counter()
+        rids = [r.rid for r in batch] + [None] * (a - len(batch))
+        if self.draft_cache is not None:
+            # the draft mirrors the target position-for-position;
+            # its cache never shares, so it prefills the FULL
+            # prompt regardless of the target's prefix hits
+            for r in batch:
+                self.draft_cache.alloc(r.rid, r.total_tokens)
+            sd = self.ladder.pick_prefill(
+                max(r.prompt_len for r in batch))
+            tr.phase("build", "draft")
+            d_ids, d_lens = self._pack_prompts(batch, sd)
+            self._dispatch(
+                tr, "draft", sd, a, self._draft_prefill,
+                self.draft_cache,
+                (self.draft_cache.table_array(rids, W), d_ids, d_lens),
+                self.draft_params, key, None)
+        # under sharing each row forwards ONLY its unshared tail
+        # through the chunk program, starting at its shared-token
+        # offset and attending the shared pages through the same table
+        # gather decode uses (a full miss is starts=0 — a dense prefill
+        # with junk routed to scratch instead of page-scattered)
+        skip = [r.shared_tokens if cfg.prefix_sharing else 0
+                for r in batch]
+        s = self.ladder.pick_prefill(
+            max(r.prompt_len - n for r, n in zip(batch, skip)))
+        tr.phase("build", "prefill")
+        ids, lens = self._pack_prompts(batch, s, skip)
+        tables = self.cache.table_array(rids, W)
+        if cfg.prefix_sharing:
+            starts = np.zeros((a,), np.int32)
+            starts[:len(batch)] = skip
+            tok = self._dispatch(tr, "prefill", s, a, self._chunk,
+                                 self.cache, (tables, ids, starts, lens),
+                                 self.params, key, 2)
+        else:
+            tok = self._dispatch(tr, "prefill", s, a, self._prefill,
+                                 self.cache, (tables, ids, lens),
+                                 self.params, key, 1)
+        now = time.perf_counter()
+        tr.phase("accept", "prefill", now)
+        for i, r in enumerate(batch):
+            r.admitted_ts = t0
+            r.first_token_ts = now
+            r.pos = r.prompt_len
+            r.accept(int(tok[i]))
+        if cfg.prefix_sharing:
+            # adopt this prompt's full-chunk pages into the radix
+            # index AFTER the prefill landed their K/V — the NEXT
+            # request with this prefix shares them
+            for r in batch:
+                self.cache.register_prefix(r.rid, r.ids)
+        if _rt._enabled:
+            tick = self._span_tick()
+            for r in batch:
+                if r.shared_tokens:
+                    # the radix-match + shared-alloc slice of
+                    # admission, so tail attribution sees sharing
+                    # cost (and benefit) by name
+                    _rt.record_span(
+                        r.rid, "prefix_match", t0, t_match,
+                        shared_tokens=r.shared_tokens,
+                        replica=self.trace_replica, tick=tick)
+                _rt.record_span(r.rid, "prefill",
+                                t_match if r.shared_tokens else t0,
+                                now, bucket=s, width=a,
+                                replica=self.trace_replica, tick=tick)
+        if _obs._enabled:
+            _obs.counter("serving.admitted_total").add(len(batch))
+            if cfg.prefix_sharing:
+                hits = sum(1 for r in batch if r.shared_tokens)
+                if hits:
+                    _obs.counter("serving.prefix_hits_total").add(hits)
+                    _obs.counter(
+                        "serving.prefix_shared_pages_total").add(
+                        sum(r.shared_tokens // cfg.block_size
+                            for r in batch))
+        return s
+
+    def _decode_chunk(self, active, key, tr) -> int:
+        """One chunked decode dispatch for every active slot. Returns
+        the slot bucket."""
+        cfg = self.config
+        b = self.ladder.pick_decode(len(active))
+        t0 = time.perf_counter()
+        tr.phase("build", "decode", t0)
+        toks, positions, rids = self._pack_slots(active, b)
+        tables = self.cache.table_array(rids, cfg.table_width)
+        toks_out = self._dispatch(          # [decode_chunk, B]
+            tr, "decode", b, int(cfg.decode_chunk), self._decode,
+            self.cache, (tables, toks, positions), self.params, key, 1)
+        tr.phase("accept", "decode")
+        accepted = 0
+        for i, r in enumerate(active):
+            for s in range(toks_out.shape[0]):
+                if r.done:
+                    break   # over-decoded junk: host trims
+                r.pos += 1
+                r.accept(int(toks_out[s, i]))
+                accepted += 1
+        if _rt._enabled:
+            t1 = time.perf_counter()
+            tick = self._span_tick()
+            for r in active:
+                _rt.record_span(r.rid, "decode", t0, t1, bucket=b,
+                                chunk=int(toks_out.shape[0]),
+                                replica=self.trace_replica, tick=tick)
+        if _obs._enabled:
+            _obs.counter("serving.tokens_total").add(accepted)
+        return b
+
+    def _speculate(self, active, key, tr) -> int:
+        """One speculative boundary: draft proposes k tokens in one
+        scan dispatch, target scores anchor + proposals in one chunk
+        dispatch, host keeps the longest agreeing prefix. Every
+        emitted token is a TARGET argmax over a cache prefix that held
+        only accepted tokens — bit-identical to sequential greedy by
+        induction; speculation can only change how many such tokens
+        land per boundary. Returns the slot bucket."""
+        cfg = self.config
+        k = self._spec_k
+        W = cfg.table_width
+        b = self.ladder.pick_decode(len(active))
+        t0 = time.perf_counter()
+        tr.phase("build", "draft", t0)
+        toks, positions, rids = self._pack_slots(active, b)
+        props = self._dispatch(             # [k, B]
+            tr, "draft", b, k, self._draft_decode, self.draft_cache,
+            (self.draft_cache.table_array(rids, W), toks, positions),
+            self.draft_params, key, 1)
+        t_draft = time.perf_counter()
+        tr.phase("build", "verify", t_draft)
+        ids = np.zeros((b, k + 1), np.int32)
+        lens = np.ones((b,), np.int32)
+        for i, r in enumerate(active):
+            # emission cap: proposals past the budget are junk the
+            # chunk program routes to scratch (lens masks them)
+            cap = min(k, r.max_new_tokens - len(r.out))
+            ids[i, 0] = r.out[-1]
+            ids[i, 1:] = props[:, i]
+            lens[i] = cap + 1
+        tables = self.cache.table_array(rids, W)
+        all_tok = self._dispatch(           # [B, k+1]
+            tr, "verify", b, k + 1, self._chunk, self.cache,
+            (tables, ids, positions, lens), self.params, key, 1)
+        tr.phase("accept", "verify")
+        proposed = accepted = 0
+        for i, r in enumerate(active):
+            cap = int(lens[i]) - 1
+            proposed += cap
+            n = 0
+            while n < cap:
+                tokv = int(all_tok[i, n])     # target argmax
+                r.pos += 1
+                r.accept(tokv)
+                n += 1
+                if r.done or n >= cap:
+                    break
+                if int(props[n - 1, i]) != tokv:
+                    break   # draft diverged: later scores are
+                    #         junk-conditioned, stop here
+            accepted += n
+        if _rt._enabled:
+            t1 = time.perf_counter()
+            tick = self._span_tick()
+            for r in active:
+                _rt.record_span(r.rid, "draft", t0, t_draft, bucket=b,
+                                k=k, replica=self.trace_replica,
+                                tick=tick)
+                _rt.record_span(r.rid, "decode", t_draft, t1, bucket=b,
+                                chunk=k + 1,
+                                replica=self.trace_replica, tick=tick)
+        if _obs._enabled:
+            _obs.counter("serving.tokens_total").add(accepted)
+            _obs.counter("serving.spec_proposed_total").add(proposed)
+            _obs.counter("serving.spec_accepted_total").add(accepted)
+            if proposed:
+                _obs.gauge("serving.spec_acceptance_rate").set(
+                    accepted / proposed)
+        return b
 
     # -- fleet surface: eviction + hot weight swap ---------------------------
     def evict_requests(self) -> List[Request]:

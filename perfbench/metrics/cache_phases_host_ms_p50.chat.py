@@ -1,0 +1,7 @@
+"""Per engine step the host time in alloc and build (page allocation,
+id arrays, block tables); median over the window's steps."""
+from perfbench.lib import step_spans
+
+
+def read(ctx):
+    return step_spans.phases_ms_p50(ctx, step_spans.CACHE_PHASES)

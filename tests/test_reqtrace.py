@@ -16,7 +16,12 @@ Receipts pinned here:
   window, -1 on no data, multi-window alert only when EVERY window
   burns past the bar;
 - serving_breach_verdict priorities: replica death (kill > covert
-  stall) > recompile > overload shed > swap flip > dominant component.
+  stall) > recompile > overload shed > swap flip > dominant component;
+- engine steps: every working step is one ``step`` span over
+  contiguous, ordered phases that share its ``step``; the requests'
+  spans keep the fields and stamps they had; step events stay out of
+  the request readers; one ``serve:`` annotation per ring phase.
+  Structure only: no duration is asserted.
 """
 import time
 
@@ -81,6 +86,28 @@ def test_disabled_record_under_one_microsecond():
     med = sorted(medians)[len(medians) // 2]
     assert med < 1e-6, f"disabled mark costs {med * 1e9:.0f}ns"
     assert rt.get_tracer().events() == []   # and stored nothing
+
+
+def test_disabled_step_helpers_under_one_microsecond():
+    """The step writer's sites are in ``ServingEngine.step()`` for
+    good: off, ``open_step`` is a bool read and hands out ``NO_STEP``,
+    whose ``phase`` and ``close`` are empty methods."""
+    assert not rt.enabled()
+    n = 10000
+    off = rt.open_step(1, 0)
+    assert off is rt.NO_STEP
+    for name, call in (("open_step", lambda: rt.open_step(1, 0)),
+                       ("phase", lambda: off.phase("build", "decode")),
+                       ("close", lambda: off.close(3))):
+        medians = []
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                call()
+            medians.append((time.perf_counter() - t0) / n)
+        med = sorted(medians)[len(medians) // 2]
+        assert med < 1e-6, f"disabled {name} costs {med * 1e9:.0f}ns"
+    assert rt.get_tracer().events() == []
 
 
 def test_ring_wraps_newest_wins_and_reset():
@@ -347,6 +374,280 @@ def test_engine_spans_and_export_determinism(model):
         assert len(pf) == 1 and pf[0]["bucket"] in (8, 16)
     seqs_b, _ = _run_traced(model, rids)
     assert seqs_a == seqs_b
+
+
+# -- engine steps: the step span and its phases --------------------------------
+
+def _steps_of(evts):
+    """{step: (step span, [phase spans in ring order])}."""
+    out = {}
+    for e in evts:
+        if e.get("comp") == "step":
+            out.setdefault(e["step"], [None, []])[0] = e
+        elif e.get("parent") == "step":
+            out.setdefault(e["step"], [None, []])[1].append(e)
+    return out
+
+
+def _drive(eng, specs=((3, 4), (7, 6), (5, 5), (12, 4)), seed=0):
+    """The fixed request set of `_run_traced` through `eng`, one
+    step() at a time; returns how many steps had work."""
+    rng = np.random.RandomState(seed)
+    for i, (L, n) in enumerate(specs):
+        eng.submit(rng.randint(0, 97, (L,)).astype(np.int32), n,
+                   rid=f"q{i}")
+    working = 0
+    while eng.has_work():
+        working += 1
+        eng.step()
+    return working
+
+
+def _engine(model, lever):
+    if lever == "speculative":
+        return ServingEngine(model, engine_config(speculative_k=2),
+                             draft_model=model).warmup()
+    kw = {"prefix_sharing": True} if lever == "prefix_sharing" else {}
+    return ServingEngine(model, engine_config(**kw)).warmup()
+
+
+@pytest.mark.parametrize("lever", ["plain", "prefix_sharing",
+                                   "speculative"])
+def test_every_working_step_is_one_step_span_over_ordered_phases(
+        model, lever):
+    rt.enable()
+    eng = _engine(model, lever)
+    rt.reset()
+    working = _drive(eng)
+    steps = _steps_of(rt.get_tracer().events())
+    assert len(steps) == working
+    order = {n: i for i, n in enumerate(rt.STEP_PHASES)}
+    kinds_seen = set()
+    for n, (whole, phases) in steps.items():
+        assert whole is not None and whole["rid"] is None
+        assert set(whole) == {"i", "rid", "comp", "t0", "t1", "step",
+                              "replica", "executables"}
+        names = [p["comp"] for p in phases]
+        assert names[:3] == ["retire", "admit", "keys"]
+        assert names[-1] == "observe" and names.count("observe") == 1
+        # per dispatch: [alloc] build dispatch [sync accept], in the
+        # stated order; a new `build` (or `alloc`) starts the next one
+        body = names[3:-1]
+        for a, b in zip(body, body[1:]):
+            assert order[b] > order[a] or b in ("alloc", "build"), names
+        assert body.count("build") == body.count("dispatch") >= \
+            body.count("sync") >= body.count("accept")
+        # contiguous, inside the step, sharing its `step`
+        assert phases[0]["t0"] >= whole["t0"]
+        assert phases[-1]["t1"] == whole["t1"]
+        for a, b in zip(phases, phases[1:]):
+            assert a["t1"] == b["t0"]
+        for ph in phases:
+            assert ph["step"] == n and ph["t1"] >= ph["t0"]
+            fields = {"i", "rid", "comp", "t0", "t1", "step", "replica",
+                      "parent"}
+            if ph["comp"] in ("build", "dispatch", "sync", "accept"):
+                fields.add("kind")
+                kinds_seen.add(ph["kind"])
+            assert set(ph) == fields
+    assert kinds_seen == ({"draft", "verify", "prefill"}
+                          if lever == "speculative"
+                          else {"prefill", "decode"})
+    # the count of a step is taken at its own boundary
+    assert all(w["executables"] == eng.executable_count()
+               for w, _ in steps.values())
+
+
+def test_request_spans_keep_their_fields_and_stamps(model):
+    """`admission`, `prefill` and `decode` spans are field for field
+    what the engine wrote before the step spans existed (rid, t0, t1,
+    bucket, width|chunk, replica, tick); their stamps are the
+    phases' of the step their `tick` names."""
+    rt.enable()
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.reset()
+    _drive(eng)
+    evts = rt.get_tracer().events()
+    steps = _steps_of(evts)
+    base = {"i", "rid", "comp", "t0", "t1", "replica", "tick", "bucket"}
+    admits = [ph for _, phases in steps.values() for ph in phases
+              if ph["comp"] == "admit"]
+    n_prefill = n_decode = n_admission = 0
+    for e in evts:
+        if e.get("comp") == "admission":
+            n_admission += 1
+            assert set(e) == {"i", "rid", "comp", "t0", "t1"}
+            assert sum(ph["t0"] <= e["t1"] <= ph["t1"]
+                       for ph in admits) == 1
+        if e.get("comp") not in ("prefill", "decode"):
+            continue
+        assert e["replica"] is None
+        phases = {}
+        for ph in steps[e["tick"]][1]:
+            if ph.get("kind") == e["comp"]:
+                phases.setdefault(ph["comp"], ph)
+            elif ph["comp"] == "alloc":
+                phases["alloc"] = ph
+        if e["comp"] == "prefill":
+            n_prefill += 1
+            assert set(e) == base | {"width"}
+            # two to a batch; the bucket is its longest prompt's
+            assert (e["bucket"], e["width"]) == (
+                {"q0": 8, "q1": 8, "q2": 16, "q3": 16}[e["rid"]], 2)
+            assert e["t0"] == phases["alloc"]["t0"]
+            assert e["t1"] == phases["sync"]["t1"]
+        else:
+            n_decode += 1
+            assert set(e) == base | {"chunk"}
+            assert (e["bucket"], e["chunk"]) == (4, 2)
+            assert e["t0"] == phases["build"]["t0"]
+            assert phases["accept"]["t0"] <= e["t1"] <= \
+                phases["accept"]["t1"]
+    assert n_admission == n_prefill == 4 and n_decode > 4
+
+
+def _fixed_request_events():
+    return [
+        {"i": 0, "rid": "fast", "mark": "submit", "t": 0.0},
+        {"i": 1, "rid": "fast", "comp": "decode", "t0": 0.0, "t1": 1.0,
+         "replica": 0},
+        {"i": 2, "rid": "fast", "mark": "retire", "t": 1.0},
+        {"i": 3, "rid": "slow", "mark": "submit", "t": 0.0},
+        {"i": 4, "rid": "slow", "comp": "queue", "t0": 0.0, "t1": 8.0,
+         "replica": 1},
+        {"i": 5, "rid": "slow", "comp": "decode", "t0": 8.0, "t1": 10.0,
+         "replica": 1},
+        {"i": 6, "rid": "slow", "mark": "retire", "t": 10.0},
+    ]
+
+
+def test_explain_tail_unchanged_by_interleaved_step_events():
+    """Step events are not requests: a `step` span a hundred seconds
+    long and phases named like marks (`retire`, `dispatch`) move
+    nothing in timelines, attribute or explain_tail."""
+    plain = _fixed_request_events()
+    mixed = []
+    for e in plain:
+        mixed.append(e)
+        mixed.append({"i": 100 + e["i"], "rid": None, "comp": "step",
+                      "t0": -50.0, "t1": 50.0, "step": e["i"],
+                      "replica": 1, "executables": 3})
+        for name in rt.STEP_PHASES:
+            mixed.append({"i": 200 + e["i"], "rid": None, "comp": name,
+                          "t0": -5.0, "t1": 20.0, "step": e["i"],
+                          "replica": 1, "parent": "step"})
+    assert rt.timelines(mixed) == rt.timelines(plain)
+    assert set(rt.timelines(mixed)) == {"fast", "slow"}
+    for p in (99.0, 0.0):
+        assert rt.explain_tail(mixed, p=p) == rt.explain_tail(plain, p=p)
+
+
+def test_executables_rises_by_one_on_a_new_bucket_shape(model):
+    """`executables` on the step span is the engine's compile count at
+    that boundary: a cold engine's first bucket-8 prefill and its
+    decode make 2, the first bucket-16 prompt one more, and a step that
+    only decodes none."""
+    rt.enable()
+    eng = ServingEngine(model, engine_config())      # no warmup
+    rng = np.random.RandomState(1)
+    eng.submit(rng.randint(0, 97, (5,)).astype(np.int32), 6, rid="a")
+    eng.step()
+    eng.step()
+    eng.submit(rng.randint(0, 97, (12,)).astype(np.int32), 2, rid="b")
+    eng.step()
+    eng.step()
+    counts = [w["executables"] for _, (w, _) in
+              sorted(_steps_of(rt.get_tracer().events()).items())]
+    assert counts == [2, 2, 3, 3]
+
+
+def test_serve_annotation_entered_once_per_ring_phase(model,
+                                                      monkeypatch):
+    """One writer, two clocks: each ring phase (and each step) entered
+    and left exactly one `serve:<name>` profiler annotation carrying
+    the same `step`; the requests' spans entered none."""
+    entered, left = [], []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            self.key = (name, kw.get("step"))
+
+        def __enter__(self):
+            entered.append(self.key)
+            return self
+
+        def __exit__(self, *exc):
+            left.append(self.key)
+
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.enable()
+    monkeypatch.setattr(rt, "_TraceAnnotation", Counting)
+    rt.reset()
+    _drive(eng)
+    ring = [("serve:" + e["comp"], e["step"])
+            for e in rt.get_tracer().events() if e.get("rid") is None
+            and "comp" in e]
+    assert ring and sorted(entered) == sorted(ring) == sorted(left)
+    # and with tracing off the engine enters none
+    rt.disable()
+    del entered[:]
+    _drive(eng, seed=2)
+    assert entered == []
+
+
+def test_a_step_that_raises_leaves_no_annotation_entered(
+        model, monkeypatch):
+    """The engine closes the step in a ``finally``: a dispatch that
+    raises still leaves every ``serve:`` annotation it entered, and the
+    ring holds the step up to the phase that failed."""
+    depth = []
+
+    class Counting:
+        def __init__(self, name, **kw):
+            pass
+
+        def __enter__(self):
+            depth.append(1)
+
+        def __exit__(self, *exc):
+            depth.pop()
+
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.enable()
+    monkeypatch.setattr(rt, "_TraceAnnotation", Counting)
+    rt.reset()
+
+    def boom(*a, **kw):
+        assert len(depth) == 2      # serve:step over serve:dispatch
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(eng, "_prefill", boom)
+    eng.submit(np.arange(5, dtype=np.int32), 3, rid="x")
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.step()
+    assert depth == []
+    (whole, phases), = _steps_of(rt.get_tracer().events()).values()
+    assert [p["comp"] for p in phases] == [
+        "retire", "admit", "keys", "alloc", "build", "dispatch"]
+    assert whole["executables"] is None
+    assert phases[-1]["t1"] == whole["t1"]
+
+
+def test_chrome_trace_draws_steps_on_lanes_of_their_own(model):
+    rt.enable()
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.reset()
+    _drive(eng)
+    evs = rt.chrome_trace_events()
+    names = {e["tid"]: e["args"]["name"] for e in evs if e["ph"] == "M"}
+    assert sorted(names.values()) == ["engine steps 0",
+                                      "serving replica 0"]
+    step_tid = next(t for t, n in names.items() if n.startswith("eng"))
+    on_lane = {e["name"] for e in evs
+               if e["ph"] == "X" and e["tid"] == step_tid}
+    assert on_lane == {"step"} | set(rt.STEP_PHASES)
+    assert all(":" in e["name"] for e in evs
+               if e["ph"] == "X" and e["tid"] != step_tid)
 
 
 def test_tpu_doctor_serving_cli_reads_receipt(tmp_path, capsys):
