@@ -22,8 +22,11 @@ serving engine: paddle_tpu/serving/programs.py imports `_ln`, `_attend`,
 `_prefill`, `_pick` (and the engine `_gpt_params`/`_cast_params`) so the
 paged-cache decode is the same ops in the same order with only the cache
 addressing changed — that reuse is what makes the paged-vs-dense greedy
-parity contract bit-exact in f32 (tests/test_serving_engine.py). A
-change to these helpers must keep both suites green.
+parity contract bit-exact in f32 (tests/test_serving_engine.py). That
+holds of the portable path; on a TPU the engine's decode attention is
+the paged Pallas kernel, for which `_attend` over the gathered pages is
+the reference (tests/test_paged_decode_attention.py). A change to these
+helpers must keep all three suites green.
 """
 from __future__ import annotations
 

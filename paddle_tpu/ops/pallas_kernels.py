@@ -33,7 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_mha", "flash_attention_mha_sharded",
-           "pallas_available"]
+           "paged_decode_attention", "pallas_available"]
 
 # Max block sizes along the q/k sequence dims. Large blocks amortize the
 # per-grid-step overhead (DMA setup + Mosaic loop) — with head_dim 64 a
@@ -521,3 +521,148 @@ def flash_attention_mha_sharded(query, key, value, mesh, batch_axes,
         body, mesh=mesh, in_specs=(spec, spec, spec, P()),
         out_specs=spec, check_vma=False)(query, key, value,
                                          _seed_array(seed))
+
+
+# ------------------------------------------------------- paged decode
+
+# K/V pages a grid step: each is one operand window of its pool, so a
+# step's pages are fetched by the pipeline's own page-sized copies
+# while the step before is attended. On the v5e a step costs about
+# 0.3 us and 0.04 us a window whether its page changes or not, a page
+# about 0.4 us; a slot's last step is padded to this many windows, and
+# an inactive lane is a step of its own. 4, 8 and 16 read 51, 57 and
+# 69 us a call with 4 slots of 32 live and 827, 830 and 840 us with
+# every table full (PERF.md, PR 26).
+_PAGES_PER_STEP = 4
+
+
+def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
+                         q_ref, *refs, scale, bs, pps):
+    """One grid step is one round of one slot: table entries
+    round*pps .. round*pps+pps-1 in the K and V windows. A page is
+    [bs, nh, hd]: q.k is a lane reduction per (token, head), p.v a
+    lane broadcast and a sum over tokens, all in f32; the running max,
+    sum and accumulator live in scratch across a slot's rounds. A page
+    past the length is skipped (its window still holds an older page:
+    no copy was made for it either). q and the output are whole in
+    VMEM: a window of one row each would cost two small copies a slot,
+    whose latency a short step cannot hide."""
+    del fetch_ref                               # the index maps read it
+    k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
+    t = pl.program_id(0)
+    slot, rnd = slot_ref[t], round_ref[t]
+    length = lengths_ref[slot]
+    nh = q_ref.shape[1]
+    q = q_ref[slot].astype(jnp.float32) * scale                # [nh, hd]
+
+    @pl.when(rnd == 0)
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for i in range(pps):
+        first = (rnd * pps + i) * bs            # the page's first position
+
+        @pl.when(first < length)
+        def _():
+            k = k_refs[i][0].astype(jnp.float32)           # [bs, nh, hd]
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
+            pos = first + jax.lax.broadcasted_iota(
+                jnp.int32, (bs, nh, 1), 0)
+            s = jnp.where(pos < length, s, _NEG)           # [bs, nh, 1]
+            m = m_ref[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))         # [nh, 1]
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[None])
+            v = v_refs[i][0].astype(jnp.float32)
+            m_ref[...] = m_new
+            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
+            acc_ref[...] = alpha * acc_ref[...] + jnp.sum(p * v, axis=0)
+
+    @pl.when((rnd + 1) * pps * bs >= length)
+    def _():
+        o_ref[slot] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _decode_rounds(tables, lengths, bs, pps):
+    """The kernel's work list, from the tables and lengths alone (the
+    same for every layer of a token-step). A slot has
+    ceil(pages held / pps) rounds, at least one. Returns the number of
+    rounds in all, and per round (padded to the most there can be) its
+    slot, its index within the slot, and the page each of its `pps`
+    windows holds: the table entry where that is live (it holds a
+    position under the slot's length), elsewhere the page the same
+    window held the round before, so the pipeline, which copies only
+    when a window's index changes, fetches live pages and nothing else
+    (the first round may fetch pages it does not need)."""
+    b, w = tables.shape
+    most = b * (w // pps)
+    pages = -(-lengths // bs)
+    rounds = -(-pages // pps)
+    ends = jnp.cumsum(rounds)
+    at = jnp.arange(most, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1),
+                       b - 1).astype(jnp.int32)
+    rnd = at - (ends - rounds)[slot]
+    col = rnd[:, None] * pps + jnp.arange(pps, dtype=jnp.int32)[None, :]
+    live = (col < pages[slot][:, None]) & (at < ends[-1])[:, None]
+    last = jax.lax.cummax(jnp.where(live, at[:, None], 0), axis=0)
+    entry = tables[slot[:, None], jnp.minimum(col, w - 1)]
+    fetch = jnp.take_along_axis(entry, last, axis=0)
+    return ends[-1], slot, rnd.astype(jnp.int32), fetch.reshape(-1)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
+                           interpret=False):
+    """Single-token attention over a paged K/V cache, read in place.
+
+    q [B, nh, hd]; k_pool, v_pool [n_blocks, bs, nh, hd]; tables [B, W]
+    int32 (table order is logical order); lengths [B] int32, each at
+    least 1: slot b attends positions 0..lengths[b]-1, exactly. Returns
+    [B, nh, hd] in the pools' dtype. The pools stay in HBM: only pages
+    that hold a live position are read, and the grid has as many steps
+    as the slots hold rounds of pages, so the work grows with the
+    tokens held and not with the table's width. f32 scores, softmax
+    statistics and accumulation. `interpret=True` runs the kernel
+    under the Pallas interpreter (the CPU suite). Jitted, so that a
+    program that calls it once a layer traces and lowers it once: 36
+    separate calls added 4-6 s to every process's set-up."""
+    b, nh, hd = q.shape
+    bs = k_pool.shape[1]
+    pps = _PAGES_PER_STEP
+    tables = tables.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    pad = -tables.shape[1] % pps
+    if pad:
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    n_rounds, slot, rnd, fetch = _decode_rounds(tables, lengths, bs, pps)
+
+    def page(i):
+        return pl.BlockSpec(
+            (1, bs, nh, hd),
+            lambda t, slot, rnd, fetch, lens: (fetch[t * pps + i], 0, 0, 0))
+
+    whole = pl.BlockSpec((b, nh, hd), lambda t, *_: (0, 0, 0))
+    kern = functools.partial(_paged_decode_kernel, scale=float(scale),
+                             bs=bs, pps=pps)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_rounds,),
+            in_specs=[whole] + [page(i) for i in range(pps)] * 2,
+            out_specs=whole,
+            scratch_shapes=[
+                pltpu.VMEM((nh, 1), jnp.float32),
+                pltpu.VMEM((nh, 1), jnp.float32),
+                pltpu.VMEM((nh, hd), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, nh, hd), k_pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="paged_decode_attention",
+    )(slot, rnd, fetch, lengths, q, *([k_pool] * pps), *([v_pool] * pps))

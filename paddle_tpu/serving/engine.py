@@ -761,9 +761,12 @@ class ServingEngine:
         if _rt._enabled:
             t1 = time.perf_counter()
             tick = self._span_tick()
-            for r in active:
+            for i, r in enumerate(active):
+                # tokens: what the request held at the dispatch, the
+                # positions the first token-step's attention reads
                 _rt.record_span(r.rid, "decode", t0, t1, bucket=b,
                                 chunk=int(toks_out.shape[0]),
+                                tokens=int(positions[i]),
                                 replica=self.trace_replica, tick=tick)
         if _obs._enabled:
             _obs.counter("serving.tokens_total").add(accepted)

@@ -16,7 +16,15 @@ graph_lint donation rule proves the aliasing on the lowered module.
 The math reuses models/generation.py's helpers (`_ln`, `_attend`,
 `_prefill`, `_pick`) verbatim, which is what makes the paged-vs-dense
 greedy parity contract hold token-for-token in f32: same ops in the
-same order, only the cache addressing differs.
+same order, only the cache addressing differs. That is the portable
+path, and what every platform but a TPU runs. On a TPU the decode
+step's attention is `ops/pallas_kernels.paged_decode_attention`: it
+reads each slot's live pages in place through the block table (an
+online softmax in f32) instead of gathering every table whole, chosen
+by the platform as the flash kernel is, and held to the gather and
+`_attend` by tests/test_paged_decode_attention.py (f32 within 1e-5,
+the greedy token the same). The chunk program (several queries a slot)
+keeps the gather.
 
 Addressing: logical position ``p`` of a request lives in page
 ``table[p // block_size]`` at offset ``p % block_size``. Masked or
@@ -74,7 +82,8 @@ def make_decode_fn(eps: float, n_heads: int, block_size: int,
     toks [B] is each slot's last emitted token, positions [B] the
     logical index where its K/V land (== tokens held so far). The body
     mirrors generation.py's ragged decode body exactly, with the
-    dynamic_update_slice cache write swapped for the paged scatter.
+    dynamic_update_slice cache write swapped for the paged scatter
+    (and, on a TPU, the gather and `_attend` for the paged kernel).
 
     n_steps > 1 is the multi-step-scheduling lever: admission/retire
     decisions then happen every n_steps tokens instead of every token,
@@ -93,6 +102,12 @@ def make_decode_fn(eps: float, n_heads: int, block_size: int,
         b = toks.shape[0]
         hd = head_dim or params["wte"].shape[1] // n_heads
         scale = 1.0 / math.sqrt(hd)
+        # one algorithm, two executions, chosen by the platform as the
+        # flash kernel is: on a TPU the paged kernel reads the live
+        # pages in place; elsewhere the gather and _attend, which are
+        # the reference the kernel is tested against
+        from ..ops import pallas_kernels as _pk
+        on_tpu = _pk.pallas_available()
         with _scope("embed"):
             x = (params["wte"][toks]
                  + params["wpe"][positions])[:, None, :]
@@ -114,10 +129,15 @@ def make_decode_fn(eps: float, n_heads: int, block_size: int,
                 v_tok = qkv[:, 0, 2]
                 kp = kp.at[blk, off].set(k_tok)
                 vp = vp.at[blk, off].set(v_tok)
-                kc = _gathered(kp, tables, n_heads, hd)
-                vc = _gathered(vp, tables, n_heads, hd)
-                ctx = _attend(q, kc, vc, positions + 1, scale)
-                ctx = jnp.einsum("bnsh->bsnh", ctx).reshape(b, 1, -1)
+                if on_tpu:
+                    ctx = _pk.paged_decode_attention(
+                        q[:, :, 0], kp, vp, tables, positions + 1, scale)
+                else:
+                    kc = _gathered(kp, tables, n_heads, hd)
+                    vc = _gathered(vp, tables, n_heads, hd)
+                    ctx = jnp.einsum("bnsh->bsnh", _attend(
+                        q, kc, vc, positions + 1, scale))
+                ctx = ctx.reshape(b, 1, -1)
                 proj = _mm(ctx, bp, "proj")
                 if tp_reduce is not None:
                     proj = tp_reduce(proj)

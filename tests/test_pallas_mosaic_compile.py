@@ -130,3 +130,118 @@ def test_sharded_train_step_lowers_for_v5e_with_the_kernel_on(monkeypatch):
     assert len(calls) >= 3
     rows = (8 // 2) * (4 // 2)
     assert all(f"[{rows},128," in ln for ln in calls), calls[0][:300]
+
+
+# ------------------------------------------------- paged decode attention
+
+# the chat cell's engine shape (perfbench/configs/gpt2-large.json)
+SLOTS, TABLE_W, PAGE, HEADS, HEAD = 32, 64, 16, 20, 64
+
+
+def _pool(n_pages, heads=HEADS, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct((n_pages, PAGE, heads, HEAD), dtype)
+
+
+@pytest.mark.parametrize("heads,dtype", [
+    (HEADS, jnp.bfloat16), (HEADS // 2, jnp.bfloat16),   # tp=2's shard
+    (HEADS, jnp.float32)], ids=["bf16_nh20", "bf16_nh10", "f32_nh20"])
+def test_paged_decode_kernel_compiles_for_one_v5e_chip(heads, dtype):
+    """Head 64 and 20 (or 10) heads a page are no multiples of the
+    (8, 128) tile: the page windows are whole in their last two
+    dimensions, which Mosaic takes where it refuses a sliced copy."""
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    q = jax.ShapeDtypeStruct((SLOTS, heads, HEAD), dtype)
+    pool = _pool(1024, heads, dtype)
+    tables = jax.ShapeDtypeStruct((SLOTS, TABLE_W), jnp.int32)
+    lengths = jax.ShapeDtypeStruct((SLOTS,), jnp.int32)
+
+    def attn(q, kp, vp, tables, lengths):
+        return pk.paged_decode_attention(q, kp, vp, tables, lengths, 0.125)
+    text = _compile(attn, (q, pool, pool, tables, lengths),
+                    (one,) * 5).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "paged_decode_attention" in text
+
+
+def _gpt2_large_decode_avals(n_layers, n_pages, pool_at, rep, param_at):
+    """make_decode_fn's arguments at GPT-2-large's widths as shapes:
+    pools placed by `pool_at`, block tables, tokens, positions and the
+    key by `rep`, a parameter named `name` by `param_at(name)`."""
+    def s(shape, sharding, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    h, vocab, ctx = HEADS * HEAD, 50304, 1024
+    shapes = {"ln1_w": (h,), "ln1_b": (h,), "ln2_w": (h,), "ln2_b": (h,),
+              "qkv_w": (h, 3 * h), "qkv_b": (3 * h,), "proj_w": (h, h),
+              "proj_b": (h,), "fc1_w": (h, 4 * h), "fc1_b": (4 * h,),
+              "fc2_w": (4 * h, h), "fc2_b": (h,)}
+    block = {k: s(v, param_at(k)) for k, v in shapes.items()}
+    params = {"wte": s((vocab, h), rep), "wpe": s((ctx, h), rep),
+              "lnf_w": s((h,), rep), "lnf_b": s((h,), rep),
+              "blocks": [block] * n_layers}
+    pool = s((n_pages, PAGE, HEADS, HEAD), pool_at)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return (((pool, pool),) * n_layers,
+            s((SLOTS, TABLE_W), rep, jnp.int32), s((SLOTS,), rep, jnp.int32),
+            s((SLOTS,), rep, jnp.int32), params,
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep))
+
+
+@pytest.mark.parametrize("n_pages", [1024, 2048])
+def test_decode_program_reads_pages_in_place_on_a_v5e(n_pages, monkeypatch,
+                                                      capsys):
+    """The whole decode program at the chat cell's shapes (36 layers
+    cut to 2 for time), with the kernel chosen as on a TPU: no buffer
+    of every slot's whole block table, [32 x 64 pages, 16, 20, 64], is
+    left; one Mosaic call a layer. Prints the program's temporaries
+    (PERF.md has them at 36 layers beside the parent's)."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_decode_fn)
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    n_layers = 2
+    fn = jit_with_donated_pools(make_decode_fn(
+        1e-5, HEADS, PAGE, 0.0, None, None, n_steps=4))
+    compiled = fn.trace(*_gpt2_large_decode_avals(
+        n_layers, n_pages, one, one, lambda name: one)).lower(
+            lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    # neither the gathered tables nor their re-layout (at 2,048 pages a
+    # pool has the re-layout's shape itself)
+    assert f"[{SLOTS},{TABLE_W},{PAGE},{HEADS},{HEAD}]" not in text
+    if n_pages != SLOTS * TABLE_W:
+        assert f"[{SLOTS * TABLE_W},{PAGE},{HEADS},{HEAD}]" not in text
+    assert text.count("tpu_custom_call") == n_layers
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\ndecode program, {n_layers} layers, {n_pages} pages: "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB, "
+              f"arguments {mem.argument_size_in_bytes / 1e6:.1f} MB")
+
+
+def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
+    """Under `MeshPlan(tp=2)` the same body runs inside a shard_map
+    over 'tp': each chip's kernel call sees its 10 of the 20 heads of
+    every page, and nothing gathers the pools."""
+    from paddle_tpu.distributed.sharding import (SERVING_POOL_SPEC,
+                                                 SERVING_TP_RULES)
+    from paddle_tpu.serving.programs import (jit_tp_with_donated_pools,
+                                             make_decode_fn)
+    mesh = Mesh(np.asarray(_v5e_devices()[:2]), ("tp",))
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    n_layers, tp = 2, 2
+    at = lambda spec: NamedSharding(mesh, spec)
+    avals = _gpt2_large_decode_avals(
+        n_layers, 1024, at(SERVING_POOL_SPEC), at(P()),
+        lambda name: at(SERVING_TP_RULES.get(name, P())))
+    specs = jax.tree_util.tree_map(lambda a: a.sharding.spec, avals[4])
+    fn = jit_tp_with_donated_pools(
+        make_decode_fn(1e-5, HEADS // tp, PAGE, 0.0, None, None, n_steps=4,
+                       qkv_heads_major=True, head_dim=HEAD,
+                       tp_reduce=lambda t: jax.lax.psum(t, "tp")),
+        mesh, specs, n_plain=3, n_out=2)
+    text = fn.trace(*avals).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == n_layers
+    assert all(f"bf16[{SLOTS},{HEADS // tp},{HEAD}]" in ln for ln in calls)
+    assert "all-gather" not in text

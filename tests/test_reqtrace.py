@@ -473,6 +473,8 @@ def test_request_spans_keep_their_fields_and_stamps(model):
     admits = [ph for _, phases in steps.values() for ph in phases
               if ph["comp"] == "admit"]
     n_prefill = n_decode = n_admission = 0
+    prompt = {"q0": 3, "q1": 7, "q2": 5, "q3": 12}
+    seen = dict.fromkeys(prompt, 0)
     for e in evts:
         if e.get("comp") == "admission":
             n_admission += 1
@@ -498,8 +500,12 @@ def test_request_spans_keep_their_fields_and_stamps(model):
             assert e["t1"] == phases["sync"]["t1"]
         else:
             n_decode += 1
-            assert set(e) == base | {"chunk"}
+            assert set(e) == base | {"chunk", "tokens"}
             assert (e["bucket"], e["chunk"]) == (4, 2)
+            # what the request held at the dispatch: its prompt, and
+            # a chunk more with every decode before this one
+            assert e["tokens"] == prompt[e["rid"]] + 2 * seen[e["rid"]]
+            seen[e["rid"]] += 1
             assert e["t0"] == phases["build"]["t0"]
             assert phases["accept"]["t0"] <= e["t1"] <= \
                 phases["accept"]["t1"]
