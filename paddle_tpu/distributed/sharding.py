@@ -905,9 +905,11 @@ SERVING_TP_RULES = {
     "fc2_w": P("tp", None), "fc2_b": P(),
 }
 
-#: the paged K/V page pools [n_blocks, block_size, n_heads, hd] shard
-#: over the heads axis — each chip holds exactly 1/tp of every page
-SERVING_POOL_SPEC = P(None, None, "tp", None)
+#: the paged K/V page pools [n_blocks, block_size, n_heads * hd] shard
+#: over the merged heads axis — each chip holds exactly 1/tp of every
+#: page: its own whole heads, (n_heads/tp) * hd contiguous lanes (the
+#: heads-major qkv layout gives a chip a contiguous group of heads)
+SERVING_POOL_SPEC = P(None, None, "tp")
 
 
 def permute_qkv_heads(arr, n_heads):

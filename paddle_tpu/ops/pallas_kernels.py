@@ -527,34 +527,67 @@ def flash_attention_mha_sharded(query, key, value, mesh, batch_axes,
 
 # K/V pages a grid step: each is one operand window of its pool, so a
 # step's pages are fetched by the pipeline's own page-sized copies
-# while the step before is attended. On the v5e a step costs about
-# 0.3 us and 0.04 us a window whether its page changes or not, a page
-# about 0.4 us; a slot's last step is padded to this many windows, and
-# an inactive lane is a step of its own. 4, 8 and 16 read 51, 57 and
-# 69 us a call with 4 slots of 32 live and 827, 830 and 840 us with
-# every table full (PERF.md, PR 26).
-_PAGES_PER_STEP = 4
+# while the step before is attended. A slot's last step is padded to
+# this many windows, and an inactive lane is a step of its own. On the
+# v5e a step costs about 0.8 us whatever it holds (two small matmuls
+# and the accumulator's update) and a window about 0.02 us, so more
+# pages a step means fewer steps for long slots and a dearer step for
+# an inactive lane: 4, 8 and 16 read 25, 28 and 35 us a call with no
+# slot of 32 live, 30, 30 and 36 with 4 live, 53, 41 and 38 with 18,
+# 303, 175 and 123 with every table full (PERF.md, PR 29).
+_PAGES_PER_STEP = 8
+
+
+def _dot_f32(a, b, dims, exact=False):
+    """f32 `a` times `b` on the MXU, accumulated in f32, without
+    rounding `a` to b's precision: f32 pages take the f32 matmul
+    (`HIGHEST`); 16-bit pages take `a` as two 16-bit terms, high and
+    remainder, stacked into one matmul (one pass over `b`), or as one
+    term where the caller knows `a` to be `exact` in b's dtype."""
+    dims = (dims, ((), ()))
+    if b.dtype == jnp.float32:
+        return jax.lax.dot_general(
+            a, b, dims, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+    hi = a.astype(b.dtype)
+    lhs = hi if exact else jnp.concatenate(
+        [hi, (a - hi.astype(jnp.float32)).astype(b.dtype)], axis=0)
+    out = jax.lax.dot_general(lhs, b, dims,
+                              precision=jax.lax.Precision.DEFAULT,
+                              preferred_element_type=jnp.float32)
+    n = a.shape[0]
+    return out if exact else out[:n] + out[n:]
 
 
 def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
-                         q_ref, *refs, scale, bs, pps):
+                         q_ref, sel_ref, *refs, scale, bs, pps, q_exact):
     """One grid step is one round of one slot: table entries
     round*pps .. round*pps+pps-1 in the K and V windows. A page is
-    [bs, nh, hd]: q.k is a lane reduction per (token, head), p.v a
-    lane broadcast and a sum over tokens, all in f32; the running max,
-    sum and accumulator live in scratch across a slot's rounds. A page
-    past the length is skipped (its window still holds an older page:
-    no copy was made for it either). q and the output are whole in
-    VMEM: a window of one row each would cost two small copies a slot,
-    whose latency a short step cannot hide."""
+    [bs, nh*hd], one row a token with the heads side by side in the
+    lanes, so a step's pages stack into [T, nh*hd] (T = pps*bs) with
+    full tiles, and the per-head sums inside a row are matmuls on the
+    otherwise idle MXU. `sel` [nhp, nh*hd] holds a 1 where lane d
+    belongs to head h (heads padded to nhp rows): sel * q puts each
+    head's query in its own row, its product with K over the lanes is
+    the scores [nhp, T] (heads in sublanes, tokens in lanes), and
+    p [nhp, T] times V is every head's weighting of every lane,
+    [nhp, nh*hd], of which row h is wanted in head h's lanes only: the
+    accumulator keeps that form over a slot's rounds and `sel` picks
+    the diagonal once, at the slot's end. Scores, the running max and
+    sum, and the accumulator are f32 (`_dot_f32`; `q_exact`: q came in
+    the pages' dtype, so sel * q is exact in it). A window past the
+    length still holds an older page (no copy was made for it): its
+    positions are masked, so it weighs exactly 0. q and the output are
+    whole in VMEM, f32 so that one row can be read and written at a
+    dynamic sublane."""
     del fetch_ref                               # the index maps read it
     k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
     t = pl.program_id(0)
     slot, rnd = slot_ref[t], round_ref[t]
     length = lengths_ref[slot]
-    nh = q_ref.shape[1]
-    q = q_ref[slot].astype(jnp.float32) * scale                # [nh, hd]
+    sel = sel_ref[...]                                         # [nhp, D]
+    nhp, t_step = sel.shape[0], pps * bs
 
     @pl.when(rnd == 0)
     def _():
@@ -562,28 +595,28 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    for i in range(pps):
-        first = (rnd * pps + i) * bs            # the page's first position
+    k = jnp.concatenate([r[0] for r in k_refs], axis=0)        # [T, D]
+    v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+    qh = sel * q_ref[pl.ds(slot, 1), :]                        # [nhp, D]
+    s = _dot_f32(qh, k, ((1,), (1,)), q_exact) * scale         # [nhp, T]
+    pos = rnd * t_step + jax.lax.broadcasted_iota(
+        jnp.int32, (nhp, t_step), 1)
+    s = jnp.where(pos < length, s, _NEG)
+    m = m_ref[...]
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [nhp, 1]
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    m_ref[...] = m_new
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + _dot_f32(p, v, ((1,), (0,)))
 
-        @pl.when(first < length)
-        def _():
-            k = k_refs[i][0].astype(jnp.float32)           # [bs, nh, hd]
-            s = jnp.sum(k * q[None], axis=-1, keepdims=True)
-            pos = first + jax.lax.broadcasted_iota(
-                jnp.int32, (bs, nh, 1), 0)
-            s = jnp.where(pos < length, s, _NEG)           # [bs, nh, 1]
-            m = m_ref[...]
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))         # [nh, 1]
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[None])
-            v = v_refs[i][0].astype(jnp.float32)
-            m_ref[...] = m_new
-            l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
-            acc_ref[...] = alpha * acc_ref[...] + jnp.sum(p * v, axis=0)
-
-    @pl.when((rnd + 1) * pps * bs >= length)
+    @pl.when((rnd + 1) * t_step >= length)
     def _():
-        o_ref[slot] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        # one head's row is non-zero in a lane, so the sums over the
+        # heads are that row's value: acc / l, element by element
+        ctx = jnp.sum(acc_ref[...] * sel, axis=0, keepdims=True)
+        norm = jnp.sum(l_ref[...] * sel, axis=0, keepdims=True)
+        o_ref[pl.ds(slot, 1), :] = ctx / norm
 
 
 def _decode_rounds(tables, lengths, bs, pps):
@@ -619,19 +652,23 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
                            interpret=False):
     """Single-token attention over a paged K/V cache, read in place.
 
-    q [B, nh, hd]; k_pool, v_pool [n_blocks, bs, nh, hd]; tables [B, W]
-    int32 (table order is logical order); lengths [B] int32, each at
-    least 1: slot b attends positions 0..lengths[b]-1, exactly. Returns
-    [B, nh, hd] in the pools' dtype. The pools stay in HBM: only pages
-    that hold a live position are read, and the grid has as many steps
-    as the slots hold rounds of pages, so the work grows with the
-    tokens held and not with the table's width. f32 scores, softmax
-    statistics and accumulation. `interpret=True` runs the kernel
-    under the Pallas interpreter (the CPU suite). Jitted, so that a
-    program that calls it once a layer traces and lowers it once: 36
-    separate calls added 4-6 s to every process's set-up."""
+    q [B, nh, hd]; k_pool, v_pool [n_blocks, bs, nh*hd] (one row a
+    token, heads side by side: the shape whose default device layout
+    is the row-major one that the serving programs write, so nothing
+    converts a pool around the call); tables [B, W] int32 (table order
+    is logical order); lengths [B] int32, each at least 1: slot b
+    attends positions 0..lengths[b]-1, exactly. Returns [B, nh, hd] in
+    the pools' dtype. The pools stay in HBM: only pages that hold a
+    live position are read, and the grid has as many steps as the
+    slots hold rounds of pages, so the work grows with the tokens held
+    and not with the table's width. f32 scores, softmax statistics and
+    accumulation; the result is rounded once, here. `interpret=True`
+    runs the kernel under the Pallas interpreter (the CPU suite).
+    Jitted, so that a program that calls it once a layer traces and
+    lowers it once: 36 separate calls added 4-6 s to every process's
+    set-up."""
     b, nh, hd = q.shape
-    bs = k_pool.shape[1]
+    bs, d = k_pool.shape[1:]
     pps = _PAGES_PER_STEP
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
@@ -639,30 +676,41 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
     if pad:
         tables = jnp.pad(tables, ((0, 0), (0, pad)))
     n_rounds, slot, rnd, fetch = _decode_rounds(tables, lengths, bs, pps)
+    # heads padded to whole 16-bit sublane tiles; the padding's rows of
+    # `sel` are zero, so they score 0 everywhere and are never picked
+    nhp = _ceil_to(nh, 16)
+    sel = (jnp.arange(d)[None, :] // hd
+           == jnp.arange(nhp)[:, None]).astype(jnp.float32)
 
     def page(i):
         return pl.BlockSpec(
-            (1, bs, nh, hd),
-            lambda t, slot, rnd, fetch, lens: (fetch[t * pps + i], 0, 0, 0))
+            (1, bs, d),
+            lambda t, slot, rnd, fetch, lens: (fetch[t * pps + i], 0, 0))
 
-    whole = pl.BlockSpec((b, nh, hd), lambda t, *_: (0, 0, 0))
+    def whole(rows):
+        return pl.BlockSpec((rows, d), lambda t, *_: (0, 0))
+
     kern = functools.partial(_paged_decode_kernel, scale=float(scale),
-                             bs=bs, pps=pps)
-    return pl.pallas_call(
+                             bs=bs, pps=pps,
+                             q_exact=q.dtype == k_pool.dtype)
+    out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_rounds,),
-            in_specs=[whole] + [page(i) for i in range(pps)] * 2,
-            out_specs=whole,
+            in_specs=[whole(b), whole(nhp)]
+            + [page(i) for i in range(pps)] * 2,
+            out_specs=whole(b),
             scratch_shapes=[
-                pltpu.VMEM((nh, 1), jnp.float32),
-                pltpu.VMEM((nh, 1), jnp.float32),
-                pltpu.VMEM((nh, hd), jnp.float32),
+                pltpu.VMEM((nhp, 1), jnp.float32),
+                pltpu.VMEM((nhp, 1), jnp.float32),
+                pltpu.VMEM((nhp, d), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, nh, hd), k_pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(slot, rnd, fetch, lengths, q, *([k_pool] * pps), *([v_pool] * pps))
+    )(slot, rnd, fetch, lengths, q.astype(jnp.float32).reshape(b, d), sel,
+      *([k_pool] * pps), *([v_pool] * pps))
+    return out.astype(k_pool.dtype).reshape(b, nh, hd)

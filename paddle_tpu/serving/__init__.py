@@ -8,7 +8,7 @@ streams, admission into a running decode, and memory that outlives one
 call. TPU-native shape (the TVM lesson — fixed executables + buckets
 beat dynamic shapes):
 
-  paged_cache  fixed pool of [n_blocks, block_size, n_heads, hd] KV
+  paged_cache  fixed pool of [n_blocks, block_size, n_heads * hd] KV
                pages per layer + host block tables; eviction = a host
                list splice; page refcounts + a radix prefix index give
                copy-on-write prompt sharing (prefix_sharing=True)

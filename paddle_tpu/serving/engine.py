@@ -244,9 +244,10 @@ class ServingEngine:
         if self.tp > 1 and self.n_heads % self.tp:
             raise ValueError(
                 f"plan tp={self.tp} must divide n_heads="
-                f"{self.n_heads}: the paged pools shard their heads "
-                f"axis ([n_blocks, block_size, n_heads={self.n_heads},"
-                f" head_dim]) and the qkv/proj weights shard per head "
+                f"{self.n_heads}: the paged pools shard their merged "
+                f"heads axis ([n_blocks, block_size, n_heads="
+                f"{self.n_heads} * head_dim]) by whole heads and the "
+                f"qkv/proj weights shard per head "
                 f"— {self.n_heads} % {self.tp} != 0 leaves a ragged "
                 "shard no chip can own")
         # weight snapshot, cast (and PTQ-quantized under quant="int8",

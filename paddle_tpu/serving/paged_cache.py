@@ -9,10 +9,18 @@ PagedAttention insight, TPU-statically-shaped here) splits the cache
 into fixed-size PAGES:
 
 - device side: per layer, one K pool and one V pool of shape
-  ``[n_blocks, block_size, n_heads, head_dim]`` — allocated once at
-  engine build, donated through every compiled prefill/decode call so
-  XLA updates the pages in place (graph_lint's donation rule proves the
-  aliasing);
+  ``[n_blocks, block_size, n_heads * head_dim]`` — one row a token,
+  heads side by side in the lanes — allocated once at engine build,
+  donated through every compiled prefill/decode call so XLA updates
+  the pages in place (graph_lint's donation rule proves the aliasing).
+  Heads and head size are merged because of the TPU's default layouts:
+  a 4-D ``[n_blocks, block_size, n_heads, 64]`` array lives on the
+  device pages-minor-most (head 64 fills half of the 128 lanes, so XLA
+  avoids the row-major form), while the programs' scatters and the
+  decode kernel address pages row-major, and every dispatch then
+  converted each pool at entry and back at exit. The merged row's
+  default layout IS row-major (and lane-dense), so a donated pool goes
+  in, is written in place and comes out with no conversion;
 - host side: a free-list allocator and a per-request block table
   (request -> ordered page ids). A request's cache is the list of
   pages its table names; logical token position ``p`` lives in page
@@ -198,12 +206,13 @@ class PagedKVCache:
         # tensor parallelism: n_heads stays the GLOBAL head count —
         # every host-side structure (tables, free list, refcounts,
         # radix index, sizing math) is tp-invariant; only the device
-        # pools shard, each chip holding heads/tp of every page
-        # (pool_sharding = NamedSharding over the plan's 'tp' axis)
+        # pools shard, each chip holding heads/tp of every page: its
+        # own whole heads, a contiguous (n_heads/tp)*head_dim of the
+        # merged axis (pool_sharding = NamedSharding over 'tp')
         self.tp = int(tp)
         self.pool_sharding = pool_sharding
-        shape = (self.n_blocks, self.block_size, self.n_heads,
-                 self.head_dim)
+        shape = (self.n_blocks, self.block_size,
+                 self.n_heads * self.head_dim)
 
         def _pool():
             z = jnp.zeros(shape, self.dtype)

@@ -26,8 +26,16 @@ by the platform as the flash kernel is, and held to the gather and
 the greedy token the same). The chunk program (several queries a slot)
 keeps the gather.
 
-Addressing: logical position ``p`` of a request lives in page
-``table[p // block_size]`` at offset ``p % block_size``. Masked or
+Addressing: a pool is ``[n_blocks, block_size, n_heads * head_dim]``,
+one row a token with the heads side by side; logical position ``p`` of
+a request lives in page ``table[p // block_size]`` at offset
+``p % block_size``. The programs write whole rows (``k_tok`` reshaped
+to ``[B, nh*hd]``, a prefill's page chunks to ``[A, nblk, bs, nh*hd]``)
+and split heads only after a gather. The shape is chosen for the
+device: its default TPU layout is the row-major one these scatters
+(and the kernel) address, so a donated pool is written in place; a
+4-D page with head 64 lived pages-minor-most and every dispatch
+converted all the pools at entry and at exit (paged_cache.py). Masked or
 padded lanes carry an all-zeros table row — their writes land in the
 reserved scratch page 0 and their reads are iota-masked, so inactive
 lanes cost no conditional scatter. Junk K/V (pad positions a bucketed
@@ -60,11 +68,12 @@ __all__ = ["make_decode_fn", "make_prefill_fn", "make_chunk_fn",
 
 
 def _gathered(pool, tables, n_heads, hd):
-    """Pages -> contiguous logical cache: [n_blocks, bs, nh, hd]
+    """Pages -> contiguous logical cache: [n_blocks, bs, nh*hd]
     gathered by [B, W] tables into [B, nh, W*bs, hd] (table order IS
-    logical order, so index j along the length axis is position j)."""
+    logical order, so index j along the length axis is position j);
+    heads are split after the gather."""
     b, w = tables.shape
-    pages = pool[tables]                       # [B, W, bs, nh, hd]
+    pages = pool[tables]                       # [B, W, bs, nh*hd]
     flat = pages.reshape(b, w * pool.shape[1], n_heads, hd)
     return jnp.einsum("bsnh->bnsh", flat)
 
@@ -125,8 +134,8 @@ def make_decode_fn(eps: float, n_heads: int, block_size: int,
                 else:
                     qkv = qkv.reshape(b, 1, 3, n_heads, hd)
                 q = jnp.einsum("bsnh->bnsh", qkv[:, :, 0])  # [B,nh,1,hd]
-                k_tok = qkv[:, 0, 1]                     # [B,nh,hd]
-                v_tok = qkv[:, 0, 2]
+                k_tok = qkv[:, 0, 1].reshape(b, -1)      # [B,nh*hd]
+                v_tok = qkv[:, 0, 2].reshape(b, -1)
                 kp = kp.at[blk, off].set(k_tok)
                 vp = vp.at[blk, off].set(v_tok)
                 if on_tpu:
@@ -207,11 +216,11 @@ def make_prefill_fn(eps: float, n_heads: int, block_size: int,
                                  head_dim=head_dim)
             new_pools = []
             for (kp, vp), (kc, vc) in zip(pools, caches):
-                # [A, nh, S, hd] -> page chunks [A, nblk, bs, nh, hd]
+                # [A, nh, S, hd] -> page chunks [A, nblk, bs, nh*hd]
                 kcs = jnp.einsum("ansh->asnh", kc).reshape(
-                    a, nblk, block_size, kc.shape[1], kc.shape[3])
+                    a, nblk, block_size, -1)
                 vcs = jnp.einsum("ansh->asnh", vc).reshape(
-                    a, nblk, block_size, vc.shape[1], vc.shape[3])
+                    a, nblk, block_size, -1)
                 kp = kp.at[tables[:, :nblk]].set(kcs)
                 vp = vp.at[tables[:, :nblk]].set(vcs)
                 new_pools.append((kp, vp))
@@ -290,8 +299,8 @@ def make_chunk_fn(eps: float, n_heads: int, block_size: int,
                 else:
                     qkv = qkv.reshape(b, s, 3, n_heads, hd)
                 q = jnp.einsum("bsnh->bnsh", qkv[:, :, 0])  # [B,nh,S,hd]
-                kp = kp.at[blk, off].set(qkv[:, :, 1])
-                vp = vp.at[blk, off].set(qkv[:, :, 2])
+                kp = kp.at[blk, off].set(qkv[:, :, 1].reshape(b, s, -1))
+                vp = vp.at[blk, off].set(qkv[:, :, 2].reshape(b, s, -1))
                 kc = _gathered(kp, tables, n_heads, hd)
                 vc = _gathered(vp, tables, n_heads, hd)
                 att = jnp.einsum("bnqh,bnkh->bnqk", q, kc) * scale

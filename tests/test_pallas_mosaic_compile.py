@@ -9,6 +9,7 @@ What the on-chip PRNG *produces* is only checked on a chip
 (`PD_TEST_TPU=1 pytest tests/test_pallas_attention.py`).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -138,17 +139,47 @@ def test_sharded_train_step_lowers_for_v5e_with_the_kernel_on(monkeypatch):
 SLOTS, TABLE_W, PAGE, HEADS, HEAD = 32, 64, 16, 20, 64
 
 
+def _pool_lines(text, n_pages, width):
+    """The compiled text's instructions whose result is a whole pool,
+    `[n_pages, 16, width]`, as (opcode, line)."""
+    pat = re.compile(
+        rf"= \w+\[{n_pages},{PAGE},{width}\]\{{[^}}]*\}} ([\w-]+)\(")
+    return [(m.group(1), ln.strip()) for ln in text.splitlines()
+            for m in [pat.search(ln)] if m]
+
+
+def _pool_copies(text, n_pages, width):
+    """Layout conversions of a whole pool: with 4-D pages every
+    serving program held two a pool, one at entry and one at exit. A
+    conversion is a `copy`, or a `copy-start` whose source and result
+    differ in more than the memory space (`S(1)`): the compiler may
+    park a pool in VMEM around its scatter, a move in the same layout
+    (it does so for one pool of the 2-layer decode program and for
+    none of the 36-layer one), which is not a conversion."""
+    shape = rf"\w+\[{n_pages},{PAGE},{width}\]\{{([^}}]*)\}}"
+    moved = re.compile(rf"= \({shape}, {shape}, .*copy-start\(")
+    space = re.compile(r"S\(\d+\)")
+    found = [ln for op, ln in _pool_lines(text, n_pages, width)
+             if op == "copy"]
+    for ln in text.splitlines():
+        m = moved.search(ln)
+        if m and space.sub("", m.group(1)) != space.sub("", m.group(2)):
+            found.append(ln.strip())
+    return found
+
+
 def _pool(n_pages, heads=HEADS, dtype=jnp.bfloat16):
-    return jax.ShapeDtypeStruct((n_pages, PAGE, heads, HEAD), dtype)
+    return jax.ShapeDtypeStruct((n_pages, PAGE, heads * HEAD), dtype)
 
 
 @pytest.mark.parametrize("heads,dtype", [
     (HEADS, jnp.bfloat16), (HEADS // 2, jnp.bfloat16),   # tp=2's shard
     (HEADS, jnp.float32)], ids=["bf16_nh20", "bf16_nh10", "f32_nh20"])
 def test_paged_decode_kernel_compiles_for_one_v5e_chip(heads, dtype):
-    """Head 64 and 20 (or 10) heads a page are no multiples of the
-    (8, 128) tile: the page windows are whole in their last two
-    dimensions, which Mosaic takes where it refuses a sliced copy."""
+    """A page is [16, heads x 64]: one row a token, 1,280 (or tp=2's
+    640) lanes of whole tiles; the page windows are whole in their
+    last two dimensions. One Mosaic call, and no copy of a pool
+    around it."""
     one = SingleDeviceSharding(_v5e_devices()[0])
     q = jax.ShapeDtypeStruct((SLOTS, heads, HEAD), dtype)
     pool = _pool(1024, heads, dtype)
@@ -161,6 +192,7 @@ def test_paged_decode_kernel_compiles_for_one_v5e_chip(heads, dtype):
                     (one,) * 5).as_text()
     assert text.count("tpu_custom_call") == 1
     assert "paged_decode_attention" in text
+    assert not _pool_copies(text, 1024, heads * HEAD)
 
 
 def _gpt2_large_decode_avals(n_layers, n_pages, pool_at, rep, param_at):
@@ -178,7 +210,7 @@ def _gpt2_large_decode_avals(n_layers, n_pages, pool_at, rep, param_at):
     params = {"wte": s((vocab, h), rep), "wpe": s((ctx, h), rep),
               "lnf_w": s((h,), rep), "lnf_b": s((h,), rep),
               "blocks": [block] * n_layers}
-    pool = s((n_pages, PAGE, HEADS, HEAD), pool_at)
+    pool = s((n_pages, PAGE, HEADS * HEAD), pool_at)
     key = jax.eval_shape(lambda: jax.random.key(0))
     return (((pool, pool),) * n_layers,
             s((SLOTS, TABLE_W), rep, jnp.int32), s((SLOTS,), rep, jnp.int32),
@@ -186,42 +218,91 @@ def _gpt2_large_decode_avals(n_layers, n_pages, pool_at, rep, param_at):
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep))
 
 
+def _compile_decode(n_layers, n_pages, monkeypatch):
+    """make_decode_fn (the kernel chosen as on a TPU, 4 tokens a
+    dispatch, pools donated) compiled for one v5e chip."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_decode_fn)
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    fn = jit_with_donated_pools(make_decode_fn(
+        1e-5, HEADS, PAGE, 0.0, None, None, n_steps=4))
+    return fn.trace(*_gpt2_large_decode_avals(
+        n_layers, n_pages, one, one, lambda name: one)).lower(
+            lowering_platforms=("tpu",)).compile()
+
+
+def _compile_prefill(n_layers, n_pages, monkeypatch):
+    """make_prefill_fn at the score cell's widest shape, 4 prompts of
+    1,024 tokens, pools donated, compiled for one v5e chip."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_prefill_fn)
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    pools, _, _, _, params, key = _gpt2_large_decode_avals(
+        n_layers, n_pages, one, one, lambda name: one)
+    admit, bucket = 4, 1024
+    s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one)
+    fn = jit_with_donated_pools(make_prefill_fn(
+        1e-5, HEADS, PAGE, 0.0, None, None))
+    return fn.trace(pools, s32(admit, TABLE_W), s32(admit, bucket),
+                    s32(admit), params, key).lower(
+                        lowering_platforms=("tpu",)).compile()
+
+
 @pytest.mark.parametrize("n_pages", [1024, 2048])
 def test_decode_program_reads_pages_in_place_on_a_v5e(n_pages, monkeypatch,
                                                       capsys):
     """The whole decode program at the chat cell's shapes (36 layers
     cut to 2 for time), with the kernel chosen as on a TPU: no buffer
-    of every slot's whole block table, [32 x 64 pages, 16, 20, 64], is
-    left; one Mosaic call a layer. Prints the program's temporaries
-    (PERF.md has them at 36 layers beside the parent's)."""
-    from paddle_tpu.serving.programs import (jit_with_donated_pools,
-                                             make_decode_fn)
-    one = SingleDeviceSharding(_v5e_devices()[0])
-    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    of every slot's whole block table, [32 x 64 pages, 16, 1280], is
+    left; one Mosaic call a layer. Its temporaries hold no copy of a
+    pool (with 4-D pages they were about 400 MB at 2 layers, 7.3 GB at
+    36, and 2,048 pages did not compile): printed, and held under
+    50 MB."""
     n_layers = 2
-    fn = jit_with_donated_pools(make_decode_fn(
-        1e-5, HEADS, PAGE, 0.0, None, None, n_steps=4))
-    compiled = fn.trace(*_gpt2_large_decode_avals(
-        n_layers, n_pages, one, one, lambda name: one)).lower(
-            lowering_platforms=("tpu",)).compile()
+    compiled = _compile_decode(n_layers, n_pages, monkeypatch)
     text = compiled.as_text()
     # neither the gathered tables nor their re-layout (at 2,048 pages a
     # pool has the re-layout's shape itself)
-    assert f"[{SLOTS},{TABLE_W},{PAGE},{HEADS},{HEAD}]" not in text
+    assert f"[{SLOTS},{TABLE_W},{PAGE},{HEADS * HEAD}]" not in text
     if n_pages != SLOTS * TABLE_W:
-        assert f"[{SLOTS * TABLE_W},{PAGE},{HEADS},{HEAD}]" not in text
+        assert f"[{SLOTS * TABLE_W},{PAGE},{HEADS * HEAD}]" not in text
     assert text.count("tpu_custom_call") == n_layers
     mem = compiled.memory_analysis()
     with capsys.disabled():
         print(f"\ndecode program, {n_layers} layers, {n_pages} pages: "
               f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB, "
               f"arguments {mem.argument_size_in_bytes / 1e6:.1f} MB")
+    assert mem.temp_size_in_bytes < 50e6
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serving_programs_do_not_convert_the_pools(program, monkeypatch):
+    """The counter of the one-layout change: a pool
+    [1024, 16, 20 * 64] enters each serving program row-major, the
+    layout its scatter (and the kernel) address, and no `copy` in the
+    compiled text has a pool's shape. With [1024, 16, 20, 64] pools
+    the device's layout was pages-minor-most and each program held a
+    copy of every pool at entry and another at exit (8 at 2 layers)."""
+    n_layers, n_pages = 2, 1024
+    build = {"decode": _compile_decode, "prefill": _compile_prefill}
+    text = build[program](n_layers, n_pages, monkeypatch).as_text()
+    lines = _pool_lines(text, n_pages, HEADS * HEAD)
+    params = [ln for op, ln in lines if op == "parameter"
+              and "sharding=" in ln]           # the entry's, not a fusion's
+    assert len(params) == 2 * n_layers, params
+    assert all(f"[{n_pages},{PAGE},{HEADS * HEAD}]{{2,1,0:" in ln
+               for ln in params), params
+    assert not _pool_copies(text, n_pages, HEADS * HEAD)
+    assert f"[{n_pages},{PAGE},{HEADS},{HEAD}]" not in text
 
 
 def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
     """Under `MeshPlan(tp=2)` the same body runs inside a shard_map
     over 'tp': each chip's kernel call sees its 10 of the 20 heads of
-    every page, and nothing gathers the pools."""
+    every page, [1024, 16, 640] pools, and nothing gathers or converts
+    them."""
     from paddle_tpu.distributed.sharding import (SERVING_POOL_SPEC,
                                                  SERVING_TP_RULES)
     from paddle_tpu.serving.programs import (jit_tp_with_donated_pools,
@@ -243,5 +324,8 @@ def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
         lowering_platforms=("tpu",)).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == n_layers
-    assert all(f"bf16[{SLOTS},{HEADS // tp},{HEAD}]" in ln for ln in calls)
+    local = f"bf16[1024,{PAGE},{HEADS // tp * HEAD}]"
+    assert all(ln.count(local) >= 2 for ln in calls), calls[0][:400]
+    assert f"[1024,{PAGE},{HEADS * HEAD}]" not in text
     assert "all-gather" not in text
+    assert not _pool_copies(text, 1024, HEADS // tp * HEAD)

@@ -206,6 +206,8 @@ class TestBf16Default:
         k, v = eng.cache.pools[0]
         assert str(k.dtype) == "bfloat16" == str(v.dtype)
         assert str(eng.params["wte"].dtype) == "bfloat16"
+        # a page is [block_size, hidden]: one row a token
+        assert k.shape == v.shape == (16, 4, eng.params["wte"].shape[1])
 
 
 class TestSchedulerUnits:

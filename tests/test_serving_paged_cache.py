@@ -97,8 +97,22 @@ class TestConstruction:
                          n_heads=2, head_dim=5, dtype="bfloat16")
         assert len(c.pools) == 3
         k, v = c.pools[0]
-        assert k.shape == (8, 4, 2, 5) == v.shape
+        # one row a token, heads side by side: [n_blocks, bs, nh * hd]
+        assert k.shape == (8, 4, 2 * 5) == v.shape
         assert str(k.dtype) == "bfloat16"
+        assert (c.n_heads, c.head_dim) == (2, 5)
+
+    @pytest.mark.parametrize("n_heads,head_dim", [(20, 64), (4, 16),
+                                                  (8, 128)])
+    def test_one_page_shape_for_every_model(self, n_heads, head_dim):
+        """No option keeps a 4-D page: the merged width is read from
+        the model, and the sizing math still counts heads x head."""
+        c = PagedKVCache(n_layers=1, n_blocks=4, block_size=16,
+                         n_heads=n_heads, head_dim=head_dim,
+                         dtype="bfloat16")
+        k, v = c.pools[0]
+        assert k.shape == v.shape == (4, 16, n_heads * head_dim)
+        assert c.stats()["pool_bytes"] == 2 * k.nbytes
 
     def test_bad_shapes_raise(self):
         with pytest.raises(ValueError, match="n_blocks"):

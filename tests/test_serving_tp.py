@@ -122,14 +122,16 @@ class TestTpParity:
 
 class TestTpPools:
     def test_pools_shard_over_heads(self, engine):
-        """Each K/V page pool leaf shards P(None, None, 'tp', None):
-        2 shards, each holding n_heads/2 whole heads of every page —
+        """Each K/V page pool leaf shards P(None, None, 'tp'): 2
+        shards, each holding n_heads/2 whole heads of every page, a
+        contiguous half of the merged n_heads * head_dim axis —
         per-chip bytes exactly half the global pool."""
         for k, v in engine.cache.pools:
             for leaf in (k, v):
                 shards = leaf.addressable_shards
                 assert len(shards) == 2
-                assert shards[0].data.shape == (32, 4, 2, 8)
+                assert leaf.shape == (32, 4, 4 * 8)
+                assert shards[0].data.shape == (32, 4, 2 * 8)
                 assert shards[0].data.nbytes * 2 == leaf.nbytes
         st = engine.cache.stats()
         assert st["pool_bytes_per_chip"] * 2 == st["pool_bytes"]
