@@ -17,58 +17,38 @@ Supports greedy and temperature/top-k sampling over GPTForCausalLM
 cache equals argmax over full re-forward logits at every step
 (tests/test_generation.py).
 
-This module is ALSO the numerical reference for the continuous-batching
-serving engine: paddle_tpu/serving/programs.py imports `_ln`, `_attend`,
-`_prefill`, `_pick` (and the engine `_gpt_params`/`_cast_params`) so the
-paged-cache decode is the same ops in the same order with only the cache
-addressing changed — that reuse is what makes the paged-vs-dense greedy
-parity contract bit-exact in f32 (tests/test_serving_engine.py). That
-holds of the portable path; on a TPU the engine's decode attention is
-the paged Pallas kernel, for which `_attend` over the gathered pages is
-the reference (tests/test_paged_decode_attention.py). A change to these
-helpers must keep all three suites green.
+The block itself is models/decoder.py's `block`; this module holds the
+two DENSE addressings of the cache (`_prefill`: causal over the
+prompt's own K/V, kept as a [B, N, total, hd] cache; `_step_hidden`:
+write one token at `pos`, attend over `pos + 1`), the parameter table
+(`_gpt_params`), the sampler (`_pick`) and the two jitted builders. The
+serving engine's paged addressings (serving/programs.py) run the same
+`block`, which is what makes paged-vs-dense greedy bit-exact in f32
+(tests/test_serving_engine.py, tests/test_decoder_block.py).
 """
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..framework import Tensor
+from . import decoder
+from .decoder import DecoderSpec
 
 __all__ = ["generate_gpt"]
 
-
-def _ln(x, w, b, eps):
-    # moments in f32 regardless of storage dtype: bf16 serving (the
-    # dtype= cast below) would otherwise lose layernorm precision
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    return (((xf - mu) / jnp.sqrt(var + eps)).astype(x.dtype) * w + b)
+# the one table of a block's leaves: <layer>_w / <layer>_b for each
+_BLOCK_LAYERS = ("ln1", "ln2", "qkv", "proj", "fc1", "fc2")
 
 
-def _block_params(blk):
-    return {
-        "ln1_w": blk.ln1.weight._data, "ln1_b": blk.ln1.bias._data,
-        "ln2_w": blk.ln2.weight._data, "ln2_b": blk.ln2.bias._data,
-        "qkv_w": blk.qkv.weight._data, "qkv_b": blk.qkv.bias._data,
-        "proj_w": blk.proj.weight._data, "proj_b": blk.proj.bias._data,
-        "fc1_w": blk.fc1.weight._data, "fc1_b": blk.fc1.bias._data,
-        "fc2_w": blk.fc2.weight._data, "fc2_b": blk.fc2.bias._data,
-    }
-
-
-# decode-key -> state_dict-name, derived from the one layout table in
-# _block_params so a GPTBlock param rename can't go stale here
-_SCAN_BLOCK_KEYS = {
-    k: k[:-2] + (".weight" if k.endswith("_w") else ".bias")
-    for k in ("ln1_w", "ln1_b", "ln2_w", "ln2_b", "qkv_w", "qkv_b",
-              "proj_w", "proj_b", "fc1_w", "fc1_b", "fc2_w", "fc2_b")
-}
+def _block_params(get):
+    """`get(layer, "weight" | "bias")` -> that leaf of one block (or of
+    a whole [L, ...] stack)."""
+    return {f"{n}_{kind[0]}": get(n, kind)
+            for n in _BLOCK_LAYERS for kind in ("weight", "bias")}
 
 
 def _gpt_params(model):
@@ -79,12 +59,13 @@ def _gpt_params(model):
         # the decode loop is already per-layer, so generation works
         # identically off either parameter layout
         stk = gpt.blocks
-        get = {k: getattr(stk, stk._mangled[n])._data
-               for k, n in _SCAN_BLOCK_KEYS.items()}
-        blocks = [{k: v[i] for k, v in get.items()}
+        stacks = _block_params(lambda n, kind: getattr(
+            stk, stk._mangled[f"{n}.{kind}"])._data)
+        blocks = [{k: v[i] for k, v in stacks.items()}
                   for i in range(stk.L)]
     else:
-        blocks = [_block_params(b) for b in gpt.blocks]
+        blocks = [_block_params(lambda n, kind: getattr(
+            getattr(b, n), kind)._data) for b in gpt.blocks]
     return {
         "wte": gpt.wte.weight._data,
         "wpe": gpt.wpe.weight._data,
@@ -93,139 +74,54 @@ def _gpt_params(model):
     }
 
 
-def _mm(x, bp, name):
-    """One block matmul through either the float weight
-    (``<name>_w``: the training/bf16 serving path, unchanged HLO) or
-    the serving int8 snapshot (a ``{"q8", "s"}`` leaf from
-    quant/int8_serving — per-channel PTQ codes + dequant scales riding
-    the params pytree as traced arguments). The branch is a trace-time
-    isinstance on the pytree structure, so the float path compiles to
-    exactly the ``x @ w`` it always was — the f32 greedy parity
-    contract is untouched."""
-    w = bp[name + "_w"]
-    if isinstance(w, dict):
-        from ..quant.int8_serving import int8_matmul
-        return int8_matmul(x, w["q8"], w["s"])
-    return x @ w
-
-
-def _attend(q, kc, vc, n_valid, scale):
-    """q [B,N,1,hd] over cache kc/vc [B,N,T,hd], masked to n_valid
-    (scalar, or [B] for ragged per-row prompt lengths)."""
-    s = jnp.einsum("bnqh,bnkh->bnqk", q, kc) * scale
-    pos = jnp.arange(kc.shape[2])
-    if getattr(n_valid, "ndim", 0):
-        mask = pos[None, None, None, :] < n_valid[:, None, None, None]
-    else:
-        mask = pos[None, None, None, :] < n_valid
-    s = jnp.where(mask, s, -1e30)
-    p = jax.nn.softmax(s.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bnqk,bnkh->bnqh", p, vc)
-
-
-def _step_hidden(params, eps, n_heads, x, caches, pos):
+def _step_hidden(spec, params, x, caches, pos):
     """One token's hidden state through all blocks, updating caches.
 
     x: [B, 1, H]; caches: list of (k [B,N,T,hd], v [B,N,T,hd]);
     pos: index where this token's K/V land — a scalar (uniform
     prompts) or [B] (ragged prompts: each row writes at its own next
     position and attends over its own valid prefix)."""
-    new_caches = []
-    hd = x.shape[-1] // n_heads
-    scale = 1.0 / math.sqrt(hd)
-    ragged = bool(getattr(pos, "ndim", 0))
-    for bp, (kc, vc) in zip(params["blocks"], caches):
-        b = x.shape[0]
-        xn = _ln(x, bp["ln1_w"], bp["ln1_b"], eps)
-        qkv = (_mm(xn, bp, "qkv") + bp["qkv_b"]).reshape(
-            b, 1, 3, n_heads, hd)
-        q = jnp.einsum("bsnh->bnsh", qkv[:, :, 0])
-        k = jnp.einsum("bsnh->bnsh", qkv[:, :, 1])
-        v = jnp.einsum("bsnh->bnsh", qkv[:, :, 2])
-        if ragged:
+
+    def attend(cache, q, k, v):
+        kc, vc = cache
+        k = jnp.einsum("bsnh->bnsh", k)
+        v = jnp.einsum("bsnh->bnsh", v)
+        if getattr(pos, "ndim", 0):
             # per-row scatter: row i writes its K/V at pos[i]
-            bi = jnp.arange(b)
+            bi = jnp.arange(k.shape[0])
             kc = kc.at[bi, :, pos].set(k[:, :, 0])
             vc = vc.at[bi, :, pos].set(v[:, :, 0])
         else:
-            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos,
-                                                     axis=2)
-            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos,
-                                                     axis=2)
-        ctx = _attend(q, kc, vc, pos + 1, scale)
-        ctx = jnp.einsum("bnsh->bsnh", ctx).reshape(b, 1, -1)
-        x = x + _mm(ctx, bp, "proj") + bp["proj_b"]
-        ff = _ln(x, bp["ln2_w"], bp["ln2_b"], eps)
-        ff = jax.nn.gelu(_mm(ff, bp, "fc1") + bp["fc1_b"],
-                         approximate=False)
-        x = x + _mm(ff, bp, "fc2") + bp["fc2_b"]
-        new_caches.append((kc, vc))
-    return x, new_caches
+            kc = jax.lax.dynamic_update_slice_in_dim(kc, k, pos, axis=2)
+            vc = jax.lax.dynamic_update_slice_in_dim(vc, v, pos, axis=2)
+        mask = decoder.prefix_mask(kc.shape[2], pos + 1)
+        return (decoder.masked_attention(q, kc, vc, mask, spec.scale),
+                (kc, vc))
+
+    return decoder.blocks(spec, params, x, caches, attend)
 
 
-def _prefill(params, eps, n_heads, ids, total_len, prompt_lens=None,
-             qkv_heads_major=False, tp_reduce=None, head_dim=None):
-    """Full forward over the prompt, returning per-layer caches sized to
-    total_len and the last hidden state. Uses the same big-matmul form
+def _prefill(spec, params, ids, total_len, prompt_lens=None):
+    """Full forward over the prompt, returning its hidden states and
+    per-layer caches sized to total_len. Uses the same big-matmul form
     as training (the MXU-efficient path) — only decode is token-wise.
 
     prompt_lens [B] (ragged, right-padded prompts): keys beyond each
     row's true length are masked; their junk cache slots are
     progressively OVERWRITTEN by the decode loop's per-row scatter, so
-    they are never attended to.
+    they are never attended to."""
+    s = ids.shape[1]
+    mask = decoder.causal_mask(s, prompt_lens)
+    pad = ((0, 0), (0, 0), (0, total_len - s), (0, 0))
 
-    qkv_heads_major / tp_reduce: the tensor-parallel hooks. Inside a
-    tp shard_map the qkv columns are laid out (heads, 3, hd) — so each
-    chip's contiguous shard carries WHOLE heads with their q,k,v —
-    and the proj/fc2 partial contractions need an all-reduce before
-    the bias. Both default off; the tp=1 graph is byte-for-byte the
-    one this function always built (the parity contract). head_dim
-    must be given explicitly under tp (n_heads is then the LOCAL head
-    count while the replicated hidden stays global)."""
-    b, s = ids.shape
-    hd = head_dim or params["wte"].shape[1] // n_heads
-    scale = 1.0 / math.sqrt(hd)
-    x = params["wte"][ids] + params["wpe"][jnp.arange(s)][None]
-    cm = jnp.tril(jnp.ones((s, s), bool))
-    if prompt_lens is not None:
-        cm = (cm[None, None]
-              & (jnp.arange(s)[None, :]
-                 < prompt_lens[:, None])[:, None, None, :])
-    caches = []
-    for bp in params["blocks"]:
-        xn = _ln(x, bp["ln1_w"], bp["ln1_b"], eps)
-        qkv = _mm(xn, bp, "qkv") + bp["qkv_b"]
-        if qkv_heads_major:
-            qkv = jnp.einsum("bsnch->bscnh", qkv.reshape(
-                b, s, n_heads, 3, hd))
-        else:
-            qkv = qkv.reshape(b, s, 3, n_heads, hd)
-        q = jnp.einsum("bsnh->bnsh", qkv[:, :, 0])
-        k = jnp.einsum("bsnh->bnsh", qkv[:, :, 1])
-        v = jnp.einsum("bsnh->bnsh", qkv[:, :, 2])
-        att = jnp.einsum("bnqh,bnkh->bnqk", q, k) * scale
-        att = jnp.where(cm, att, -1e30)
-        p = jax.nn.softmax(att.astype(jnp.float32), axis=-1).astype(
-            x.dtype)
-        ctx = jnp.einsum("bnqk,bnkh->bnqh", p, v)
-        ctx = jnp.einsum("bnsh->bsnh", ctx).reshape(b, s, -1)
-        proj = _mm(ctx, bp, "proj")
-        if tp_reduce is not None:
-            proj = tp_reduce(proj)
-        x = x + proj + bp["proj_b"]
-        ff = _ln(x, bp["ln2_w"], bp["ln2_b"], eps)
-        ff = jax.nn.gelu(_mm(ff, bp, "fc1") + bp["fc1_b"],
-                         approximate=False)
-        f2 = _mm(ff, bp, "fc2")
-        if tp_reduce is not None:
-            f2 = tp_reduce(f2)
-        x = x + f2 + bp["fc2_b"]
-        kc = jnp.zeros((b, n_heads, total_len, hd), k.dtype)
-        vc = jnp.zeros((b, n_heads, total_len, hd), v.dtype)
-        kc = jax.lax.dynamic_update_slice_in_dim(kc, k, 0, axis=2)
-        vc = jax.lax.dynamic_update_slice_in_dim(vc, v, 0, axis=2)
-        caches.append((kc, vc))
-    return x, caches
+    def attend(_, q, k, v):
+        kc = jnp.einsum("bsnh->bnsh", k)
+        vc = jnp.einsum("bsnh->bnsh", v)
+        return (decoder.masked_attention(q, kc, vc, mask, spec.scale),
+                (jnp.pad(kc, pad), jnp.pad(vc, pad)))
+
+    x = decoder.embed(params, ids, jnp.arange(s))
+    return decoder.blocks(spec, params, x, None, attend)
 
 
 def _pick(logits, key, temperature, top_k, top_p=None):
@@ -278,7 +174,7 @@ def _cast_params(params, dtype):
 
 
 @functools.lru_cache(maxsize=64)
-def _build_run(eps, n_heads, temperature, top_k, eos_token_id,
+def _build_run(spec, temperature, top_k, eos_token_id,
                pad_token_id, max_new_tokens, prompt, total, dtype,
                ragged=False, top_p=None):
     """One jitted decode program per static signature — repeated
@@ -292,19 +188,16 @@ def _build_run(eps, n_heads, temperature, top_k, eos_token_id,
         params = _cast_params(params, dtype)
         b = ids.shape[0]
         pl = prompt_lens if ragged else None
-        x, caches = _prefill(params, eps, n_heads, ids, total,
-                             prompt_lens=pl)
+        x, caches = _prefill(spec, params, ids, total, prompt_lens=pl)
         if ragged:
             idx = (prompt_lens - 1).astype(jnp.int32)
             last = jnp.take_along_axis(
-                x, idx[:, None, None], axis=1)          # [B, 1, H]
-            h_last = _ln(last, params["lnf_w"], params["lnf_b"], eps)
+                x, idx[:, None, None], axis=1)[:, 0]    # [B, H]
             pos0 = prompt_lens.astype(jnp.int32)
         else:
-            h_last = _ln(x[:, -1:], params["lnf_w"], params["lnf_b"],
-                         eps)
+            last = x[:, -1]
             pos0 = jnp.int32(prompt)
-        logits = (h_last[:, 0] @ params["wte"].T)
+        logits = decoder.final_logits(spec, params, last)
 
         def body(carry, step_key):
             caches, logits, pos, done = carry
@@ -313,13 +206,10 @@ def _build_run(eps, n_heads, temperature, top_k, eos_token_id,
             if eos_token_id is not None:
                 tok = jnp.where(done, pad_token_id, tok)
                 done = done | (tok == eos_token_id)
-            emb_pos = (params["wpe"][pos] if ragged
-                       else params["wpe"][pos][None])
-            x = (params["wte"][tok] + emb_pos)[:, None, :]
-            x, caches = _step_hidden(params, eps, n_heads, x, caches,
-                                     pos)
-            h = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-            logits = h[:, 0] @ params["wte"].T
+            x = decoder.embed(params, tok[:, None],
+                              jnp.reshape(pos, (-1, 1)))
+            x, caches = _step_hidden(spec, params, x, caches, pos)
+            logits = decoder.final_logits(spec, params, x[:, 0])
             return (caches, logits, pos + 1, done), tok
 
         keys = jax.random.split(key, max_new_tokens)
@@ -332,7 +222,7 @@ def _build_run(eps, n_heads, temperature, top_k, eos_token_id,
 
 
 @functools.lru_cache(maxsize=64)
-def _build_beam_run(eps, n_heads, num_beams, eos_token_id, pad_token_id,
+def _build_beam_run(spec, num_beams, eos_token_id, pad_token_id,
                     max_new_tokens, prompt, total, dtype):
     """Beam-search decode sharing the KV-cache machinery: beams live as
     batch rows [B*W], each step expands with the beam_search_step op's
@@ -351,12 +241,11 @@ def _build_beam_run(eps, n_heads, num_beams, eos_token_id, pad_token_id,
         # prefill ONCE over the B prompts, then repeat the caches and
         # final logits across beams (duplicate rows would recompute the
         # identical prompt forward W times)
-        x, caches = _prefill(params, eps, n_heads, ids, total)
+        x, caches = _prefill(spec, params, ids, total)
         caches = jax.tree_util.tree_map(
             lambda c: jnp.repeat(c, w, axis=0), caches)
-        h_last = _ln(x[:, -1:], params["lnf_w"], params["lnf_b"], eps)
-        logits = jnp.repeat(h_last[:, 0] @ params["wte"].T, w,
-                            axis=0)                         # [B*W, V]
+        logits = jnp.repeat(decoder.final_logits(spec, params, x[:, -1]),
+                            w, axis=0)                      # [B*W, V]
         scores0 = jnp.tile(
             jnp.asarray([0.0] + [-1e30] * (w - 1), jnp.float32), (b, 1))
         done0 = jnp.zeros((b, w), bool)
@@ -379,13 +268,9 @@ def _build_beam_run(eps, n_heads, num_beams, eos_token_id, pad_token_id,
             gidx = (jnp.arange(b)[:, None] * w + parents).reshape(-1)
             caches = jax.tree_util.tree_map(
                 lambda c: jnp.take(c, gidx, axis=0), caches)
-            flat_toks = toks.reshape(-1)
-            x = (params["wte"][flat_toks]
-                 + params["wpe"][pos][None])[:, None, :]
-            x, caches = _step_hidden(params, eps, n_heads, x, caches,
-                                     pos)
-            h = _ln(x, params["lnf_w"], params["lnf_b"], eps)
-            logits = h[:, 0] @ params["wte"].T
+            x = decoder.embed(params, toks.reshape(-1, 1), pos)
+            x, caches = _step_hidden(spec, params, x, caches, pos)
+            logits = decoder.final_logits(spec, params, x[:, 0])
             return (caches, logits, pos + 1, scores, done), (toks,
                                                              parents)
 
@@ -450,8 +335,7 @@ def generate_gpt(model, input_ids, max_new_tokens=32, temperature=0.0,
             raise ValueError("prompt_lens is not supported with beam "
                              "search yet — pad to a common length")
         run = _build_beam_run(
-            float(cfg.layer_norm_eps), int(cfg.num_heads),
-            int(num_beams),
+            DecoderSpec.of(cfg), int(num_beams),
             None if eos_token_id is None else int(eos_token_id),
             int(pad_token_id), int(max_new_tokens), prompt, total,
             dtype)
@@ -473,8 +357,8 @@ def generate_gpt(model, input_ids, max_new_tokens=32, temperature=0.0,
                 f"prompt_lens must be in [1, {prompt}] (padded prompt "
                 f"width); got min={pl_host.min()} max={pl_host.max()}")
     run = _build_run(
-        float(cfg.layer_norm_eps), int(cfg.num_heads),
-        float(temperature), None if top_k is None else int(top_k),
+        DecoderSpec.of(cfg), float(temperature),
+        None if top_k is None else int(top_k),
         None if eos_token_id is None else int(eos_token_id),
         int(pad_token_id), int(max_new_tokens), prompt, total, dtype,
         ragged, None if top_p is None else float(top_p))

@@ -98,20 +98,19 @@ def int8_matmul(x, q8, s):
     return (acc.astype(jnp.float32) * sx * s).astype(x.dtype)
 
 
-def logits_drift_receipt(params, eps, n_heads, ids, qcfg=None):
+def logits_drift_receipt(params, spec, ids, qcfg=None):
     """The accuracy receipt's numeric half: last-position logits over
     one f32 prompt forward, compared across the three serving casts.
     Returns max-abs logit drift for int8 and for bf16 (the reference
     yardstick the ISSUE bounds int8 against) plus whether the greedy
     top-1 tokens agree on these prompts."""
     import jax.numpy as jnp
-    from ..models.generation import _cast_params, _ln, _prefill
+    from ..models.decoder import final_logits
+    from ..models.generation import _cast_params, _prefill
 
     def last_logits(p):
-        x, _ = _prefill(p, eps, n_heads, ids, ids.shape[1])
-        h = _ln(x[:, -1:], p["lnf_w"], p["lnf_b"], eps)
-        wte = p["wte"]
-        return (h[:, 0] @ wte.T).astype(jnp.float32)
+        x, _ = _prefill(spec, p, ids, ids.shape[1])
+        return final_logits(spec, p, x[:, -1]).astype(jnp.float32)
 
     l32 = last_logits(params)
     l8 = last_logits(quantize_params(params, qcfg))

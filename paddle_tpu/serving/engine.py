@@ -4,8 +4,8 @@ Ties the pieces together: a weight snapshot (bf16 serving cast by
 default — decode is HBM-bound on weight reads, PERF_PLAN lever #5; f32
 parity mode is pinned bit-for-bit against generation.py greedy), the
 page pools + host block tables (paged_cache), the FIFO
-continuous-batching scheduler, and the two per-engine compiled
-programs (programs.py). One ``step()`` is one token boundary:
+continuous-batching scheduler, and the per-engine compiled programs
+(programs.py). One ``step()`` is one token boundary:
 
   retire finished -> admit queued (one bucketed prefill for the whole
   mixed-length admit batch) -> one decode step for every active slot
@@ -54,12 +54,14 @@ increments ``serving.evicted_total`` itself.
 """
 from __future__ import annotations
 
+import functools
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..models.decoder import DecoderSpec
 from ..models.generation import _cast_params, _gpt_params
 from ..observability import memory as _mem
 from ..observability import metrics as _obs
@@ -71,7 +73,33 @@ from .programs import (jit_tp_with_donated_pools,
                        make_decode_fn, make_prefill_fn)
 from .scheduler import BucketLadder, FifoScheduler, Request
 
-__all__ = ["ServingConfig", "ServingEngine", "build_serving_snapshot"]
+__all__ = ["ServingConfig", "ServingEngine", "build_serving_snapshot",
+           "serving_decoder_spec"]
+
+
+def _qkv_heads_major(cfg) -> bool:
+    """Fused-qkv columns as (heads, 3, hd), not (3, heads, hd): where a
+    tp plan shards heads, so a chip's contiguous shard carries whole
+    heads with their q, k, v. The programs' spec and the snapshot's
+    weights both ask here, so they cannot disagree."""
+    return cfg.tp > 1
+
+
+def serving_decoder_spec(model_config, cfg) -> DecoderSpec:
+    """The block description this config's programs run. Under a tp
+    plan each chip runs n_heads/tp heads and all-reduces the proj/fc2
+    partial contractions through the planned collectives (tp_wire
+    picks the wire tier; f32 is exact)."""
+    spec = DecoderSpec.of(model_config)
+    if cfg.tp == 1:
+        return spec
+    from ..distributed.comm import CommConfig, planned_all_reduce
+    comm_cfg = CommConfig(compress=cfg.tp_wire)
+    return replace(
+        spec, n_heads=spec.n_heads // cfg.tp,
+        qkv_heads_major=_qkv_heads_major(cfg),
+        reduce=lambda t: planned_all_reduce(t, config=comm_cfg,
+                                            axes=("tp",)))
 
 
 def build_serving_snapshot(params, cfg, n_heads: Optional[int] = None
@@ -92,8 +120,7 @@ def build_serving_snapshot(params, cfg, n_heads: Optional[int] = None
     embeddings/norms replicated. Shapes and treedef are unchanged, so
     the swap-validation contract is dtype/shape-identical to tp=1."""
     snap = _cast_params(params, cfg.dtype)
-    tp = cfg.tp
-    if tp > 1:
+    if _qkv_heads_major(cfg):
         if n_heads is None:
             raise ValueError(
                 "build_serving_snapshot needs n_heads under a tp plan "
@@ -107,7 +134,7 @@ def build_serving_snapshot(params, cfg, n_heads: Optional[int] = None
     if cfg.quant == "int8":
         from ..quant.int8_serving import quantize_params
         snap = quantize_params(snap, cfg.quant_config)
-    if tp > 1:
+    if cfg.tp > 1:
         import jax
         from ..distributed.sharding import serving_param_shardings
         snap = jax.device_put(
@@ -257,21 +284,25 @@ class ServingEngine:
         # recompiles)
         self.params = build_serving_snapshot(_gpt_params(model), cfg,
                                              n_heads=self.n_heads)
-        self.eps = float(mcfg.layer_norm_eps)
         self.vocab_size = int(mcfg.vocab_size)
-        hd = int(mcfg.hidden_size) // self.n_heads
         pool_dtype = cfg.dtype or "float32"
         pool_sharding = None
+        jit = jit_with_donated_pools
         if self.tp > 1:
             from jax.sharding import NamedSharding
-            from ..distributed.sharding import SERVING_POOL_SPEC
-            pool_sharding = NamedSharding(cfg.plan.mesh,
-                                          SERVING_POOL_SPEC)
+            from ..distributed.sharding import (SERVING_POOL_SPEC,
+                                                serving_param_specs)
+            pool_sharding = NamedSharding(cfg.plan.mesh, SERVING_POOL_SPEC)
+            # the same programs, shard_mapped over 'tp'
+            jit = functools.partial(
+                jit_tp_with_donated_pools, mesh=cfg.plan.mesh,
+                params_specs=serving_param_specs(self.params),
+                n_plain=3, n_out=2)
         self.cache = PagedKVCache(
             n_layers=int(mcfg.num_layers), n_blocks=cfg.n_blocks,
             block_size=cfg.block_size, n_heads=self.n_heads,
-            head_dim=hd, dtype=pool_dtype,
-            prefix_sharing=cfg.prefix_sharing,
+            head_dim=int(mcfg.hidden_size) // self.n_heads,
+            dtype=pool_dtype, prefix_sharing=cfg.prefix_sharing,
             pool_sharding=pool_sharding, tp=self.tp)
         self.ladder = BucketLadder(cfg.prefill_buckets,
                                    cfg.decode_buckets, cfg.block_size)
@@ -279,41 +310,17 @@ class ServingEngine:
         sampling = (float(cfg.temperature),
                     None if cfg.top_k is None else int(cfg.top_k),
                     None if cfg.top_p is None else float(cfg.top_p))
-        if self.tp > 1:
-            # tp programs: the SAME bodies, shard_mapped over 'tp'.
-            # Each chip runs n_heads/tp heads in the permuted
-            # heads-major qkv layout and all-reduces the proj/fc2
-            # partial contractions through the planned collectives
-            # (tp_wire picks the wire tier; f32 is exact).
-            from ..distributed.comm import (CommConfig,
-                                            planned_all_reduce)
-            from ..distributed.sharding import serving_param_specs
-            comm_cfg = CommConfig(compress=cfg.tp_wire)
 
-            def tp_reduce(t):
-                return planned_all_reduce(t, config=comm_cfg,
-                                          axes=("tp",))
+        def programs(spec, sampling, n_steps):
+            """(prefill, decode) of one model: the target's or the
+            draft's (which a tp plan refuses)."""
+            return (jit(make_prefill_fn(spec, cfg.block_size, sampling)),
+                    jit(make_decode_fn(spec, cfg.block_size, sampling,
+                                       n_steps)))
 
-            mesh = cfg.plan.mesh
-            pspecs = serving_param_specs(self.params)
-            nh_local = self.n_heads // self.tp
-            tp_kw = dict(qkv_heads_major=True, tp_reduce=tp_reduce,
-                         head_dim=hd)
-            self._decode = jit_tp_with_donated_pools(
-                make_decode_fn(self.eps, nh_local, cfg.block_size,
-                               *sampling,
-                               n_steps=int(cfg.decode_chunk), **tp_kw),
-                mesh, pspecs, n_plain=3, n_out=2)
-            self._prefill = jit_tp_with_donated_pools(
-                make_prefill_fn(self.eps, nh_local, cfg.block_size,
-                                *sampling, **tp_kw),
-                mesh, pspecs, n_plain=3, n_out=2)
-        else:
-            self._decode = jit_with_donated_pools(make_decode_fn(
-                self.eps, self.n_heads, cfg.block_size, *sampling,
-                n_steps=int(cfg.decode_chunk)))
-            self._prefill = jit_with_donated_pools(make_prefill_fn(
-                self.eps, self.n_heads, cfg.block_size, *sampling))
+        spec = serving_decoder_spec(mcfg, cfg)
+        self._prefill, self._decode = programs(
+            spec, sampling, int(cfg.decode_chunk))
         # the chunk program serves BOTH new levers (speculative verify
         # at [slots, k+1], shared-prefix suffix prefill at [admit,
         # bucket]) — one jit, shape-bucketed executables
@@ -321,9 +328,8 @@ class ServingEngine:
         self._chunk = None
         if cfg.prefix_sharing or self._spec_k:
             self._chunk = jit_with_donated_pools(make_chunk_fn(
-                self.eps, self.n_heads, cfg.block_size, *sampling))
-        self.draft_cache = None
-        self.draft_params = None
+                spec, cfg.block_size, sampling))
+        self.draft_cache = self.draft_params = None
         self._draft_prefill = self._draft_decode = None
         if self._spec_k:
             if draft_model is None:
@@ -340,26 +346,19 @@ class ServingEngine:
                 raise ValueError(
                     f"max_total_tokens={cfg.max_total_tokens} exceeds "
                     f"the draft's max_seq_len={dcfg.max_seq_len}")
-            self._draft_heads = int(dcfg.num_heads)
-            self._draft_eps = float(dcfg.layer_norm_eps)
+            dspec = DecoderSpec.of(dcfg)
             # draft keeps the plain float cast (no int8): it is small
             # by construction, and its only job is proposal quality
             self.draft_params = _cast_params(_gpt_params(draft_model),
                                              cfg.dtype)
             self.draft_cache = PagedKVCache(
                 n_layers=int(dcfg.num_layers), n_blocks=cfg.n_blocks,
-                block_size=cfg.block_size, n_heads=self._draft_heads,
-                head_dim=int(dcfg.hidden_size) // self._draft_heads,
-                dtype=pool_dtype)
-            greedy = (0.0, None, None)   # proposals are always argmax
-            self._draft_prefill = jit_with_donated_pools(
-                make_prefill_fn(self._draft_eps, self._draft_heads,
-                                cfg.block_size, *greedy))
-            # ONE scan dispatch proposes all k tokens
-            self._draft_decode = jit_with_donated_pools(
-                make_decode_fn(self._draft_eps, self._draft_heads,
-                               cfg.block_size, *greedy,
-                               n_steps=self._spec_k))
+                block_size=cfg.block_size, n_heads=dspec.n_heads,
+                head_dim=dspec.head_dim, dtype=pool_dtype)
+            # proposals are always argmax, and ONE scan dispatch
+            # proposes all k tokens
+            self._draft_prefill, self._draft_decode = programs(
+                dspec, (0.0, None, None), self._spec_k)
         self.sentinel = RecompileSentinel("serving")
         self._key = jax.random.key(int(cfg.seed))
         self._step_no = 0

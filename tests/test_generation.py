@@ -10,6 +10,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
+from paddle_tpu.models.decoder import DecoderSpec
 
 
 @pytest.fixture(scope="module")
@@ -81,9 +82,8 @@ class TestKVCacheDecode:
         rng = np.random.RandomState(5)
         ids = rng.randint(0, 97, (2, 6)).astype(np.int32)
         model.generate(paddle.to_tensor(ids), max_new_tokens=4)
-        run = _build_run(float(model.gpt.config.layer_norm_eps),
-                         model.gpt.config.num_heads, 0.0, None, None,
-                         0, 4, 6, 10, None)
+        run = _build_run(DecoderSpec.of(model.gpt.config), 0.0, None,
+                         None, 0, 4, 6, 10, None)
         before = run._cache_size()
         model.generate(paddle.to_tensor(ids), max_new_tokens=4)
         model.generate(paddle.to_tensor(ids + 1), max_new_tokens=4)
@@ -112,10 +112,8 @@ class TestBeamSearch:
         ids = rng.randint(0, 97, (2, 5)).astype(np.int32)
         g = np.asarray(model.generate(paddle.to_tensor(ids),
                                       max_new_tokens=6)._data)
-        cfg = model.gpt.config
-        run = _build_beam_run(float(cfg.layer_norm_eps),
-                              int(cfg.num_heads), 1, None, 0, 6, 5, 11,
-                              None)
+        run = _build_beam_run(DecoderSpec.of(model.gpt.config), 1, None,
+                              0, 6, 5, 11, None)
         b, _ = run(_gpt_params(model), ids, jax.random.key(0))
         np.testing.assert_array_equal(g, np.asarray(b))
 
@@ -197,9 +195,8 @@ class TestServingDtype:
         import jax
         from paddle_tpu.models.generation import (_build_run,
                                                   _gpt_params)
-        run = _build_run(float(model.gpt.config.layer_norm_eps),
-                         model.gpt.config.num_heads, 0.0, None, None,
-                         0, 4, 6, 10, "bfloat16")
+        run = _build_run(DecoderSpec.of(model.gpt.config), 0.0, None,
+                         None, 0, 4, 6, 10, "bfloat16")
         params = _gpt_params(model)
         ids = np.zeros((2, 6), np.int32)
         text = run.lower(params, ids, jax.random.key(0)).as_text()

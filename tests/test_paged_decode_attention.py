@@ -2,7 +2,7 @@
 
 On a TPU `make_decode_fn` attends through
 `pallas_kernels.paged_decode_attention`; elsewhere through the gather
-and `_attend`, which stay the reference and the anchor of the f32
+and `masked_attention`, which stay the reference and the anchor of the f32
 "paged equals dense" contract (tests/test_serving_engine.py). Here the
 kernel runs under the Pallas interpreter on toy pools and is held to
 that reference; `tests/test_pallas_mosaic_compile.py` compiles it with
@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models.generation import _attend
+from paddle_tpu.models.decoder import masked_attention, prefix_mask
 from paddle_tpu.ops import pallas_kernels as pk
 from paddle_tpu.serving.programs import _gathered
 
@@ -38,7 +38,8 @@ def _reference(q, kp, vp, tables, lengths, scale):
     nh, hd = q.shape[1:]
     kc = _gathered(kp, tables, nh, hd)
     vc = _gathered(vp, tables, nh, hd)
-    return _attend(q[:, :, None, :], kc, vc, lengths, scale)[:, :, 0, :]
+    mask = prefix_mask(kc.shape[2], lengths)
+    return masked_attention(q[:, None], kc, vc, mask, scale)[:, 0]
 
 
 def _case(dtype, nh, length, hd=HD):

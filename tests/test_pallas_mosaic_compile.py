@@ -8,6 +8,7 @@ sandbox — what it refuses, the chip refuses (a three-value
 What the on-chip PRNG *produces* is only checked on a chip
 (`PD_TEST_TPU=1 pytest tests/test_pallas_attention.py`).
 """
+import dataclasses
 import functools
 import re
 
@@ -18,6 +19,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
+from paddle_tpu.models.decoder import DecoderSpec
 from paddle_tpu.ops import pallas_kernels as pk
 
 
@@ -137,6 +139,8 @@ def test_sharded_train_step_lowers_for_v5e_with_the_kernel_on(monkeypatch):
 
 # the chat cell's engine shape (perfbench/configs/gpt2-large.json)
 SLOTS, TABLE_W, PAGE, HEADS, HEAD = 32, 64, 16, 20, 64
+_SPEC = DecoderSpec(eps=1e-5, n_heads=HEADS, head_dim=HEAD)
+_GREEDY = (0.0, None, None)
 
 
 def _pool_lines(text, n_pages, width):
@@ -226,7 +230,7 @@ def _compile_decode(n_layers, n_pages, monkeypatch):
     one = SingleDeviceSharding(_v5e_devices()[0])
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
     fn = jit_with_donated_pools(make_decode_fn(
-        1e-5, HEADS, PAGE, 0.0, None, None, n_steps=4))
+        _SPEC, PAGE, _GREEDY, n_steps=4))
     return fn.trace(*_gpt2_large_decode_avals(
         n_layers, n_pages, one, one, lambda name: one)).lower(
             lowering_platforms=("tpu",)).compile()
@@ -243,8 +247,7 @@ def _compile_prefill(n_layers, n_pages, monkeypatch):
     admit, bucket = 4, 1024
     s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one)
-    fn = jit_with_donated_pools(make_prefill_fn(
-        1e-5, HEADS, PAGE, 0.0, None, None))
+    fn = jit_with_donated_pools(make_prefill_fn(_SPEC, PAGE, _GREEDY))
     return fn.trace(pools, s32(admit, TABLE_W), s32(admit, bucket),
                     s32(admit), params, key).lower(
                         lowering_platforms=("tpu",)).compile()
@@ -316,9 +319,10 @@ def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
         lambda name: at(SERVING_TP_RULES.get(name, P())))
     specs = jax.tree_util.tree_map(lambda a: a.sharding.spec, avals[4])
     fn = jit_tp_with_donated_pools(
-        make_decode_fn(1e-5, HEADS // tp, PAGE, 0.0, None, None, n_steps=4,
-                       qkv_heads_major=True, head_dim=HEAD,
-                       tp_reduce=lambda t: jax.lax.psum(t, "tp")),
+        make_decode_fn(dataclasses.replace(
+            _SPEC, n_heads=HEADS // tp, qkv_heads_major=True,
+            reduce=lambda t: jax.lax.psum(t, "tp")), PAGE, _GREEDY,
+            n_steps=4),
         mesh, specs, n_plain=3, n_out=2)
     text = fn.trace(*avals).lower(
         lowering_platforms=("tpu",)).compile().as_text()

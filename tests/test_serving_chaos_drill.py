@@ -3,11 +3,14 @@
 Tier-1: the --smoke kill drill — 3 in-process replicas under open-loop
 load, replica 1 killed mid-decode at a named fleet tick; the receipt
 must show ZERO dropped requests, >= 1 evicted request replayed
-BIT-IDENTICALLY (f32 greedy parity), p99 TTFT recovered inside the
-bound, and one remediation receipt naming the replica (the ISSUE's
-serving twin of the goodput drill).
+BIT-IDENTICALLY (f32 greedy parity) and one remediation receipt naming
+the replica (the ISSUE's serving twin of the goodput drill). The smoke
+judges counts and identity only: it shares its CPU with the other test
+workers, where a wall-clock TTFT says nothing, so `p99_recovery_s` is
+reported there and judged by the full drills.
 
-Slow tier: the stall / swap / overload drills at full shapes.
+Slow tier: the kill / stall / swap / overload drills at full shapes,
+p99 TTFT recovered inside the bound among their bars.
 """
 import io
 import json
@@ -42,7 +45,7 @@ class TestSmokeKillDrill:
         assert x["replay"]["bit_identical"] is True
         assert x["receipt_names_replica"] is True
         assert x["expected_verdict"] == "crash"
-        assert 0.0 <= x["p99_recovery_s"] <= x["recovery_bound_s"]
+        assert x["recovery_judged"] is False
         # the trace-ALONE breach verdict names the evicted replica and
         # the requeue component (no receipts consulted)
         v = x["breach_verdict"]
@@ -69,13 +72,17 @@ class TestSmokeKillDrill:
 @pytest.mark.slow  # ~8 s each at full shapes; the tier-1 smoke above
 #   keeps the kill path + receipt contract covered
 class TestFullDrills:
-    def test_stall_drill(self, tmp_path):
-        rc, rep = _run(["--mode", "stall", "--check",
+    @pytest.mark.parametrize("mode,verdict", [("kill", "crash"),
+                                              ("stall", "hang")])
+    def test_fault_drill(self, tmp_path, mode, verdict):
+        rc, rep = _run(["--mode", mode, "--check",
                         "--receipts-dir", str(tmp_path)])
         assert rc == 0
         x = rep["extras"]
         assert x["receipt_ok"] is True
-        assert x["expected_verdict"] == "hang"
+        assert x["expected_verdict"] == verdict
+        assert x["recovery_judged"] is True
+        assert 0.0 <= x["p99_recovery_s"] <= x["recovery_bound_s"]
         assert x["dropped"] == 0
         assert x["replay"]["bit_identical"] is True
 

@@ -23,6 +23,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
+from paddle_tpu.models.decoder import DecoderSpec
 from paddle_tpu.models.generation import _gpt_params
 from paddle_tpu.quant import QuantConfig
 from paddle_tpu.quant.int8_serving import (
@@ -141,10 +142,8 @@ class TestInt8:
         import jax.numpy as jnp
         rng = np.random.RandomState(6)
         ids = jnp.asarray(rng.randint(0, V, (4, 8)), jnp.int32)
-        mcfg = model.gpt.config
         rec = logits_drift_receipt(_gpt_params(model),
-                                   float(mcfg.layer_norm_eps),
-                                   int(mcfg.num_heads), ids)
+                                   DecoderSpec.of(model.gpt.config), ids)
         assert np.isfinite(rec["logit_drift_int8"])
         assert rec["logit_drift_int8"] < 1.0   # tiny-model logit scale
         assert 0.0 <= rec["top1_agreement_last"] <= 1.0

@@ -397,15 +397,14 @@ def main(argv=None) -> int:
         # bounded relative to the bf16 round-off it replaces
         import jax.numpy as jnp
         import numpy as np
+        from paddle_tpu.models.decoder import DecoderSpec
         from paddle_tpu.models.generation import _gpt_params
         from paddle_tpu.quant.int8_serving import logits_drift_receipt
         L = min(t.ids.size for t in trace[:4])
         ids = jnp.asarray(np.stack([t.ids[:L] for t in trace[:4]]),
                           jnp.int32)
-        mcfg = model.gpt.config
         int8_parity = logits_drift_receipt(
-            _gpt_params(model), float(mcfg.layer_norm_eps),
-            int(mcfg.num_heads), ids)
+            _gpt_params(model), DecoderSpec.of(model.gpt.config), ids)
 
     static_cold = replay_static(model, trace,
                                 batch_size=args.static_batch,

@@ -242,6 +242,11 @@ def run_fault_drill(args, mode):
         tail["cohort"]
         and all(abs(c["share_sum"] - 1.0) <= 0.02
                 for c in tail["cohort"]))
+    # --smoke is tier-1's drill and runs on a CPU it shares with the
+    # other test workers: its receipt holds counts and identity and
+    # reports p99_recovery_s without judging it. A wall-clock TTFT is
+    # judged by the full drills only.
+    recovered = args.smoke or 0.0 <= rec_s <= args.recovery_bound_s
     ok = (dropped == 0
           and replay["replayed"] >= 1
           and replay["bit_identical"] is True
@@ -249,7 +254,7 @@ def run_fault_drill(args, mode):
           and any(e["verdict"] == expected_verdict
                   for e in remediations)
           and summ["recompile_events"] == 0
-          and 0.0 <= rec_s <= args.recovery_bound_s
+          and recovered
           and trace_verdict_ok
           and tail_sums_ok
           and ledger_audited["ok"])
@@ -263,6 +268,7 @@ def run_fault_drill(args, mode):
             "replay": replay,
             "p99_recovery_s": round(rec_s, 3),
             "recovery_bound_s": args.recovery_bound_s,
+            "recovery_judged": not args.smoke,
             "remediation": remediations,
             "receipt_names_replica": receipt_names_replica,
             "expected_verdict": expected_verdict,
