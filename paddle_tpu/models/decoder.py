@@ -139,9 +139,14 @@ def block(spec, bp, x, attend):
         qkv = _mm(xn, bp, "qkv") + bp["qkv_b"]
         if spec.qkv_heads_major:
             qkv = jnp.einsum("bsnch->bscnh", qkv.reshape(b, s, nh, 3, hd))
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         else:
-            qkv = qkv.reshape(b, s, 3, nh, hd)
-        ctx, cache = attend(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+            # thirds of the lanes, then heads: slicing a [.., 3, nh, hd]
+            # view made XLA lay a prefill's whole qkv result out
+            # sequence-minor and copy it back for every reader
+            q, k, v = (t.reshape(b, s, nh, hd)
+                       for t in jnp.split(qkv, 3, axis=-1))
+        ctx, cache = attend(q, k, v)
         proj = _mm(ctx.reshape(b, s, nh * hd), bp, "proj")
         if spec.reduce is not None:
             proj = spec.reduce(proj)

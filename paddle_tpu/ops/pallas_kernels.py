@@ -33,7 +33,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_mha", "flash_attention_mha_sharded",
-           "paged_decode_attention", "pallas_available"]
+           "flash_prefill_attention", "paged_decode_attention",
+           "pallas_available"]
 
 # Max block sizes along the q/k sequence dims. Large blocks amortize the
 # per-grid-step overhead (DMA setup + Mosaic loop) — with head_dim 64 a
@@ -146,6 +147,23 @@ def _drop_mask(seed_ref, bh, i, j, nq, nk, bq, bk, dropout_p, heads):
     return bits >= threshold  # keep with prob 1 - p
 
 
+def _online_softmax(s, mask, m_prev, l_prev):
+    """One key block of the online softmax, the arithmetic every
+    forward kernel here shares: scores `s` [bq, bk] in f32 with their
+    `mask` (None: every link is live), the rows' running max and sum
+    so far -> the block's unnormalised probabilities p (f32, 0 where
+    masked), the new max and sum, and `corr`, what the accumulator
+    built under the old max is worth under the new."""
+    if mask is not None:
+        s = jnp.where(mask, s, _NEG)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    p = jnp.exp(s - m_new[:, None])
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    return p, m_new, l_prev * corr + jnp.sum(p, axis=-1), corr
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref,
                 *, scale, causal, bq, bk, nq, nk, sk, dropout_p, heads):
@@ -174,17 +192,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         if causal:
             row = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             mask = mask & (row >= col)
-        s = jnp.where(mask, s, _NEG)
-
-        m_prev = m_ref[:, 0]
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
         # the softmax denominator sums over ALL links (dropout zeroes
-        # entries of the NORMALIZED probs), so l uses the unmasked p
-        l_new = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
+        # entries of the NORMALIZED probs), so l uses the undropped p
+        p, m_new, l_new, corr = _online_softmax(
+            s, mask, m_ref[:, 0], l_ref[:, 0])
         if dropout_p > 0.0:
             keep = _drop_mask(seed_ref, bh, i, j, nq, nk, bq, bk, dropout_p,
                               heads)
@@ -521,6 +532,163 @@ def flash_attention_mha_sharded(query, key, value, mesh, batch_axes,
         body, mesh=mesh, in_specs=(spec, spec, spec, P()),
         out_specs=spec, check_vma=False)(query, key, value,
                                          _seed_array(seed))
+
+
+# ------------------------------------------------------ flash prefill
+
+# The serving prefill's shapes are the bucket ladder's and nothing else
+# tunes them (not the training kernels' PD_FLASH_BQ / PD_FLASH_BK):
+# the largest query / key block; the most rows of scores a grid step
+# holds over the heads that share its lanes; and the rows of them
+# attended at a time (the chunk after feeds the MXU while this one's
+# softmax runs). The chunks are unrolled, and a kernel's unrolled size
+# is set-up time in every process, compile cache or not: five lane
+# blocks a step were 9% faster a call and 22 s of set-up (PERF.md,
+# PR 31).
+_PREFILL_BLOCK = 512
+_PREFILL_ROWS = 1024
+_PREFILL_CHUNK = 128
+
+
+def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
+                    acc_ref, m_ref, l_ref, *, scale, bq, nk, hd):
+    """One grid step is one (query block, key block) of one prompt for
+    the `g` heads that share a block of lanes: a row of q, k, v and o is
+    a token with its heads side by side (the serving layout, read and
+    written in place), and a step's window is [bq, g * hd] lanes of it
+    (a pair of 64-wide heads). The MXU contracts over all the window's
+    lanes whatever the head size, so the heads are stacked along the
+    rows: g copies of the query block, copy h zeroed outside head h's
+    lanes, are [g * bq, lanes]; their product with the key block over
+    the lanes is every head's scores (the other heads' lanes meet
+    zeros), and p times v weighs every lane, of which copy h's result
+    is wanted in head h's lanes only: picked once, when the query block
+    is done. The stacked rows go through in chunks; a chunk's update
+    is `_fwd_kernel`'s (`_online_softmax`), with the scale folded into
+    q (exact where it is a power of two, as 1/8 is for a head of 64;
+    one more rounding of q elsewhere).
+
+    `lens` decides what is skipped, never what is masked: a true query
+    sees only keys at or before it, which are true too. A step runs
+    iff it is on or under the diagonal and its query block starts
+    before the prompt's end. Only a diagonal block has links to mask
+    (query and key blocks are as long), and there a chunk takes the
+    keys up to its own last row and no further. The accumulator,
+    zeroed at a query block's first step, over a floored sum at its
+    last makes a block that never ran exactly 0."""
+    a, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    lb = q_ref.shape[-1]
+    g = lb // hd
+    chunk = min(_PREFILL_CHUNK, bq)
+    head_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, lb), 1) // hd
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    def step(on_diagonal):
+        k, v = k_ref[0], v_ref[0]
+        q = q_ref[0].astype(jnp.float32) * scale
+        qs = jnp.concatenate(
+            [jnp.where(head_of_lane == h, q, 0.0) for h in range(g)],
+            axis=0).astype(k.dtype)                        # [g * bq, lb]
+        for r in range(0, g * bq, chunk):
+            rows = slice(r, r + chunk)
+            # i * bq == j * bk on the diagonal: the offsets cancel
+            keys = r % bq + chunk if on_diagonal else bq
+            s = _dot(qs[rows], k[:keys], 1, 1)             # [chunk, keys]
+            mask = None
+            if on_diagonal:
+                mask = (r % bq + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+                    >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            p, m_new, l_new, corr = _online_softmax(
+                s, mask, m_ref[rows, 0], l_ref[rows, 0])
+            acc_ref[rows] = acc_ref[rows] * corr[:, None] + _dot(
+                p.astype(v.dtype), v[:keys], 1, 0)
+            m_ref[rows] = jnp.broadcast_to(m_new[:, None], (chunk, 128))
+            l_ref[rows] = jnp.broadcast_to(l_new[:, None], (chunk, 128))
+
+    live = i * bq < lens_ref[a]
+    pl.when(live & (j == i))(functools.partial(step, True))
+    if nk > 1:
+        pl.when(live & (j < i))(functools.partial(step, False))
+
+    @pl.when(j == nk - 1)
+    def _():
+        o = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
+        out = o[:bq]
+        for h in range(1, g):
+            out = jnp.where(head_of_lane == h, o[h * bq:(h + 1) * bq], out)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def flash_prefill_attention(q, k, v, lengths, scale, interpret=False):
+    """Causal attention of right-padded prompts over their own K/V,
+    the scores never leaving the chip.
+
+    q, k, v [A, S, nh, hd] (views of the block's one qkv result);
+    lengths [A] int32, each row's true length. Returns [A, S, nh, hd]
+    in q's dtype: query t < lengths[a] of row a attended keys 0..t,
+    exactly; a query past the length holds something finite that
+    nothing may read (0 where its whole block lies past the length).
+    Scores, the running max and sum and the accumulator are f32, the
+    probabilities rounded to v's dtype for their matmul: what
+    `decoder.masked_attention` does under `causal_mask(S, lengths)`,
+    less its rounding of the scores to the storage dtype.
+
+    Rows are read as [A, S, nh * hd], heads side by side, in blocks of
+    lcm(hd, 128) lanes (two heads of 64), or the whole row where that
+    does not divide it; S is padded to whole 128-row tiles, which no
+    bucket of 128 or more needs. Jitted so that a program calling it
+    once a layer traces and lowers it once."""
+    a, s, nh, hd = q.shape
+    d = nh * hd
+    lb = math.lcm(hd, 128)
+    if d % lb:
+        lb = d
+    g = lb // hd
+    s_p, bq = _pick_block(s, max(128, min(_PREFILL_BLOCK,
+                                          _PREFILL_ROWS // g // 128 * 128)))
+    nq = s_p // bq
+
+    def rows(x):
+        x = x.reshape(a, s, d)
+        return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0))) if s_p != s else x
+
+    def last_key_block(i, lens, row):
+        """Past it a query block's steps are skipped, so their key
+        windows stay where they are and nothing is fetched for them."""
+        return jnp.where(i * bq < lens[row], i, 0)
+
+    q_spec = pl.BlockSpec((1, bq, lb), lambda r, c, i, j, lens: (r, i, c))
+    kv_spec = pl.BlockSpec(
+        (1, bq, lb), lambda r, c, i, j, lens:
+        (r, jnp.minimum(j, last_key_block(i, lens, r)), c))
+    out = pl.pallas_call(
+        functools.partial(_prefill_kernel, scale=float(scale), bq=bq,
+                          nk=nq, hd=hd),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(a, d // lb, nq, nq),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((g * bq, lb), jnp.float32),
+                pltpu.VMEM((g * bq, 128), jnp.float32),
+                pltpu.VMEM((g * bq, 128), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((a, s_p, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret,
+        name="flash_prefill_attention",
+    )(lengths.astype(jnp.int32), rows(q), rows(k), rows(v))
+    return out[:, :s].reshape(a, s, nh, hd)
 
 
 # ------------------------------------------------------- paged decode

@@ -25,7 +25,11 @@ live pages in place through the block table instead of gathering every
 table whole: chosen by the platform as the flash kernel is, and held to
 the gather and `masked_attention` by tests/test_paged_decode_attention.py
 (f32 within 1e-5, the greedy token the same). The chunk program
-(several queries a slot) keeps the gather.
+(several queries a slot) keeps the gather. The prefill's is
+`flash_prefill_attention` on a TPU, a flash forward over the prompt's
+own K/V rows in place (no `[A, heads, S, S]` scores, mask or
+probabilities in HBM), and `masked_attention` under the dense causal
+mask elsewhere (tests/test_flash_prefill_attention.py).
 
 A pool is ``[n_blocks, block_size, n_heads * head_dim]``, one row a
 token with the heads side by side (the shape whose default TPU layout
@@ -112,19 +116,29 @@ def _decode_addressing(spec, block_size, tables, positions):
     return attend
 
 
-def _prefill_addressing(spec, block_size, tables, mask):
-    """A whole prompt a row: the queries attend over this call's own
-    K/V under `mask`, and the K/V rows go page-wise into the first
+def _prefill_addressing(spec, block_size, tables, s, prompt_lens):
+    """A whole prompt a row, right-padded to `s`: the queries attend
+    over this call's own K/V, causally — on a TPU through the flash
+    kernel, which keeps the scores on the chip and takes `prompt_lens`
+    only to skip the blocks past a row's end; elsewhere through
+    `masked_attention` under the dense mask, the reference the kernel
+    is tested against — and the K/V rows go page-wise into the first
     S / block_size pages of each row's table."""
+    from ..ops import pallas_kernels as _pk
+    on_tpu = _pk.pallas_available()
+    mask = None if on_tpu else decoder.causal_mask(s, prompt_lens)
 
     def attend(pools, q, k, v):
-        kc = jnp.einsum("bsnh->bnsh", k)
-        vc = jnp.einsum("bsnh->bnsh", v)
-        ctx = decoder.masked_attention(q, kc, vc, mask, spec.scale)
-        a, s = k.shape[:2]
+        if on_tpu:
+            ctx = _pk.flash_prefill_attention(q, k, v, prompt_lens,
+                                              spec.scale)
+        else:
+            kc = jnp.einsum("bsnh->bnsh", k)
+            vc = jnp.einsum("bsnh->bnsh", v)
+            ctx = decoder.masked_attention(q, kc, vc, mask, spec.scale)
         nblk = s // block_size
         return ctx, _written(pools, tables[:, :nblk],
-                             (a, nblk, block_size, -1), k, v)
+                             (k.shape[0], nblk, block_size, -1), k, v)
 
     return attend
 
@@ -207,9 +221,10 @@ def make_prefill_fn(spec, block_size: int, sampling):
     run(pools, tables, ids, prompt_lens, params, key) -> (pools', tok)
 
     ids [A, S] is right-padded to the bucket width S (a multiple of
-    block_size: BucketLadder refuses any other); prompt_lens [A] drives
-    the causal mask's key limit, so each row's hidden state at its own
-    last true token is exactly what the dense ragged path computes.
+    block_size: BucketLadder refuses any other); a true token attends
+    causally, so never past its row's prompt_lens [A], and each row's
+    hidden state at its own last true token is exactly what the dense
+    ragged path computes.
     Each layer's K/V rows are scattered page-wise into the pools and
     the first generated token is picked from the last-token logits.
     """
@@ -220,8 +235,7 @@ def make_prefill_fn(spec, block_size: int, sampling):
             x = decoder.embed(params, ids, jnp.arange(s))
         x, pools = decoder.blocks(
             spec, params, x, pools, _prefill_addressing(
-                spec, block_size, tables,
-                decoder.causal_mask(s, prompt_lens)))
+                spec, block_size, tables, s, prompt_lens))
         with _scope("lm_head"):
             idx = (prompt_lens - 1).astype(jnp.int32)
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)

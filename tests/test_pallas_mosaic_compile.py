@@ -236,15 +236,17 @@ def _compile_decode(n_layers, n_pages, monkeypatch):
             lowering_platforms=("tpu",)).compile()
 
 
-def _compile_prefill(n_layers, n_pages, monkeypatch):
-    """make_prefill_fn at the score cell's widest shape, 4 prompts of
-    1,024 tokens, pools donated, compiled for one v5e chip."""
+def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024):
+    """make_prefill_fn (the flash kernel chosen as on a TPU, pools
+    donated) at 4 prompts of `bucket` tokens, the score cell's widest
+    shape by default, compiled for one v5e chip."""
     from paddle_tpu.serving.programs import (jit_with_donated_pools,
                                              make_prefill_fn)
     one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
     pools, _, _, _, params, key = _gpt2_large_decode_avals(
         n_layers, n_pages, one, one, lambda name: one)
-    admit, bucket = 4, 1024
+    admit = 4
     s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one)
     fn = jit_with_donated_pools(make_prefill_fn(_SPEC, PAGE, _GREEDY))
@@ -301,35 +303,110 @@ def test_serving_programs_do_not_convert_the_pools(program, monkeypatch):
     assert f"[{n_pages},{PAGE},{HEADS},{HEAD}]" not in text
 
 
+def _tp2(n_layers, make, n_plain, plain):
+    """A serving program under `MeshPlan(tp=2)`, as the engine builds
+    it: the body inside a shard_map over 'tp' with the LOCAL head
+    count. `plain(s32)` makes the replicated host arrays between the
+    pools and the parameters. Returns the compiled text."""
+    from paddle_tpu.distributed.sharding import (SERVING_POOL_SPEC,
+                                                 SERVING_TP_RULES)
+    from paddle_tpu.serving.programs import jit_tp_with_donated_pools
+    mesh = Mesh(np.asarray(_v5e_devices()[:2]), ("tp",))
+    tp = 2
+    at = lambda spec: NamedSharding(mesh, spec)
+    pools, _, _, _, params, key = _gpt2_large_decode_avals(
+        n_layers, 1024, at(SERVING_POOL_SPEC), at(P()),
+        lambda name: at(SERVING_TP_RULES.get(name, P())))
+    specs = jax.tree_util.tree_map(lambda a: a.sharding.spec, params)
+    s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=at(P()))
+    fn = jit_tp_with_donated_pools(
+        make(dataclasses.replace(
+            _SPEC, n_heads=HEADS // tp, qkv_heads_major=True,
+            reduce=lambda t: jax.lax.psum(t, "tp"))),
+        mesh, specs, n_plain=n_plain, n_out=2)
+    return fn.trace(pools, *plain(s32), params, key).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+
 def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
     """Under `MeshPlan(tp=2)` the same body runs inside a shard_map
     over 'tp': each chip's kernel call sees its 10 of the 20 heads of
     every page, [1024, 16, 640] pools, and nothing gathers or converts
     them."""
-    from paddle_tpu.distributed.sharding import (SERVING_POOL_SPEC,
-                                                 SERVING_TP_RULES)
-    from paddle_tpu.serving.programs import (jit_tp_with_donated_pools,
-                                             make_decode_fn)
-    mesh = Mesh(np.asarray(_v5e_devices()[:2]), ("tp",))
+    from paddle_tpu.serving.programs import make_decode_fn
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
     n_layers, tp = 2, 2
-    at = lambda spec: NamedSharding(mesh, spec)
-    avals = _gpt2_large_decode_avals(
-        n_layers, 1024, at(SERVING_POOL_SPEC), at(P()),
-        lambda name: at(SERVING_TP_RULES.get(name, P())))
-    specs = jax.tree_util.tree_map(lambda a: a.sharding.spec, avals[4])
-    fn = jit_tp_with_donated_pools(
-        make_decode_fn(dataclasses.replace(
-            _SPEC, n_heads=HEADS // tp, qkv_heads_major=True,
-            reduce=lambda t: jax.lax.psum(t, "tp")), PAGE, _GREEDY,
-            n_steps=4),
-        mesh, specs, n_plain=3, n_out=2)
-    text = fn.trace(*avals).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
+    text = _tp2(
+        n_layers,
+        lambda spec: make_decode_fn(spec, PAGE, _GREEDY, n_steps=4), 3,
+        lambda s32: (s32(SLOTS, TABLE_W), s32(SLOTS), s32(SLOTS)))
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == n_layers
     local = f"bf16[1024,{PAGE},{HEADS // tp * HEAD}]"
     assert all(ln.count(local) >= 2 for ln in calls), calls[0][:400]
     assert f"[1024,{PAGE},{HEADS * HEAD}]" not in text
+    assert "all-gather" not in text
+    assert not _pool_copies(text, 1024, HEADS // tp * HEAD)
+
+
+# ------------------------------------------------ flash prefill attention
+
+@pytest.mark.parametrize("heads", [HEADS, HEADS // 2],
+                         ids=["nh20", "tp_shard_nh10"])
+@pytest.mark.parametrize("bucket", [128, 1024, 48])
+def test_flash_prefill_kernel_compiles_for_one_v5e_chip(bucket, heads):
+    """Rows [bucket, heads x 64] read in place in 128-lane blocks (a
+    pair of heads): one Mosaic call under its own name, whatever the
+    bucket (48 is padded to one 128-row tile)."""
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    qkv = jax.ShapeDtypeStruct((4, bucket, heads, HEAD), jnp.bfloat16)
+    lens = jax.ShapeDtypeStruct((4,), jnp.int32)
+
+    def attn(q, k, v, lens):
+        return pk.flash_prefill_attention(q, k, v, lens, 0.125)
+    text = _compile(attn, (qkv, qkv, qkv, lens), (one,) * 4).as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_prefill_attention" in text
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_prefill_program_keeps_its_scores_on_the_chip(bucket, monkeypatch,
+                                                      capsys):
+    """The whole prefill program (36 layers cut to 2 for time) with
+    the kernel chosen as on a TPU: one Mosaic call a layer and no
+    [4, 20, s, s] tensor of scores, mask or probabilities, nor the
+    heads-first [4, 20, s, 64] copies the dense attention read. The
+    temporaries (175 MB at 2 layers of 4 x 1,024 with the dense
+    attention) are printed, and held under 20 MB."""
+    n_layers = 2
+    compiled = _compile_prefill(n_layers, 1024, monkeypatch, bucket)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == n_layers
+    assert "flash_prefill_attention" in text
+    assert f"[4,{HEADS},{bucket},{bucket}]" not in text
+    assert f"[4,{HEADS},{bucket},{HEAD}]" not in text
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nprefill program, {n_layers} layers, 4 x {bucket}: "
+              f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB")
+    assert mem.temp_size_in_bytes < 20e6
+
+
+def test_tp2_prefill_program_runs_the_kernel_on_local_heads(monkeypatch):
+    """Under tp=2 the prefill body's kernel call takes this chip's 10
+    heads of every row, [4, 128, 640], and no tensor of scores is
+    left; the pools are neither gathered nor converted."""
+    from paddle_tpu.serving.programs import make_prefill_fn
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    n_layers, tp, bucket = 2, 2, 128
+    text = _tp2(
+        n_layers, lambda spec: make_prefill_fn(spec, PAGE, _GREEDY), 3,
+        lambda s32: (s32(4, TABLE_W), s32(4, bucket), s32(4)))
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == n_layers
+    local = f"bf16[4,{bucket},{HEADS // tp * HEAD}]"
+    assert all(ln.count(local) >= 4 for ln in calls), calls[0][:400]
+    assert f"[4,{HEADS // tp},{bucket},{bucket}]" not in text
     assert "all-gather" not in text
     assert not _pool_copies(text, 1024, HEADS // tp * HEAD)
