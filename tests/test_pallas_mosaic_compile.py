@@ -222,6 +222,14 @@ def _gpt2_large_decode_avals(n_layers, n_pages, pool_at, rep, param_at):
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep))
 
 
+def _engine_precision():
+    """conftest.py asks for "highest" matmuls everywhere; the engine
+    runs jax's default, and XLA fuses the two otherwise (under
+    "highest" it nests no matmul fusion in another): the serving
+    programs are compiled here as the chip compiles them."""
+    return jax.default_matmul_precision("default")
+
+
 def _compile_decode(n_layers, n_pages, monkeypatch):
     """make_decode_fn (the kernel chosen as on a TPU, 4 tokens a
     dispatch, pools donated) compiled for one v5e chip."""
@@ -231,9 +239,10 @@ def _compile_decode(n_layers, n_pages, monkeypatch):
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
     fn = jit_with_donated_pools(make_decode_fn(
         _SPEC, PAGE, _GREEDY, n_steps=4))
-    return fn.trace(*_gpt2_large_decode_avals(
-        n_layers, n_pages, one, one, lambda name: one)).lower(
-            lowering_platforms=("tpu",)).compile()
+    with _engine_precision():
+        return fn.trace(*_gpt2_large_decode_avals(
+            n_layers, n_pages, one, one, lambda name: one)).lower(
+                lowering_platforms=("tpu",)).compile()
 
 
 def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024):
@@ -250,9 +259,10 @@ def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024):
     s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one)
     fn = jit_with_donated_pools(make_prefill_fn(_SPEC, PAGE, _GREEDY))
-    return fn.trace(pools, s32(admit, TABLE_W), s32(admit, bucket),
-                    s32(admit), params, key).lower(
-                        lowering_platforms=("tpu",)).compile()
+    with _engine_precision():
+        return fn.trace(pools, s32(admit, TABLE_W), s32(admit, bucket),
+                        s32(admit), params, key).lower(
+                            lowering_platforms=("tpu",)).compile()
 
 
 @pytest.mark.parametrize("n_pages", [1024, 2048])
@@ -325,8 +335,9 @@ def _tp2(n_layers, make, n_plain, plain):
             _SPEC, n_heads=HEADS // tp, qkv_heads_major=True,
             reduce=lambda t: jax.lax.psum(t, "tp"))),
         mesh, specs, n_plain=n_plain, n_out=2)
-    return fn.trace(pools, *plain(s32), params, key).lower(
-        lowering_platforms=("tpu",)).compile().as_text()
+    with _engine_precision():
+        return fn.trace(pools, *plain(s32), params, key).lower(
+            lowering_platforms=("tpu",)).compile().as_text()
 
 
 def test_tp2_decode_program_runs_the_kernel_on_local_heads(monkeypatch):
@@ -410,3 +421,120 @@ def test_tp2_prefill_program_runs_the_kernel_on_local_heads(monkeypatch):
     assert f"[4,{HEADS // tp},{bucket},{bucket}]" not in text
     assert "all-gather" not in text
     assert not _pool_copies(text, 1024, HEADS // tp * HEAD)
+
+
+# ------------------------------------- where the MLP's activation rides
+
+FFN = 4 * HEADS * HEAD
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$")
+_INSTRUCTION = re.compile(
+    r"^\s+(?:ROOT )?%([\w.\-]+) = (\(?[^=]*?\)?) ([\w\-]+)\((.*)$")
+# instructions that compute nothing an element (a `fusion` is counted
+# through its body)
+_TRIVIAL = ("parameter", "constant", "broadcast", "bitcast", "iota",
+            "tuple", "fusion")
+
+
+def _computations(text):
+    """The compiled text as {computation: [(name, type, opcode, rest
+    of the line)]}."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        m = _COMPUTATION.match(ln)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = _INSTRUCTION.match(ln)
+            if m:
+                cur.append(m.groups())
+    return comps
+
+
+def _width(type_str):
+    """The last dimension of an instruction's (first) result."""
+    m = re.search(r"\w+\[([\d,]*)\]", type_str)
+    return int(m.group(1).split(",")[-1]) if m and m.group(1) else None
+
+
+def _with_nested(comps, name):
+    """A computation's instructions and those of the fusions it calls
+    (XLA nests a producer fusion inside its consumer's)."""
+    for ins in comps[name]:
+        yield ins
+        for callee in re.findall(r"calls=%([\w.\-]+)", ins[3]):
+            yield from _with_nested(comps, callee)
+
+
+def _mlp_fusions(comps):
+    """({fc2's fused computations}, {fc1's}): those that hold the
+    convolution [.., FFN] -> [.., 1280], and the other way round.
+    Operands are printed by name, so their widths are looked up."""
+    fc2, fc1 = set(), set()
+    for name, instrs in comps.items():
+        widths = {n: _width(t) for n, t, _, _ in instrs}
+        for _, t, op, rest in instrs:
+            if op != "convolution":
+                continue
+            operand = widths.get(re.findall(r"%([\w.\-]+)", rest)[0])
+            if (operand, _width(t)) == (FFN, HEADS * HEAD):
+                fc2.add(name)
+            elif (operand, _width(t)) == (HEADS * HEAD, FFN):
+                fc1.add(name)
+    return fc2, fc1
+
+
+# (most non-trivial instructions in fc2's fusion, `copy` instructions
+# of the parent of the change that moved the activation)
+_ACTIVATION_CASES = {"prefill_1024": (16, 1), "prefill_128": (40, 0),
+                     "decode_32x4": (40, 8)}
+
+
+@pytest.mark.parametrize("program", list(_ACTIVATION_CASES))
+def test_gelu_is_not_expanded_in_fc2s_operand(program, monkeypatch, capsys):
+    """The counter of where the activation is evaluated. With
+    `jax.nn.gelu(approximate=False)` on the bf16 result XLA expanded
+    `erfc` (84 instructions an element, both branches, an exponential
+    and two divides) into the OPERAND of fc2's matmul fusion, and a
+    side fusion packed one of its predicates into `u8[1024, 5120]`;
+    `decoder._gelu` keeps `erf` one instruction. At 4 x 1,024 the
+    activation rides in fc1's epilogue and fc2's fusion is the matmul,
+    the residual and the next LayerNorm's row sum (9 instructions). At
+    4 x 128 and at the decode program's 32 rows XLA nests fc1's whole
+    fusion, LayerNorm and `erf` with it, inside fc2's (two
+    convolutions, 28-35 instructions where the expansion made 82-99;
+    the LayerNorm's divide is over [.., 1280]). Fails when a later
+    change lets the expansion back into a matmul's operand."""
+    most, parent_copies = _ACTIVATION_CASES[program]
+    n_layers = 2
+    if program == "decode_32x4":
+        text = _compile_decode(n_layers, 1024, monkeypatch).as_text()
+    else:
+        text = _compile_prefill(n_layers, 1024, monkeypatch,
+                                int(program.split("_")[1])).as_text()
+    comps = _computations(text)
+    fc2, fc1 = _mlp_fusions(comps)
+    assert len(fc2) == n_layers, sorted(fc2)
+    for name in fc2:
+        body = list(_with_nested(comps, name))
+        ops = [op for _, _, op, _ in body]
+        assert "exponential" not in ops, name
+        assert not [n for n, t, op, _ in body
+                    if op == "divide" and _width(t) == FFN], name
+        assert ops.count("erf") <= 1
+        busy = [op for op in ops if op not in _TRIVIAL]
+        assert len(busy) <= most, (name, len(busy), sorted(busy))
+    assert not re.search(rf"= \(?u8\[\d+,{FFN}\]", text)
+    copies = [n for instrs in comps.values() for n, _, op, _ in instrs
+              if op == "copy"]
+    assert len(copies) <= parent_copies, copies
+    with capsys.disabled():
+        print(f"\n{program}, {n_layers} layers: estimated cycles of")
+        for instrs in comps.values():
+            for name, _, op, rest in instrs:
+                callee = re.search(r"calls=%([\w.\-]+)", rest)
+                cycles = re.search(r'"estimated_cycles":"(\d+)"', rest)
+                if op == "fusion" and cycles and callee.group(1) in fc2 | fc1:
+                    which = "fc2" if callee.group(1) in fc2 else "fc1"
+                    print(f"  {which} {name}: {cycles.group(1)}")
