@@ -8,4 +8,5 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForPretraining,
                     ErnieForSequenceClassification)  # noqa: F401
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .generation import generate_gpt  # noqa: F401
+from .retention import RetentionConfig, RetentionForCausalLM  # noqa: F401
 from .yolo import YOLOv3, DarkNetTiny, yolov3_default_anchors  # noqa: F401

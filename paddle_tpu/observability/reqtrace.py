@@ -38,8 +38,9 @@ marks are points:
 
 Engine steps are on the same ring (``open_step`` and the handle it
 returns, the ONE writer): every ``ServingEngine.step()`` that has work
-is one ``step`` span (rid None; step, replica, and ``executables``,
-the engine's compile count at that boundary) over contiguous phase
+is one ``step`` span (rid None; step, replica, ``executables``, the
+engine's compile count at that boundary, and ``state_rows_live`` where
+the engine's cache is state rows) over contiguous phase
 spans (``STEP_PHASES``; step, replica, parent="step", and the
 program's ``kind`` on a dispatch's phases). The same call that writes
 a phase to the ring enters ``jax.profiler.TraceAnnotation(
@@ -247,14 +248,16 @@ class _Step:
         annotation.__enter__()
         self._open = (name, now, meta, annotation)
 
-    def close(self, executables: Optional[int] = None):
+    def close(self, executables: Optional[int] = None, **counts):
         """End the step: its last phase, then the ``step`` span with
-        the engine's compile count at this boundary."""
+        the engine's compile count at this boundary and what else the
+        engine counts there (``state_rows_live`` where its cache is
+        state rows)."""
         now = self._close_phase(None)
         self._whole.__exit__(None, None, None)
         _tracer.record_span(None, "step", self.t0, now, step=self.step,
                             replica=self.replica,
-                            executables=executables)
+                            executables=executables, **counts)
 
 
 class _NoStep:
@@ -265,7 +268,7 @@ class _NoStep:
     def phase(self, name, kind=None, t=None):
         pass
 
-    def close(self, executables=None):
+    def close(self, executables=None, **counts):
         pass
 
 

@@ -34,7 +34,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_mha", "flash_attention_mha_sharded",
            "flash_prefill_attention", "paged_decode_attention",
-           "pallas_available"]
+           "retention_decode", "pallas_available"]
 
 # Max block sizes along the q/k sequence dims. Large blocks amortize the
 # per-grid-step overhead (DMA setup + Mosaic loop) — with head_dim 64 a
@@ -882,3 +882,146 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
     )(slot, rnd, fetch, lengths, q.astype(jnp.float32).reshape(b, d), sel,
       *([k_pool] * pps), *([v_pool] * pps))
     return out.astype(k_pool.dtype).reshape(b, nh, hd)
+
+
+# -- retention decode: one pass over a lane's state row -----------------------
+
+# rows of the state (values, e) a loop iteration holds: 32 rows of 128
+# lanes are 4 registers of state and 4 of each query head's running
+# sum, which with 5 query heads stay in registers over the whole loop
+_RETENTION_ROWS = 32
+
+
+def _retention_decode_kernel(rows_ref, x_ref, s_ref, z_ref, y_ref, so_ref,
+                             zo_ref, p_ref, a_ref, *, grp, hd, et):
+    """One grid step is one lane's state of one key-value head:
+    S [F, hd, hd] (`S[d, e, a]`: feature offset d, value e in the
+    sublanes, feature position a in the lanes) and z [F rounded up to
+    8, hd] (the rows past F zero), read once and written once, in
+    place.
+
+    `x` [8, hd] holds the step's vectors one a sublane: the `grp` query
+    heads of this key-value head, then k, v, and the decay e^gate in
+    every lane. Row d of the features of all of them at once is
+    `c_d x roll(x, d)` (models/decoder.py, "the retention mixer"): one
+    register, kept in `p` (row 8 d + i is vector i's; the rows of z's
+    padding are zero). The state's plane d becomes
+    `decay S[d] + v (x) phi(k)[d]` (v down the sublanes, phi(k)[d]
+    along the lanes), is stored, and is multiplied into each query
+    head's running sum `acc_i[e, a] += S'[d, e, a] phi(q_i)[d, a]`
+    while it is in registers; the sums over d stay elementwise, the sum
+    over a is taken once at the end (a transpose and a sublane sum, so
+    that e comes out in the lanes). z alike, whole."""
+    del rows_ref                                # the index maps read it
+    nf, nfz = hd // 2 + 1, z_ref.shape[2]
+    x8 = x_ref[0, 0]                                           # [8, hd]
+    for d in range(nf):
+        c = 1.0 if d in (0, hd // 2) else math.sqrt(2.0)
+        p_ref[pl.ds(8 * d, 8), :] = x8 * pltpu.roll(x8, d, 1) * c
+    p_ref[pl.ds(8 * nf, 8 * (nfz - nf)), :] = jnp.zeros(
+        (8 * (nfz - nf), hd), jnp.float32)
+    decay = x8[grp + 2:grp + 3, :]                             # [1, hd]
+    fk = p_ref[pl.ds(grp, nfz, stride=8), :]                   # [Fz, hd]
+    z_new = decay * z_ref[0, 0] + fk
+    zo_ref[0, 0] = z_new
+    den = [jnp.sum(p_ref[pl.ds(i, nfz, stride=8), :] * z_new,
+                   keepdims=True) for i in range(grp)]         # [1, 1]
+    # v down the sublanes, the same in every lane
+    v_col = jnp.broadcast_to(x8[grp + 1:grp + 2, :], (hd, hd)).T
+    decay_t = jnp.broadcast_to(decay, (et, hd))
+    for e0 in range(0, hd, et):
+        v_t = v_col[e0:e0 + et, :]
+
+        def plane(d, accs, e0=e0, v_t=v_t):
+            p = p_ref[pl.ds(pl.multiple_of(d * 8, 8), 8), :]
+            m = decay_t * s_ref[0, 0, d, e0:e0 + et, :] \
+                + v_t * p[grp:grp + 1, :]
+            so_ref[0, 0, d, e0:e0 + et, :] = m
+            return tuple(acc + m * p[i:i + 1, :]
+                         for i, acc in enumerate(accs))
+
+        accs = jax.lax.fori_loop(
+            0, nf, plane,
+            tuple(jnp.zeros((et, hd), jnp.float32) for _ in range(grp)))
+        for i, acc in enumerate(accs):
+            a_ref[i, e0:e0 + et, :] = acc
+    out = [jnp.sum(a_ref[i].T, axis=0, keepdims=True) / den[i]
+           for i in range(grp)]
+    y_ref[0, 0] = jnp.concatenate(
+        out + [jnp.zeros((8 - grp, hd), jnp.float32)], axis=0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def retention_decode(state, rows, q, k, v, gate, interpret=False):
+    """One token a lane through the retention state, read and written
+    once: `decoder.retention_step` as one Mosaic call.
+
+    state = (S [R, n_kv, F, hd, hd], z [R, n_kv, F rounded up to 8,
+    hd]) in f32, F = hd/2 + 1; rows [B] int32, the state row of each lane (dead
+    lanes name the scratch row 0); q [B, N, hd], k and v [B, n_kv, hd],
+    gate [B, n_kv] (f32 log decays). Lane b's row is scaled by its
+    decay, gains phi(k) v^T, is written back in place (the state
+    operands are aliased to the outputs: donate them), and
+    phi(q)^T S / phi(q)^T z is accumulated for the N / n_kv query
+    heads of each key-value head from the planes in VMEM. All
+    arithmetic in f32. Returns (ctx [B, N, hd] in q's dtype, state').
+    `interpret=True` runs the kernel under the Pallas interpreter (the
+    CPU suite). Jitted, so that a program that calls it once a layer
+    and token-step traces and lowers it once."""
+    s_all, z_all = state
+    b, nh, hd = q.shape
+    n_kv = k.shape[1]
+    grp = nh // n_kv
+    nf, nfz = hd // 2 + 1, z_all.shape[2]
+    if s_all.dtype != jnp.float32 or z_all.dtype != jnp.float32:
+        raise ValueError("retention_decode keeps its state in float32, "
+                         f"got {s_all.dtype}")
+    if grp + 3 > 8:
+        raise ValueError(
+            f"{grp} query heads a key-value head: the step's vectors "
+            "(queries, k, v, decay) ride in one 8-sublane tile")
+    et = min(_RETENTION_ROWS, hd)
+    f32 = jnp.float32
+    x = jnp.concatenate([
+        q.astype(f32).reshape(b, n_kv, grp, hd),
+        k.astype(f32)[:, :, None], v.astype(f32)[:, :, None],
+        jnp.broadcast_to(jnp.exp(gate.astype(f32))[..., None, None],
+                         (b, n_kv, 1, hd)),
+        jnp.zeros((b, n_kv, 8 - grp - 3, hd), f32)], axis=2)
+
+    def lane(*tail):
+        return lambda i, j, rows: (i, j) + tail
+
+    def row(*tail):
+        return lambda i, j, rows: (rows[i], j) + tail
+
+    kern = functools.partial(_retention_decode_kernel, grp=grp, hd=hd,
+                             et=et)
+    y, s_all, z_all = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_kv),
+            in_specs=[pl.BlockSpec((1, 1, 8, hd), lane(0, 0)),
+                      pl.BlockSpec((1, 1, nf, hd, hd), row(0, 0, 0)),
+                      pl.BlockSpec((1, 1, nfz, hd), row(0, 0))],
+            out_specs=[pl.BlockSpec((1, 1, 8, hd), lane(0, 0)),
+                       pl.BlockSpec((1, 1, nf, hd, hd), row(0, 0, 0)),
+                       pl.BlockSpec((1, 1, nfz, hd), row(0, 0))],
+            scratch_shapes=[pltpu.VMEM((nfz * 8, hd), f32),
+                            pltpu.VMEM((grp, hd, hd), f32)]),
+        out_shape=[jax.ShapeDtypeStruct((b, n_kv, 8, hd), f32),
+                   jax.ShapeDtypeStruct(s_all.shape, f32),
+                   jax.ShapeDtypeStruct(z_all.shape, f32)],
+        # operands count the prefetched rows: S is 2, z is 3
+        input_output_aliases={2: 1, 3: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # a plane set is 4.3 MB at hd 128, in and out, each
+            # double-buffered
+            vmem_limit_bytes=48 * 1024 * 1024),
+        interpret=interpret,
+        name="retention_decode",
+    )(rows.astype(jnp.int32), x, s_all, z_all)
+    ctx = y[:, :, :grp].reshape(b, nh, hd).astype(q.dtype)
+    return ctx, (s_all, z_all)

@@ -12,6 +12,9 @@ beat dynamic shapes):
                pages per layer + host block tables; eviction = a host
                list splice; page refcounts + a radix prefix index give
                copy-on-write prompt sharing (prefix_sharing=True)
+  state_cache  for a decoder of retention layers: one fixed-size f32
+               state ROW a request per layer in place of pages and
+               tables; row 0 is the scratch row of dead lanes
   programs     THREE compiled programs (bucketed prefill, paged decode
                step, and the mid-stream chunk forward that serves both
                speculative verify and shared-prefix suffix prefill)
@@ -51,10 +54,11 @@ from .engine import ServingConfig, ServingEngine, \
 from .fleet import (FleetConfig, FleetRequest, PRIORITY_CLASSES,
                     Replica, ServingFleet, ServingSLO)
 from .paged_cache import PagedKVCache
+from .state_cache import StateCache
 from .scheduler import BucketLadder, FifoScheduler, Request
 from . import loadgen
 
-__all__ = ["ServingConfig", "ServingEngine", "PagedKVCache",
+__all__ = ["ServingConfig", "ServingEngine", "PagedKVCache", "StateCache",
            "BucketLadder", "FifoScheduler", "Request", "loadgen",
            "ServingFleet", "ServingSLO", "FleetConfig", "FleetRequest",
            "Replica", "PRIORITY_CLASSES", "build_serving_snapshot"]

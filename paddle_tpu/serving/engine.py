@@ -1,11 +1,13 @@
-"""ServingEngine: continuous-batching GPT serving over the paged cache.
+"""ServingEngine: continuous-batching serving of a decoder over its cache.
 
 Ties the pieces together: a weight snapshot (bf16 serving cast by
 default — decode is HBM-bound on weight reads, PERF_PLAN lever #5; f32
 parity mode is pinned bit-for-bit against generation.py greedy), the
-page pools + host block tables (paged_cache), the FIFO
-continuous-batching scheduler, and the per-engine compiled programs
-(programs.py). One ``step()`` is one token boundary:
+cache the decoder's mixer asks for — page pools + host block tables
+(paged_cache) for softmax attention, state rows (state_cache) for
+retention layers — the FIFO continuous-batching scheduler, and the
+per-engine compiled programs (programs.py). One ``step()`` is one
+token boundary:
 
   retire finished -> admit queued (one bucketed prefill for the whole
   mixed-length admit batch) -> one decode step for every active slot
@@ -68,6 +70,7 @@ from ..observability import metrics as _obs
 from ..observability import reqtrace as _rt
 from ..observability.sentinel import RecompileSentinel
 from .paged_cache import PagedKVCache
+from .state_cache import StateCache
 from .programs import (jit_tp_with_donated_pools,
                        jit_with_donated_pools, make_chunk_fn,
                        make_decode_fn, make_prefill_fn)
@@ -250,8 +253,39 @@ class ServingConfig:
         return -(-self.max_total_tokens // self.block_size)
 
 
+def _refuse_for_retention(cfg):
+    """What the engine cannot do for a decoder whose cache is a state
+    row a request, each with its reason."""
+    if cfg.prefix_sharing:
+        raise ValueError(
+            "prefix_sharing is not supported for a retention decoder: "
+            "a recurrent state cannot be cut at a shared prefix; "
+            "sharing needs snapshots of the state at page boundaries, "
+            "which nothing takes yet. Drop prefix_sharing.")
+    if cfg.speculative_k:
+        raise ValueError(
+            "speculative_k is not supported for a retention decoder: "
+            "a rejected proposal cannot be taken out of a state again "
+            "(verification needs a state snapshot to fall back to), "
+            "and the draft would need state rows of its own. Drop "
+            "speculative_k.")
+    if cfg.tp > 1:
+        raise ValueError(
+            "a tp plan is not supported for a retention decoder: the "
+            "state rows and the decode kernel are not sharded over "
+            "key-value heads yet. Drop the plan.")
+    if cfg.quant is not None:
+        raise ValueError(
+            "quant is not supported for a retention decoder: the int8 "
+            "snapshot knows the fused-qkv block's matmuls only. Drop "
+            "quant.")
+
+
 class ServingEngine:
-    """Continuous-batching serving over one GPTForCausalLM.
+    """Continuous-batching serving over one decoder LM: a
+    GPTForCausalLM, or a model that describes its own block
+    (``config.decoder_spec()``, ``decoder_params()``:
+    models/retention.py).
 
     ``draft_model`` (required iff ``config.speculative_k >= 1``): the
     small proposer — any GPTForCausalLM over the same vocab; its own
@@ -261,7 +295,12 @@ class ServingEngine:
                  draft_model=None):
         import jax
         self.config = cfg = config or ServingConfig()
-        mcfg = model.gpt.config
+        own = hasattr(model, "decoder_params")
+        mcfg = model.config if own else model.gpt.config
+        retention = DecoderSpec.of(mcfg).mixer == "retention"
+        if retention:
+            _refuse_for_retention(cfg)
+        spec = serving_decoder_spec(mcfg, cfg)
         if cfg.max_total_tokens > mcfg.max_seq_len:
             raise ValueError(
                 f"max_total_tokens={cfg.max_total_tokens} exceeds the "
@@ -282,8 +321,9 @@ class ServingEngine:
         # build; new weights land only through swap_weights() at a
         # token boundary (same treedef/avals — the ladder never
         # recompiles)
-        self.params = build_serving_snapshot(_gpt_params(model), cfg,
-                                             n_heads=self.n_heads)
+        self.params = build_serving_snapshot(
+            model.decoder_params() if own else _gpt_params(model), cfg,
+            n_heads=self.n_heads)
         self.vocab_size = int(mcfg.vocab_size)
         pool_dtype = cfg.dtype or "float32"
         pool_sharding = None
@@ -298,12 +338,20 @@ class ServingEngine:
                 jit_tp_with_donated_pools, mesh=cfg.plan.mesh,
                 params_specs=serving_param_specs(self.params),
                 n_plain=3, n_out=2)
-        self.cache = PagedKVCache(
-            n_layers=int(mcfg.num_layers), n_blocks=cfg.n_blocks,
-            block_size=cfg.block_size, n_heads=self.n_heads,
-            head_dim=int(mcfg.hidden_size) // self.n_heads,
-            dtype=pool_dtype, prefix_sharing=cfg.prefix_sharing,
-            pool_sharding=pool_sharding, tp=self.tp)
+        if retention:
+            # the cache kind is the mixer's: a state row a request
+            # (n_blocks counts the rows, scratch included), in f32
+            # whatever the weights' dtype: it sums over a whole request
+            self.cache = StateCache(
+                n_layers=int(mcfg.num_layers), n_rows=cfg.n_blocks,
+                n_kv_heads=spec.n_kv_heads, head_dim=spec.head_dim)
+        else:
+            self.cache = PagedKVCache(
+                n_layers=int(mcfg.num_layers), n_blocks=cfg.n_blocks,
+                block_size=cfg.block_size, n_heads=self.n_heads,
+                head_dim=int(mcfg.hidden_size) // self.n_heads,
+                dtype=pool_dtype, prefix_sharing=cfg.prefix_sharing,
+                pool_sharding=pool_sharding, tp=self.tp)
         self.ladder = BucketLadder(cfg.prefill_buckets,
                                    cfg.decode_buckets, cfg.block_size)
         self.sched = FifoScheduler(cfg.max_slots, cfg.max_admit)
@@ -318,7 +366,6 @@ class ServingEngine:
                     jit(make_decode_fn(spec, cfg.block_size, sampling,
                                        n_steps)))
 
-        spec = serving_decoder_spec(mcfg, cfg)
         self._prefill, self._decode = programs(
             spec, sampling, int(cfg.decode_chunk))
         # the chunk program serves BOTH new levers (speculative verify
@@ -455,6 +502,11 @@ class ServingEngine:
         W = cfg.table_width
         a = self.sched.max_admit
         key = jax.random.key(0)
+
+        def dummy(n):
+            # lanes without a request: the cache's scratch addressing
+            return self.cache.table_array([None] * n, W)
+
         # prime the per-boundary key derivation as well: the first
         # step()'s fold_in chain otherwise traces+compiles mid-traffic
         # — ~100 ms the request traces pin on the first admit batch
@@ -465,7 +517,7 @@ class ServingEngine:
             # to scratch instead of page-scattered); plus the COW copy
             for s in self.ladder.prefill:
                 self.cache.pools, _, _ = self._chunk(
-                    self.cache.pools, np.zeros((a, W), np.int32),
+                    self.cache.pools, dummy(a),
                     np.zeros((a, s), np.int32),
                     np.zeros((a,), np.int32), np.ones((a,), np.int32),
                     self.params, key)
@@ -473,7 +525,7 @@ class ServingEngine:
         else:
             for s in self.ladder.prefill:
                 self.cache.pools, _ = self._prefill(
-                    self.cache.pools, np.zeros((a, W), np.int32),
+                    self.cache.pools, dummy(a),
                     np.zeros((a, s), np.int32),
                     np.ones((a,), np.int32), self.params, key)
         if self._spec_k:
@@ -482,7 +534,7 @@ class ServingEngine:
             # chunk verify, per decode bucket
             for b in self.ladder.decode:
                 self.cache.pools, _, _ = self._chunk(
-                    self.cache.pools, np.zeros((b, W), np.int32),
+                    self.cache.pools, dummy(b),
                     np.zeros((b, self._spec_k + 1), np.int32),
                     np.zeros((b,), np.int32), np.ones((b,), np.int32),
                     self.params, key)
@@ -499,7 +551,7 @@ class ServingEngine:
         else:
             for b in self.ladder.decode:
                 self.cache.pools, _ = self._decode(
-                    self.cache.pools, np.zeros((b, W), np.int32),
+                    self.cache.pools, dummy(b),
                     np.zeros((b,), np.int32), np.zeros((b,), np.int32),
                     self.params, key)
         self.sentinel.observe(self.executable_count(),
@@ -587,7 +639,7 @@ class ServingEngine:
                         self.cache.n_shared)
         finally:
             # also when a dispatch raised: no annotation stays entered
-            tr.close(n_exec)
+            tr.close(n_exec, **self.cache.span_counts())
         return finished
 
     def _dispatch(self, tr, kind, bucket, width, fn, cache, args,

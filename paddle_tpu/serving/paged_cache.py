@@ -440,6 +440,11 @@ class PagedKVCache:
     def copy_executables(self) -> int:
         return 0 if self._copy is None else int(self._copy._cache_size())
 
+    def span_counts(self) -> dict:
+        """What the engine writes of this cache on its ``step`` span:
+        nothing yet (no reader asks for the pages' counts there)."""
+        return {}
+
     def warm_copy(self):
         """Compile the COW page-copy program up front (scratch ->
         scratch is a junk-safe no-op write) so a first real copy never

@@ -1,5 +1,6 @@
 """The serving engine's three compiled programs: bucketed prefill, the
-paged decode step and the paged chunk.
+decode step and the paged chunk, for whichever decoder the engine
+serves.
 
 TVM's lesson (PAPERS.md) dictates the TPU shape: a SMALL, FIXED set of
 pre-compiled executables over static shapes, never a recompile per
@@ -49,6 +50,20 @@ programs inside a ``shard_map`` over the 'tp' axis. Nothing here knows:
 the `DecoderSpec` the engine hands the makers carries the local head
 count, the heads-major qkv layout and the all-reduce
 (engine.py::serving_decoder_spec).
+
+A decoder whose mixer is `retention` (models/decoder.py) has no page
+and no table: its cache is a state ROW a request (state_cache.py), and
+the same two makers build its prefill and its decode step around the
+retention addressings below. What is `tables [B, W]` above is then
+`rows [B]`, one state row a lane, row 0 the scratch row of padded and
+dead lanes. The prefill attends through the quadratic form over the
+bucket and writes each row's state as of its LAST TRUE token: junk
+rows past a prompt's length, which a paged cache lets the decode step
+overwrite, could never be taken out of a state again, so they never
+enter one. On a TPU the decode step is
+`ops/pallas_kernels.retention_decode` (the state read once and written
+once, in place), elsewhere `decoder.retention_step`, which
+tests/test_retention_serving.py holds it to.
 """
 from __future__ import annotations
 
@@ -167,6 +182,43 @@ def _chunk_addressing(spec, block_size, tables, positions, valid):
     return attend
 
 
+def _retention_decode_addressing(rows):
+    """One token a lane through its state row `rows[i]`: scaled by the
+    token's decay, given phi(k) v^T, written back and read by the
+    lane's queries — on a TPU in one pass of the kernel, elsewhere in
+    jax.numpy."""
+    from ..ops import pallas_kernels as _pk
+    step = _pk.retention_decode if _pk.pallas_available() \
+        else decoder.retention_step
+
+    def attend(state, q, k, v, gate):
+        ctx, state = step(state, rows, q[:, 0], k[:, 0], v[:, 0],
+                          gate[:, 0])
+        return ctx[:, None], state
+
+    return attend
+
+
+def _retention_prefill_addressing(rows, prompt_lens):
+    """A whole prompt a row, right-padded: the queries attend through
+    the quadratic form (cheaper than the features under a few thousand
+    tokens), and row `rows[i]` of the state is WRITTEN, whole, with
+    what prompt i leaves behind as of its last true token — which is
+    also the zeroing of a row that an earlier request held."""
+
+    def attend(state, q, k, v, gate):
+        ctx = decoder.retained_attention(q, k, v, gate, prompt_lens)
+        new = decoder.retention_state(k, v, gate, prompt_lens)
+        for i in range(rows.shape[0]):
+            state = tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    pool, n[i:i + 1].astype(pool.dtype), rows[i], axis=0)
+                for pool, n in zip(state, new))
+        return ctx, state
+
+    return attend
+
+
 def make_decode_fn(spec, block_size: int, sampling, n_steps: int = 1):
     """``n_steps`` token boundaries for every running slot, fused into
     one dispatch (lax.scan over the single-token step).
@@ -175,7 +227,8 @@ def make_decode_fn(spec, block_size: int, sampling, n_steps: int = 1):
         -> (pools', toks [n_steps, B])
 
     toks [B] is each slot's last emitted token, positions [B] the
-    logical index where its K/V land (== tokens held so far).
+    logical index where its K/V land (== tokens held so far); for a
+    retention decoder `tables` is `rows [B]`, a state row a slot.
     `sampling` is (temperature, top_k, top_p). The step is
     generation.py's ragged decode step under `_decode_addressing`.
 
@@ -192,9 +245,13 @@ def make_decode_fn(spec, block_size: int, sampling, n_steps: int = 1):
     def step(pools, tables, toks, positions, params, key):
         with _scope("embed"):
             x = decoder.embed(params, toks, positions)[:, None]
-        x, pools = decoder.blocks(
-            spec, params, x, pools,
-            _decode_addressing(spec, block_size, tables, positions))
+        if spec.mixer == "retention":
+            attend = _retention_decode_addressing(tables)
+        else:
+            attend = _decode_addressing(spec, block_size, tables,
+                                        positions)
+        x, pools = decoder.blocks(spec, params, x, pools, attend,
+                                  positions[:, None])
         with _scope("lm_head"):
             tok = _pick(decoder.final_logits(spec, params, x)[:, 0], key,
                         *sampling)
@@ -221,7 +278,8 @@ def make_prefill_fn(spec, block_size: int, sampling):
     run(pools, tables, ids, prompt_lens, params, key) -> (pools', tok)
 
     ids [A, S] is right-padded to the bucket width S (a multiple of
-    block_size: BucketLadder refuses any other); a true token attends
+    block_size: BucketLadder refuses any other; for a retention
+    decoder `tables` is `rows [A]`); a true token attends
     causally, so never past its row's prompt_lens [A], and each row's
     hidden state at its own last true token is exactly what the dense
     ragged path computes.
@@ -233,9 +291,13 @@ def make_prefill_fn(spec, block_size: int, sampling):
         s = ids.shape[1]
         with _scope("embed"):
             x = decoder.embed(params, ids, jnp.arange(s))
-        x, pools = decoder.blocks(
-            spec, params, x, pools, _prefill_addressing(
-                spec, block_size, tables, s, prompt_lens))
+        if spec.mixer == "retention":
+            attend = _retention_prefill_addressing(tables, prompt_lens)
+        else:
+            attend = _prefill_addressing(spec, block_size, tables, s,
+                                         prompt_lens)
+        x, pools = decoder.blocks(spec, params, x, pools, attend,
+                                  jnp.arange(s))
         with _scope("lm_head"):
             idx = (prompt_lens - 1).astype(jnp.int32)
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)

@@ -538,3 +538,134 @@ def test_gelu_is_not_expanded_in_fc2s_operand(program, monkeypatch, capsys):
                 if op == "fusion" and cycles and callee.group(1) in fc2 | fc1:
                     which = "fc2" if callee.group(1) in fc2 else "fc1"
                     print(f"  {which} {name}: {cycles.group(1)}")
+
+
+# ---------------------------------------------------- retention decode step
+
+# the complete cell's engine shape (perfbench/configs/brumby-14b-base.json):
+# 33 state rows, 32 lanes, 40 query and 8 key-value heads of 128
+R_ROWS, R_HEADS, R_KV, R_HEAD = 33, 40, 8, 128
+R_FEATS = R_HEAD // 2 + 1
+
+
+def _state_avals(one, rows=R_ROWS, kv=R_KV, hd=R_HEAD):
+    feats = hd // 2 + 1
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one)
+    return (f32(rows, kv, feats, hd, hd),
+            f32(rows, kv, -(-feats // 8) * 8, hd))
+
+
+def _state_copies(text, rows=R_ROWS):
+    """`copy` or layout-changing `copy-start` of a whole state pool,
+    S [rows, 8, 65, 128, 128] or z [rows, 8, 72, 128]."""
+    shape = rf"f32\[{rows},{R_KV},(?:{R_FEATS},{R_HEAD},{R_HEAD}|\d+,{R_HEAD})\]"
+    found = [ln.strip() for ln in text.splitlines()
+             if re.search(rf"= {shape}\{{[^}}]*\}} copy\(", ln)]
+    moved = re.compile(rf"= \({shape}\{{([^}}]*)\}}, {shape}\{{([^}}]*)\}}, "
+                       r".*copy-start\(")
+    space = re.compile(r"S\(\d+\)")
+    for ln in text.splitlines():
+        m = moved.search(ln)
+        if m and space.sub("", m.group(1)) != space.sub("", m.group(2)):
+            found.append(ln.strip())
+    return found
+
+
+@pytest.mark.parametrize("heads,kv,hd", [(R_HEADS, R_KV, R_HEAD),
+                                         (8, 8, R_HEAD), (16, 4, 64)],
+                         ids=["grp5_hd128", "grp1_hd128", "grp4_hd64"])
+def test_retention_decode_kernel_compiles_for_one_v5e_chip(heads, kv, hd):
+    """A lane's state of one key-value head is [65, 128, 128] f32 in
+    whole tiles, z [72, 128]; one Mosaic call under its own name, the
+    state operands aliased to the outputs (donated: written in place),
+    and no temporary of the state's size."""
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    state = _state_avals(one, R_ROWS, kv, hd)
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+    def step(S, z, rows, q, k, v, gate):
+        return pk.retention_decode((S, z), rows, q, k, v, gate)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).trace(
+        *state, s((SLOTS,), jnp.int32),
+        s((SLOTS, heads, hd), jnp.bfloat16), s((SLOTS, kv, hd), jnp.bfloat16),
+        s((SLOTS, kv, hd), jnp.bfloat16), s((SLOTS, kv), jnp.float32)
+    ).lower(lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "retention_decode" in text
+    mem = compiled.memory_analysis()
+    state_bytes = sum(np.prod(a.shape) * 4 for a in state)
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < 4e6
+
+
+def _retention_avals(n_layers, one):
+    """The decode and prefill programs' arguments at the published
+    widths as shapes (pools, params, key)."""
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    h, ffn, vocab = 5120, 17408, 151936
+    block = {"ln1_w": s((h,)), "q_w": s((h, R_HEADS * R_HEAD)),
+             "k_w": s((h, R_KV * R_HEAD)), "v_w": s((h, R_KV * R_HEAD)),
+             "proj_w": s((R_HEADS * R_HEAD, h)), "g_w": s((h, R_KV)),
+             "g_b": s((R_KV,)), "qn_w": s((R_HEAD,)), "kn_w": s((R_HEAD,)),
+             "ln2_w": s((h,)), "gate_w": s((h, ffn)), "up_w": s((h, ffn)),
+             "down_w": s((ffn, h))}
+    params = {"wte": s((vocab, h)), "lnf_w": s((h,)),
+              "head_w": s((h, vocab)), "blocks": [block] * n_layers}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return ((_state_avals(one),) * n_layers, params,
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one))
+
+
+def _retention_spec():
+    from paddle_tpu.models import RetentionConfig
+    return RetentionConfig().decoder_spec()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_128"])
+def test_retention_programs_keep_their_state_in_place(program, monkeypatch,
+                                                      capsys):
+    """The decode program (32 lanes x 4 token-steps) and a prefill
+    (4 x 128) of the retention decoder at the published widths (4
+    layers cut to 2 for time), the kernel chosen as on a TPU: one
+    Mosaic call a layer in the decode program and none in the prefill
+    (its attention is the quadratic form in XLA); the donated state
+    rows are neither copied nor converted (z's rows are padded to 72
+    for that: with 65 the device lays [33, 8, 65, 128] out with the 8
+    heads second-minor and every program converted it at entry and
+    exit); temporaries printed and held under 400 MB."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_decode_fn,
+                                             make_prefill_fn)
+    n_layers = 2
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    pools, params, key = _retention_avals(n_layers, one)
+    s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one)
+    spec = _retention_spec()
+    with _engine_precision():
+        if program == "decode":
+            fn = jit_with_donated_pools(make_decode_fn(
+                spec, PAGE, _GREEDY, n_steps=4))
+            compiled = fn.trace(pools, s32(SLOTS), s32(SLOTS), s32(SLOTS),
+                                params, key).lower(
+                                    lowering_platforms=("tpu",)).compile()
+        else:
+            fn = jit_with_donated_pools(make_prefill_fn(spec, PAGE,
+                                                        _GREEDY))
+            compiled = fn.trace(pools, s32(4), s32(4, 128), s32(4), params,
+                                key).lower(
+                                    lowering_platforms=("tpu",)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == \
+        (n_layers if program == "decode" else 0)
+    assert not _state_copies(text)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nretention {program}, {n_layers} layers: temporaries "
+              f"{mem.temp_size_in_bytes / 1e6:.1f} MB, arguments "
+              f"{mem.argument_size_in_bytes / 1e9:.2f} GB")
+    assert mem.temp_size_in_bytes < 400e6
