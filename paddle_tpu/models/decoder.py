@@ -41,7 +41,9 @@ from ..observability.anatomy import scope as _scope
 __all__ = ["DecoderSpec", "block", "blocks", "embed", "final_logits",
            "masked_attention", "prefix_mask", "causal_mask",
            "retention_features", "retained_attention", "retention_state",
-           "retention_step"]
+           "retention_step", "retention_chunk_push",
+           "retention_chunk_weights", "retention_pass",
+           "retention_chunk_step"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -445,3 +447,89 @@ def retention_step(state, rows, q, k, v, gate):
                      z_new[..., :fk.shape[-2], :].astype(jnp.float32))
     ctx = (num / den[..., None]).reshape(q.shape).astype(q.dtype)
     return ctx, (s_all.at[rows].set(s_new), z_all.at[rows].set(z_new))
+
+
+# -- the decode chunk: rows read every token-step, written once ---------------
+#
+# Over the token-steps t = 0 .. n-1 of a chunk, with S_0 the row as the
+# chunk found it, G_t = prod_{i<=t} g_i and w_{t,i} = prod_{i<j<=t} g_j:
+#   phi(q_t)^T S_t = G_t phi(q_t)^T S_0 + sum_{i<=t} w_{t,i} (q_t.k_i)^2 v_i
+# and phi(q_t).z_t alike. So a token-step READS its row (`num0`, `den0`)
+# and adds the chunk's own keys outside it; only the last token-step
+# WRITES, S_n = G_n S_0 + sum_i w_{n,i} v_i phi(k_i)^T (z alike). The
+# chunk's k, v and log decays ride in buffers [B, n_kv, C, hd] (gates
+# [B, n_kv, C]), one entry a token-step, in f32.
+
+def retention_chunk_push(chunk, t, k, v, gate):
+    """The chunk's buffers (keys, vals, gates) with token-step t's k, v
+    [B, n_kv, hd] and gate [B, n_kv] at index t."""
+    keys, vals, gates = chunk
+    f32 = jnp.float32
+    return (keys.at[:, :, t].set(k.astype(f32)),
+            vals.at[:, :, t].set(v.astype(f32)),
+            gates.at[:, :, t].set(gate.astype(f32)))
+
+
+def retention_chunk_weights(gates, t):
+    """At token-step t of a chunk whose log decays are gates
+    [B, n_kv, C] (entries past t ignored): (G_t [B, n_kv], the decay
+    of the row as the chunk found it; w [B, n_kv, C], w_{t,i}, 0 past
+    t)."""
+    live = jnp.arange(gates.shape[-1]) <= t
+    gam = jnp.cumsum(jnp.where(live, gates, 0.0), axis=-1)
+    total = gam[..., -1:]
+    return jnp.exp(total[..., 0]), _decay(total, gam, live)
+
+
+def retention_pass(state, rows, q, keys, vals, decay, weights, write):
+    """One pass over each lane's state row, in jax.numpy (the form
+    `ops/pallas_kernels.retention_decode` is held to). Reads the row
+    `rows[i]` as it stands: num0 = phi(q)^T S_0 [B, n_kv, group, hd]
+    and den0 = phi(q).z_0 [B, n_kv, group], f32. Where `write` (a bool,
+    traced or not), the row becomes decay S_0 + sum_i weights_i vals_i
+    phi(keys_i)^T, z alike (keys, vals [B, n_kv, C, hd], decay
+    [B, n_kv], weights [B, n_kv, C]); otherwise nothing is written.
+    Returns (num0, den0, state')."""
+    s_all, z_all = state
+    n_kv = keys.shape[1]
+    fq = retention_features(_grouped(q, n_kv))              # [B,j,g,F,hd]
+    nf = fq.shape[-2]
+    s0 = s_all[rows].astype(jnp.float32)
+    z0 = z_all[rows].astype(jnp.float32)
+    num0 = jnp.einsum("bjgda,bjdea->bjge", fq, s0)
+    den0 = jnp.einsum("bjgda,bjda->bjg", fq, z0[..., :nf, :])
+
+    def flush(state):
+        s_all, z_all = state
+        fk = retention_features(keys)                       # [B,j,C,F,hd]
+        wv = vals * weights[..., None]
+        s_new = (decay[..., None, None, None] * s0
+                 + jnp.einsum("bjce,bjcda->bjdea", wv, fk))
+        z_new = (decay[..., None, None] * z0
+                 + _z_rows(jnp.einsum("bjc,bjcda->bjda", weights, fk)))
+        return (s_all.at[rows].set(s_new.astype(s_all.dtype)),
+                z_all.at[rows].set(z_new.astype(z_all.dtype)))
+
+    return num0, den0, jax.lax.cond(write, flush, lambda st: st, state)
+
+
+def retention_chunk_step(state, rows, q, chunk, t, write,
+                         one_pass=retention_pass):
+    """Token-step t of a decode chunk whose buffers `chunk` already
+    hold this token-step's k, v and gate (`retention_chunk_push`):
+    `one_pass` (`retention_pass`, or the kernel) reads each lane's row
+    and, where `write`, writes its state as of this token-step; the
+    chunk's own tokens are added here, (q.k_i)^2 over at most C keys,
+    all in f32. Equal to t + 1 `retention_step`s from the row as the
+    chunk found it. Returns (ctx [B, N, hd] in q's dtype, state')."""
+    keys, vals, gates = chunk
+    decay, w = retention_chunk_weights(gates, t)
+    num0, den0, state = one_pass(state, rows, q, keys, vals, decay, w,
+                                 write)
+    qg = _grouped(q, keys.shape[1]).astype(jnp.float32)     # [B,j,g,hd]
+    sc = jnp.sum(qg[:, :, :, None, :] * keys[:, :, None], axis=-1)
+    a = sc * sc * w[:, :, None, :]                          # [B,j,g,C]
+    num = (decay[..., None, None] * num0
+           + jnp.sum(a[..., None] * vals[:, :, None], axis=3))
+    den = decay[..., None] * den0 + jnp.sum(a, axis=-1)
+    return (num / den[..., None]).reshape(q.shape).astype(q.dtype), state

@@ -40,7 +40,8 @@ Engine steps are on the same ring (``open_step`` and the handle it
 returns, the ONE writer): every ``ServingEngine.step()`` that has work
 is one ``step`` span (rid None; step, replica, ``executables``, the
 engine's compile count at that boundary, and ``state_rows_live`` where
-the engine's cache is state rows) over contiguous phase
+the engine's cache is state rows, with ``state_row_writes`` on a step
+that decodes them) over contiguous phase
 spans (``STEP_PHASES``; step, replica, parent="step", and the
 program's ``kind`` on a dispatch's phases). The same call that writes
 a phase to the ring enters ``jax.profiler.TraceAnnotation(
@@ -252,7 +253,8 @@ class _Step:
         """End the step: its last phase, then the ``step`` span with
         the engine's compile count at this boundary and what else the
         engine counts there (``state_rows_live`` where its cache is
-        state rows)."""
+        state rows, and ``state_row_writes`` where a step decodes
+        them)."""
         now = self._close_phase(None)
         self._whole.__exit__(None, None, None)
         _tracer.record_span(None, "step", self.t0, now, step=self.step,

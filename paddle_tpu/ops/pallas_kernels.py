@@ -890,54 +890,59 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
 # lanes are 4 registers of state and 4 of each query head's running
 # sum, which with 5 query heads stay in registers over the whole loop
 _RETENTION_ROWS = 32
+# token-steps one write of a row can cover: the chunk's keys, their
+# weighted values and their weights ride one 8-sublane tile each
+RETENTION_CHUNK = 8
 
 
-def _retention_decode_kernel(rows_ref, x_ref, s_ref, z_ref, y_ref, so_ref,
-                             zo_ref, p_ref, a_ref, *, grp, hd, et):
+def _retention_decode_kernel(rows_ref, write_ref, x_ref, s_ref, z_ref,
+                             y_ref, so_ref, zo_ref, p_ref, a_ref, vt_ref,
+                             *, grp, hd, et, n_keys):
     """One grid step is one lane's state of one key-value head:
     S [F, hd, hd] (`S[d, e, a]`: feature offset d, value e in the
     sublanes, feature position a in the lanes) and z [F rounded up to
-    8, hd] (the rows past F zero), read once and written once, in
-    place.
+    8, hd] (the rows past F zero), read once, and written only where
+    the call writes (`write_ref`).
 
-    `x` [8, hd] holds the step's vectors one a sublane: the `grp` query
-    heads of this key-value head, then k, v, and the decay e^gate in
-    every lane. Row d of the features of all of them at once is
-    `c_d x roll(x, d)` (models/decoder.py, "the retention mixer"): one
-    register, kept in `p` (row 8 d + i is vector i's; the rows of z's
-    padding are zero). The state's plane d becomes
-    `decay S[d] + v (x) phi(k)[d]` (v down the sublanes, phi(k)[d]
-    along the lanes), is stored, and is multiplied into each query
-    head's running sum `acc_i[e, a] += S'[d, e, a] phi(q_i)[d, a]`
-    while it is in registers; the sums over d stay elementwise, the sum
-    over a is taken once at the end (a transpose and a sublane sum, so
-    that e comes out in the lanes). z alike, whole."""
+    `x` [40, hd] holds the step's vectors one a sublane, in tiles of 8
+    rows: the `grp` query heads of this key-value head; the chunk's
+    keys k_i; its weighted values w_i v_i; each weight w_i in every
+    lane; the decay G of the row in every lane (decoder.py, "the decode
+    chunk").
+
+    The read: each query head's running sum `acc_i[e, a] += S[d, e, a]
+    phi(q_i)[d, a]` over the planes d while a plane is in registers;
+    the sum over a is taken once at the end (a transpose and a sublane
+    sum, so that e comes out in the lanes). Row i < grp of `y` is
+    phi(q_i)^T S, lane i of its row 7 phi(q_i).z.
+
+    The write: plane d of `so` becomes `G S[d] + sum_i (w_i v_i) (x)
+    phi(k_i)[d]` (values down the sublanes, phi(k_i)[d] along the
+    lanes), z alike, whole. A call that does not write leaves `so`
+    alone, and its index map holds it on one block of the scratch row
+    for the whole call, so the pipeline stores nothing but that block,
+    once, at the end; z is given back as it was found."""
     del rows_ref                                # the index maps read it
     nf, nfz = hd // 2 + 1, z_ref.shape[2]
-    x8 = x_ref[0, 0]                                           # [8, hd]
+    x = x_ref[0, 0]                                            # [40, hd]
+    # row d of the features of the queries and keys at once is
+    # `c_d x roll(x, d)` (models/decoder.py, "the retention mixer"): two
+    # registers, kept in `p` (row 16 d + i is query i's, 16 d + 8 + i key
+    # i's; the rows of z's padding are zero)
+    qk = x[0:16]
     for d in range(nf):
         c = 1.0 if d in (0, hd // 2) else math.sqrt(2.0)
-        p_ref[pl.ds(8 * d, 8), :] = x8 * pltpu.roll(x8, d, 1) * c
-    p_ref[pl.ds(8 * nf, 8 * (nfz - nf)), :] = jnp.zeros(
-        (8 * (nfz - nf), hd), jnp.float32)
-    decay = x8[grp + 2:grp + 3, :]                             # [1, hd]
-    fk = p_ref[pl.ds(grp, nfz, stride=8), :]                   # [Fz, hd]
-    z_new = decay * z_ref[0, 0] + fk
-    zo_ref[0, 0] = z_new
-    den = [jnp.sum(p_ref[pl.ds(i, nfz, stride=8), :] * z_new,
+        p_ref[pl.ds(16 * d, 16), :] = qk * pltpu.roll(qk, d, 1) * c
+    p_ref[pl.ds(16 * nf, 16 * (nfz - nf)), :] = jnp.zeros(
+        (16 * (nfz - nf), hd), jnp.float32)
+    z0 = z_ref[0, 0]
+    den = [jnp.sum(p_ref[pl.ds(i, nfz, stride=16), :] * z0,
                    keepdims=True) for i in range(grp)]         # [1, 1]
-    # v down the sublanes, the same in every lane
-    v_col = jnp.broadcast_to(x8[grp + 1:grp + 2, :], (hd, hd)).T
-    decay_t = jnp.broadcast_to(decay, (et, hd))
     for e0 in range(0, hd, et):
-        v_t = v_col[e0:e0 + et, :]
-
-        def plane(d, accs, e0=e0, v_t=v_t):
-            p = p_ref[pl.ds(pl.multiple_of(d * 8, 8), 8), :]
-            m = decay_t * s_ref[0, 0, d, e0:e0 + et, :] \
-                + v_t * p[grp:grp + 1, :]
-            so_ref[0, 0, d, e0:e0 + et, :] = m
-            return tuple(acc + m * p[i:i + 1, :]
+        def plane(d, accs, e0=e0):
+            p = p_ref[pl.ds(pl.multiple_of(d * 16, 16), 8), :]
+            s = s_ref[0, 0, d, e0:e0 + et, :]
+            return tuple(acc + s * p[i:i + 1, :]
                          for i, acc in enumerate(accs))
 
         accs = jax.lax.fori_loop(
@@ -945,76 +950,125 @@ def _retention_decode_kernel(rows_ref, x_ref, s_ref, z_ref, y_ref, so_ref,
             tuple(jnp.zeros((et, hd), jnp.float32) for _ in range(grp)))
         for i, acc in enumerate(accs):
             a_ref[i, e0:e0 + et, :] = acc
-    out = [jnp.sum(a_ref[i].T, axis=0, keepdims=True) / den[i]
-           for i in range(grp)]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, hd), 1)
+    den_row = jnp.zeros((1, hd), jnp.float32)
+    for i in range(grp):
+        den_row = jnp.where(lanes == i, den[i], den_row)
     y_ref[0, 0] = jnp.concatenate(
-        out + [jnp.zeros((8 - grp, hd), jnp.float32)], axis=0)
+        [jnp.sum(a_ref[i].T, axis=0, keepdims=True) for i in range(grp)]
+        + [jnp.zeros((7 - grp, hd), jnp.float32), den_row], axis=0)
+    zo_ref[0, 0] = z0
+
+    @pl.when(write_ref[0] != 0)
+    def _write():
+        decay = x[32:33, :]                                    # [1, hd]
+        z_new = decay * z0
+        for i in range(n_keys):
+            z_new = z_new + x[24 + i:25 + i, :] \
+                * p_ref[pl.ds(8 + i, nfz, stride=16), :]
+        zo_ref[0, 0] = z_new
+        for i in range(n_keys):
+            # w_i v_i down the sublanes, the same in every lane
+            vt_ref[i] = jnp.broadcast_to(x[16 + i:17 + i, :], (hd, hd)).T
+        decay_t = jnp.broadcast_to(decay, (et, hd))
+        for e0 in range(0, hd, et):
+            vts = [vt_ref[i, e0:e0 + et, :] for i in range(n_keys)]
+
+            def plane_out(d, carry, e0=e0, vts=vts):
+                p = p_ref[pl.ds(pl.multiple_of(d * 16 + 8, 8), 8), :]
+                m = decay_t * s_ref[0, 0, d, e0:e0 + et, :]
+                for i, vt in enumerate(vts):
+                    m = m + vt * p[i:i + 1, :]
+                so_ref[0, 0, d, e0:e0 + et, :] = m
+                return carry
+
+            jax.lax.fori_loop(0, nf, plane_out, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def retention_decode(state, rows, q, k, v, gate, interpret=False):
-    """One token a lane through the retention state, read and written
-    once: `decoder.retention_step` as one Mosaic call.
+def retention_decode(state, rows, q, keys, vals, decay, weights, write,
+                     interpret=False):
+    """`decoder.retention_pass` as one Mosaic call: each lane's state
+    row read once, and written only where `write`.
 
     state = (S [R, n_kv, F, hd, hd], z [R, n_kv, F rounded up to 8,
-    hd]) in f32, F = hd/2 + 1; rows [B] int32, the state row of each lane (dead
-    lanes name the scratch row 0); q [B, N, hd], k and v [B, n_kv, hd],
-    gate [B, n_kv] (f32 log decays). Lane b's row is scaled by its
-    decay, gains phi(k) v^T, is written back in place (the state
-    operands are aliased to the outputs: donate them), and
-    phi(q)^T S / phi(q)^T z is accumulated for the N / n_kv query
-    heads of each key-value head from the planes in VMEM. All
-    arithmetic in f32. Returns (ctx [B, N, hd] in q's dtype, state').
-    `interpret=True` runs the kernel under the Pallas interpreter (the
-    CPU suite). Jitted, so that a program that calls it once a layer
-    and token-step traces and lowers it once."""
+    hd]) in f32, F = hd/2 + 1; rows [B] int32, the state row of each
+    lane (dead lanes name the scratch row 0); q [B, N, hd]; the chunk's
+    keys and vals [B, n_kv, C, hd], C <= RETENTION_CHUNK, with their
+    weights [B, n_kv, C] and the row's decay [B, n_kv] (f32:
+    `decoder.retention_chunk_weights`); `write` a bool, traced or not.
+    Returns (num0 = phi(q)^T S [B, n_kv, N / n_kv, hd], den0 = phi(q).z
+    [B, n_kv, N / n_kv], state'), all arithmetic in f32. Where `write`,
+    lane b's row becomes decay S + sum_i weights_i vals_i phi(keys_i)^T
+    (z alike), in place: the state operands are aliased to the outputs
+    (donate them); a call that does not write stores one block of
+    junk in the scratch row 0, which no live lane reads. `interpret=True` runs the kernel under the Pallas
+    interpreter (the CPU suite). Jitted, so that a program that calls
+    it once a layer and token-step traces and lowers it once."""
     s_all, z_all = state
     b, nh, hd = q.shape
-    n_kv = k.shape[1]
+    n_kv, n_keys = keys.shape[1], keys.shape[2]
     grp = nh // n_kv
     nf, nfz = hd // 2 + 1, z_all.shape[2]
     if s_all.dtype != jnp.float32 or z_all.dtype != jnp.float32:
         raise ValueError("retention_decode keeps its state in float32, "
                          f"got {s_all.dtype}")
-    if grp + 3 > 8:
+    if grp > 7:
         raise ValueError(
-            f"{grp} query heads a key-value head: the step's vectors "
-            "(queries, k, v, decay) ride in one 8-sublane tile")
+            f"{grp} query heads a key-value head: the queries and their "
+            "normalisers ride one 8-sublane tile")
+    if n_keys > RETENTION_CHUNK:
+        raise ValueError(
+            f"a write of {n_keys} token-steps: at most {RETENTION_CHUNK}, "
+            "the chunk's keys ride one 8-sublane tile")
     et = min(_RETENTION_ROWS, hd)
     f32 = jnp.float32
+
+    def tile(a):
+        """[b, n_kv, n, hd] -> [b, n_kv, 8, hd] in f32, zeros below."""
+        return jnp.pad(a.astype(f32),
+                       ((0, 0), (0, 0), (0, 8 - a.shape[2]), (0, 0)))
+
     x = jnp.concatenate([
-        q.astype(f32).reshape(b, n_kv, grp, hd),
-        k.astype(f32)[:, :, None], v.astype(f32)[:, :, None],
-        jnp.broadcast_to(jnp.exp(gate.astype(f32))[..., None, None],
-                         (b, n_kv, 1, hd)),
-        jnp.zeros((b, n_kv, 8 - grp - 3, hd), f32)], axis=2)
+        tile(q.reshape(b, n_kv, grp, hd)), tile(keys),
+        tile(vals * weights[..., None]),
+        tile(jnp.broadcast_to(weights[..., None], keys.shape)),
+        tile(jnp.broadcast_to(decay[..., None, None], (b, n_kv, 1, hd)))],
+        axis=2)
 
     def lane(*tail):
-        return lambda i, j, rows: (i, j) + tail
+        return lambda i, j, rows, write: (i, j) + tail
 
     def row(*tail):
-        return lambda i, j, rows: (rows[i], j) + tail
+        return lambda i, j, rows, write: (rows[i], j) + tail
+
+    def written(i, j, rows, write):
+        """Where a call writes: lane i's row, else the scratch row's
+        first block throughout (stored once, never read for a lane)."""
+        on = write[0] != 0
+        return (jnp.where(on, rows[i], 0), jnp.where(on, j, 0), 0, 0, 0)
 
     kern = functools.partial(_retention_decode_kernel, grp=grp, hd=hd,
-                             et=et)
+                             et=et, n_keys=n_keys)
     y, s_all, z_all = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(b, n_kv),
-            in_specs=[pl.BlockSpec((1, 1, 8, hd), lane(0, 0)),
+            in_specs=[pl.BlockSpec((1, 1, 40, hd), lane(0, 0)),
                       pl.BlockSpec((1, 1, nf, hd, hd), row(0, 0, 0)),
                       pl.BlockSpec((1, 1, nfz, hd), row(0, 0))],
             out_specs=[pl.BlockSpec((1, 1, 8, hd), lane(0, 0)),
-                       pl.BlockSpec((1, 1, nf, hd, hd), row(0, 0, 0)),
+                       pl.BlockSpec((1, 1, nf, hd, hd), written),
                        pl.BlockSpec((1, 1, nfz, hd), row(0, 0))],
-            scratch_shapes=[pltpu.VMEM((nfz * 8, hd), f32),
-                            pltpu.VMEM((grp, hd, hd), f32)]),
+            scratch_shapes=[pltpu.VMEM((nfz * 16, hd), f32),
+                            pltpu.VMEM((grp, hd, hd), f32),
+                            pltpu.VMEM((n_keys, hd, hd), f32)]),
         out_shape=[jax.ShapeDtypeStruct((b, n_kv, 8, hd), f32),
                    jax.ShapeDtypeStruct(s_all.shape, f32),
                    jax.ShapeDtypeStruct(z_all.shape, f32)],
-        # operands count the prefetched rows: S is 2, z is 3
-        input_output_aliases={2: 1, 3: 2},
+        # operands count the prefetched rows and flag: S is 3, z is 4
+        input_output_aliases={3: 1, 4: 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             # a plane set is 4.3 MB at hd 128, in and out, each
@@ -1022,6 +1076,6 @@ def retention_decode(state, rows, q, k, v, gate, interpret=False):
             vmem_limit_bytes=48 * 1024 * 1024),
         interpret=interpret,
         name="retention_decode",
-    )(rows.astype(jnp.int32), x, s_all, z_all)
-    ctx = y[:, :, :grp].reshape(b, nh, hd).astype(q.dtype)
-    return ctx, (s_all, z_all)
+    )(rows.astype(jnp.int32), jnp.asarray(write, jnp.int32).reshape(1),
+      x, s_all, z_all)
+    return y[:, :, :grp], y[:, :, 7, :grp], (s_all, z_all)

@@ -360,14 +360,20 @@ class ServingEngine:
                     None if cfg.top_p is None else float(cfg.top_p))
 
         def programs(spec, sampling, n_steps):
-            """(prefill, decode) of one model: the target's or the
-            draft's (which a tp plan refuses)."""
+            """(prefill, decode, decode's row writes a dispatch or None)
+            of one model: the target's or the draft's (which a tp plan
+            refuses)."""
+            decode = make_decode_fn(spec, cfg.block_size, sampling, n_steps)
             return (jit(make_prefill_fn(spec, cfg.block_size, sampling)),
-                    jit(make_decode_fn(spec, cfg.block_size, sampling,
-                                       n_steps)))
+                    jit(decode), getattr(decode, "writes_per_dispatch",
+                                         None))
 
-        self._prefill, self._decode = programs(
+        self._prefill, self._decode, writes = programs(
             spec, sampling, int(cfg.decode_chunk))
+        # state rows a live lane's decode dispatch writes (all layers):
+        # the step span's `state_row_writes`
+        self._row_writes_per_lane = None if writes is None \
+            else writes * int(mcfg.num_layers)
         # the chunk program serves BOTH new levers (speculative verify
         # at [slots, k+1], shared-prefix suffix prefill at [admit,
         # bucket]) — one jit, shape-bucketed executables
@@ -404,7 +410,7 @@ class ServingEngine:
                 head_dim=dspec.head_dim, dtype=pool_dtype)
             # proposals are always argmax, and ONE scan dispatch
             # proposes all k tokens
-            self._draft_prefill, self._draft_decode = programs(
+            self._draft_prefill, self._draft_decode, _ = programs(
                 dspec, (0.0, None, None), self._spec_k)
         self.sentinel = RecompileSentinel("serving")
         self._key = jax.random.key(int(cfg.seed))
@@ -573,6 +579,7 @@ class ServingEngine:
         tr = (_rt.open_step(self._step_no, self.trace_replica)
               if _rt._enabled and self.sched.has_work() else _rt.NO_STEP)
         n_exec = None
+        counts = {}
         try:
             tr.phase("retire")
             finished = self.sched.retire_finished()
@@ -617,6 +624,9 @@ class ServingEngine:
                 chunk_sigs.append((decode_sig[0], self._spec_k + 1))
             elif active:
                 decode_sig = (self._decode_chunk(active, dec_key, tr),)
+                if self._row_writes_per_lane is not None:
+                    counts["state_row_writes"] = \
+                        len(active) * self._row_writes_per_lane
 
             tr.phase("observe")
             if batch or active or tr is not _rt.NO_STEP:
@@ -639,7 +649,7 @@ class ServingEngine:
                         self.cache.n_shared)
         finally:
             # also when a dispatch raised: no annotation stays entered
-            tr.close(n_exec, **self.cache.span_counts())
+            tr.close(n_exec, **self.cache.span_counts(), **counts)
         return finished
 
     def _dispatch(self, tr, kind, bucket, width, fn, cache, args,

@@ -60,10 +60,12 @@ dead lanes. The prefill attends through the quadratic form over the
 bucket and writes each row's state as of its LAST TRUE token: junk
 rows past a prompt's length, which a paged cache lets the decode step
 overwrite, could never be taken out of a state again, so they never
-enter one. On a TPU the decode step is
-`ops/pallas_kernels.retention_decode` (the state read once and written
-once, in place), elsewhere `decoder.retention_step`, which
-tests/test_retention_serving.py holds it to.
+enter one. The decode program reads each live row once a layer and
+token-step and writes it once a layer and chunk (decoder.py, "the
+decode chunk"); the pass over the rows is
+`ops/pallas_kernels.retention_decode` on a TPU and
+`decoder.retention_pass` elsewhere, both held to `decoder.retention_step`
+by tests/test_retention_serving.py.
 """
 from __future__ import annotations
 
@@ -182,21 +184,65 @@ def _chunk_addressing(spec, block_size, tables, positions, valid):
     return attend
 
 
-def _retention_decode_addressing(rows):
-    """One token a lane through its state row `rows[i]`: scaled by the
-    token's decay, given phi(k) v^T, written back and read by the
-    lane's queries — on a TPU in one pass of the kernel, elsewhere in
-    jax.numpy."""
+def _retention_decode_addressing(rows, t, write, one_pass=None):
+    """Token-step `t` of a decode chunk, one token a lane: its k, v and
+    gate join the chunk's buffers, its queries read the state row
+    `rows[i]` as the chunk found it and add the chunk's own tokens
+    (decoder.py, "the decode chunk"); where `write`, the row is
+    replaced by its state as of this token-step. A layer's cache is
+    (state, chunk buffers). The pass over the rows is `one_pass`: by
+    default on a TPU the kernel, elsewhere jax.numpy."""
     from ..ops import pallas_kernels as _pk
-    step = _pk.retention_decode if _pk.pallas_available() \
-        else decoder.retention_step
+    if one_pass is None:
+        one_pass = _pk.retention_decode if _pk.pallas_available() \
+            else decoder.retention_pass
 
-    def attend(state, q, k, v, gate):
-        ctx, state = step(state, rows, q[:, 0], k[:, 0], v[:, 0],
-                          gate[:, 0])
-        return ctx[:, None], state
+    def attend(cache, q, k, v, gate):
+        state, chunk = cache
+        chunk = decoder.retention_chunk_push(chunk, t, k[:, 0], v[:, 0],
+                                             gate[:, 0])
+        ctx, state = decoder.retention_chunk_step(
+            state, rows, q[:, 0], chunk, t, write, one_pass)
+        return ctx[:, None], (state, chunk)
 
     return attend
+
+
+def _retention_decode_run(spec, step, n_steps):
+    """The retention decoder's decode program: `make_decode_fn`'s
+    contract, with the chunk's k, v and gates of every layer in the
+    scan's carry. Token-steps read the rows; a row is written once every
+    `span` token-steps (the chunk, or the kernel's most,
+    RETENTION_CHUNK) and at the last. `run.writes_per_dispatch` states
+    how many writes of a live row a dispatch makes (one a layer each)."""
+    from ..ops.pallas_kernels import RETENTION_CHUNK
+    span = min(n_steps, RETENTION_CHUNK)
+    kv, hd = spec.n_kv_heads, spec.head_dim
+
+    def run(state, rows, toks, positions, params, key):
+        b = rows.shape[0]
+        empty = (jnp.zeros((b, kv, span, hd), jnp.float32),
+                 jnp.zeros((b, kv, span, hd), jnp.float32),
+                 jnp.zeros((b, kv, span), jnp.float32))
+
+        def body(carry, xs):
+            caches, toks, positions = carry
+            step_key, t = xs
+            at = t % span
+            caches, tok = step(
+                caches, lambda: _retention_decode_addressing(
+                    rows, at, (at == span - 1) | (t == n_steps - 1)),
+                toks, positions, params, step_key)
+            return (caches, tok, positions + 1), tok
+
+        keys = jax.random.split(key, n_steps)
+        (caches, _, _), out = jax.lax.scan(
+            body, (tuple((s, empty) for s in state), toks, positions),
+            (keys, jnp.arange(n_steps)))
+        return tuple(s for s, _ in caches), out        # [n_steps, B]
+
+    run.writes_per_dispatch = -(-n_steps // span)
+    return run
 
 
 def _retention_prefill_addressing(rows, prompt_lens):
@@ -240,28 +286,32 @@ def make_decode_fn(spec, block_size: int, sampling, n_steps: int = 1):
     most n_steps-1 junk tokens; their writes land in their own
     reserved pages (or clamp to their last page), which die with the
     request — the host trims the emitted stream.
+
+    A retention decoder's program is `_retention_decode_run`: its rows
+    are read every token-step and written once a chunk.
     """
 
-    def step(pools, tables, toks, positions, params, key):
+    def step(pools, address, toks, positions, params, key):
+        """One token-step; `address()` gives the layers' `attend`."""
         with _scope("embed"):
             x = decoder.embed(params, toks, positions)[:, None]
-        if spec.mixer == "retention":
-            attend = _retention_decode_addressing(tables)
-        else:
-            attend = _decode_addressing(spec, block_size, tables,
-                                        positions)
-        x, pools = decoder.blocks(spec, params, x, pools, attend,
+        x, pools = decoder.blocks(spec, params, x, pools, address(),
                                   positions[:, None])
         with _scope("lm_head"):
             tok = _pick(decoder.final_logits(spec, params, x)[:, 0], key,
                         *sampling)
         return pools, tok
 
+    if spec.mixer == "retention":
+        return _retention_decode_run(spec, step, n_steps)
+
     def run(pools, tables, toks, positions, params, key):
         def body(carry, step_key):
             pools, toks, positions = carry
-            pools, tok = step(pools, tables, toks, positions, params,
-                              step_key)
+            pools, tok = step(
+                pools, lambda: _decode_addressing(spec, block_size, tables,
+                                                  positions),
+                toks, positions, params, step_key)
             return (pools, tok, positions + 1), tok
         keys = jax.random.split(key, n_steps)
         (pools, _, _), out = jax.lax.scan(

@@ -540,6 +540,52 @@ def test_gelu_is_not_expanded_in_fc2s_operand(program, monkeypatch, capsys):
                     print(f"  {which} {name}: {cycles.group(1)}")
 
 
+# (copies, Mosaic calls, fusions, temporaries B, generated code B) of
+# GPT-2's four serving programs at 2 layers, as the retention decoder's
+# changes found them: what those changes touch is shared with them
+_GPT2_PROGRAMS = {
+    "prefill_1024": (1, 2, 61, 2_774_016, 9_286_656),
+    "prefill_128": (0, 2, 59, 2_709_504, 5_707_264),
+    "decode_32x4": (8, 2, 83, 5_709_312, 2_771_456),
+    "chunk_32x5": (19, 0, 86, 205_663_232, 8_350_208)}
+
+
+@pytest.mark.parametrize("program", list(_GPT2_PROGRAMS))
+def test_gpt2_programs_compile_as_before(program, monkeypatch):
+    """The program makers and `decoder.block` are shared with the
+    retention decoder; a change to them that looks local can re-lay a
+    whole program (a scatter's index shape once added a copy of a
+    weight every token-step). GPT-2's prefill (4 x 1,024 and 4 x 128),
+    decode (32 x 4) and chunk (32 x 5) programs compile for v5e to the
+    same copies, Mosaic calls, fusions, temporaries and code size."""
+    n_layers = 2
+    if program == "decode_32x4":
+        compiled = _compile_decode(n_layers, 1024, monkeypatch)
+    elif program == "chunk_32x5":
+        from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                                 make_chunk_fn)
+        one = SingleDeviceSharding(_v5e_devices()[0])
+        pools, _, _, _, params, key = _gpt2_large_decode_avals(
+            n_layers, 1024, one, one, lambda name: one)
+        s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                                  sharding=one)
+        fn = jit_with_donated_pools(make_chunk_fn(_SPEC, PAGE, _GREEDY))
+        with _engine_precision():
+            compiled = fn.trace(pools, s32(SLOTS, TABLE_W), s32(SLOTS, 5),
+                                s32(SLOTS), s32(SLOTS), params, key).lower(
+                                    lowering_platforms=("tpu",)).compile()
+    else:
+        compiled = _compile_prefill(n_layers, 1024, monkeypatch,
+                                    int(program.split("_")[1]))
+    text = compiled.as_text()
+    ops = [op for instrs in _computations(text).values()
+           for _, _, op, _ in instrs]
+    mem = compiled.memory_analysis()
+    assert (ops.count("copy"), text.count("tpu_custom_call"),
+            ops.count("fusion"), mem.temp_size_in_bytes,
+            mem.generated_code_size_in_bytes) == _GPT2_PROGRAMS[program]
+
+
 # ---------------------------------------------------- retention decode step
 
 # the complete cell's engine shape (perfbench/configs/brumby-14b-base.json):
@@ -577,19 +623,23 @@ def _state_copies(text, rows=R_ROWS):
                          ids=["grp5_hd128", "grp1_hd128", "grp4_hd64"])
 def test_retention_decode_kernel_compiles_for_one_v5e_chip(heads, kv, hd):
     """A lane's state of one key-value head is [65, 128, 128] f32 in
-    whole tiles, z [72, 128]; one Mosaic call under its own name, the
-    state operands aliased to the outputs (donated: written in place),
-    and no temporary of the state's size."""
+    whole tiles, z [72, 128]; a chunk of 4 keys; the write a flag the
+    program computes. One Mosaic call under its own name, the state
+    operands aliased to the outputs (donated: written in place, S by
+    the kernel's own copy), and no temporary of the state's size."""
     one = SingleDeviceSharding(_v5e_devices()[0])
     state = _state_avals(one, R_ROWS, kv, hd)
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one)
 
-    def step(S, z, rows, q, k, v, gate):
-        return pk.retention_decode((S, z), rows, q, k, v, gate)
+    def step(S, z, rows, q, keys, vals, decay, weights, write):
+        return pk.retention_decode((S, z), rows, q, keys, vals, decay,
+                                   weights, write)
+    chunk = (SLOTS, kv, 4)
     compiled = jax.jit(step, donate_argnums=(0, 1)).trace(
         *state, s((SLOTS,), jnp.int32),
-        s((SLOTS, heads, hd), jnp.bfloat16), s((SLOTS, kv, hd), jnp.bfloat16),
-        s((SLOTS, kv, hd), jnp.bfloat16), s((SLOTS, kv), jnp.float32)
+        s((SLOTS, heads, hd), jnp.bfloat16), s(chunk + (hd,), jnp.float32),
+        s(chunk + (hd,), jnp.float32), s((SLOTS, kv), jnp.float32),
+        s(chunk, jnp.float32), s((), jnp.bool_)
     ).lower(lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 1
@@ -617,6 +667,42 @@ def _retention_avals(n_layers, one):
     key = jax.eval_shape(lambda: jax.random.key(0))
     return ((_state_avals(one),) * n_layers, params,
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one))
+
+
+def test_retention_decode_program_writes_a_row_once_a_chunk(monkeypatch):
+    """The decode program of 4 token-steps at the published widths (2
+    layers): the Mosaic calls that can write the state are one a layer
+    inside the scan's loop, each told whether it writes by an s32[1]
+    flag that the loop's counter gives; the flag is set on the last
+    token-step only (`writes_per_dispatch` 1, which the CPU tests hold
+    the program to token by token)."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_decode_fn)
+    n_layers = 2
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    pools, params, key = _retention_avals(n_layers, one)
+    s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one)
+    run = make_decode_fn(_retention_spec(), PAGE, _GREEDY, n_steps=4)
+    assert run.writes_per_dispatch == 1
+    with _engine_precision():
+        text = jit_with_donated_pools(run).trace(
+            pools, s32(SLOTS), s32(SLOTS), s32(SLOTS), params, key).lower(
+                lowering_platforms=("tpu",)).compile().as_text()
+    comps = _computations(text)
+    bodies = [name for name, instrs in comps.items()
+              if any("tpu_custom_call" in rest for *_, rest in instrs)]
+    calls = [(n, t, rest) for name in bodies for n, t, op, rest
+             in comps[name] if "tpu_custom_call" in rest]
+    assert len(calls) == n_layers
+    # the write flag rides as a scalar-prefetched s32[1] operand
+    for n, t, rest in calls:
+        args = re.findall(r"%([\w.\-]+)", rest.split("custom_call_target")[0])
+        types = {m: tt for name in bodies
+                 for m, tt, _, _ in comps[name]}
+        assert any(types.get(a, "").startswith("s32[1]") for a in args), \
+            rest[:300]
 
 
 def _retention_spec():
@@ -660,10 +746,12 @@ def test_retention_programs_keep_their_state_in_place(program, monkeypatch,
                                 key).lower(
                                     lowering_platforms=("tpu",)).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == \
-        (n_layers if program == "decode" else 0)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == (n_layers if program == "decode" else 0)
     assert not _state_copies(text)
     mem = compiled.memory_analysis()
+    state_bytes = n_layers * sum(np.prod(a.shape) * 4 for a in pools[0])
+    assert mem.alias_size_in_bytes >= state_bytes
     with capsys.disabled():
         print(f"\nretention {program}, {n_layers} layers: temporaries "
               f"{mem.temp_size_in_bytes / 1e6:.1f} MB, arguments "

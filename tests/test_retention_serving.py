@@ -99,12 +99,14 @@ def _pools(spec, n_layers, n_rows, dtype=jnp.float32):
 
 
 def _serve_logits(spec, params, pools, prompts, n_new, bucket, chunk=2,
-                  step=None):
+                  one_pass=None):
     """The serving programs' arithmetic with the logits kept: one
     bucketed prefill of all the prompts (row i + 1 of the state for
     prompt i, one padded lane on the scratch row), then greedy decode
-    through the state rows, `chunk` token-steps between two looks at
-    the host as the engine's scan has them. Returns (tokens
+    through the state rows in chunks of `chunk` token-steps as the
+    engine's scan has them: every token-step reads the rows, the last
+    of a chunk (and the last of all) writes them. `one_pass` is the
+    pass over the rows (default: the platform's). Returns (tokens
     [n, n_new], logits [n, n_new, V], pools)."""
     n = len(prompts)
     ids = np.zeros((n + 1, bucket), np.int32)
@@ -120,17 +122,20 @@ def _serve_logits(spec, params, pools, prompts, n_new, bucket, chunk=2,
     last = jnp.take_along_axis(x, (lens - 1)[:, None, None], axis=1)
     logits = [decoder.final_logits(spec, params, last)[:, 0]]
     positions = lens
-    attend = programs._retention_decode_addressing(rows)
-    if step is not None:
-        def attend(state, q, k, v, gate):   # noqa: F811
-            ctx, state = step(state, rows, q[:, 0], k[:, 0], v[:, 0],
-                              gate[:, 0])
-            return ctx[:, None], state
-    for _ in range(n_new - 1):
+    kv, hd = spec.n_kv_heads, spec.head_dim
+    for s in range(n_new - 1):
+        t = s % chunk
+        if t == 0:
+            empty = (jnp.zeros((n + 1, kv, chunk, hd)),) * 2 \
+                + (jnp.zeros((n + 1, kv, chunk)),)
+            caches = tuple((state, empty) for state in pools)
+        attend = programs._retention_decode_addressing(
+            rows, t, t == chunk - 1 or s == n_new - 2, one_pass)
         tok = jnp.argmax(logits[-1].astype(jnp.float32), axis=-1)
-        x, pools = decoder.blocks(
+        x, caches = decoder.blocks(
             spec, params, decoder.embed(params, tok, positions)[:, None],
-            pools, attend, positions[:, None])
+            caches, attend, positions[:, None])
+        pools = tuple(state for state, _ in caches)
         logits.append(decoder.final_logits(spec, params, x)[:, 0])
         positions = positions + 1
     logits = np.asarray(jnp.stack(logits, axis=1), np.float32)[:n]
@@ -319,20 +324,102 @@ def _random_state(rng, spec, n_rows):
     return s, z.at[:, :, feats:].set(0.0)
 
 
+def _chunk_inputs(rng, spec, b, n, decay=None):
+    """n token-steps of inputs for b lanes: q, k, v as `_step_inputs`,
+    the gates all log(decay) where a decay is given."""
+    steps = [_step_inputs(rng, spec, b) for _ in range(n)]
+    if decay is not None:
+        steps = [(q, k, v, jnp.full_like(g, np.log(decay)))
+                 for q, k, v, g in steps]
+    return steps
+
+
+def _empty_chunk(spec, b, n):
+    kv, hd = spec.n_kv_heads, spec.head_dim
+    return (jnp.zeros((b, kv, n, hd)),) * 2 + (jnp.zeros((b, kv, n)),)
+
+
+def _run_chunk(state, rows, steps, one_pass, spec):
+    """A whole chunk through `retention_chunk_step`, written at its
+    last token-step: ([ctx a token-step], state after each)."""
+    chunk = _empty_chunk(spec, rows.shape[0], len(steps))
+    ctxs, states = [], []
+    for t, (q, k, v, g) in enumerate(steps):
+        chunk = decoder.retention_chunk_push(chunk, t, k, v, g)
+        ctx, state = decoder.retention_chunk_step(
+            state, rows, q, chunk, t, t == len(steps) - 1, one_pass)
+        ctxs.append(ctx)
+        states.append(state)
+    return ctxs, states
+
+
+_PASSES = {"jnp": decoder.retention_pass,
+           "kernel": functools.partial(pk.retention_decode, interpret=True)}
+
+
+def _prefilled_state(rng, spec, n_rows, n_tokens=12):
+    """Rows as a prefill leaves them: 12 tokens of random k and v at
+    decays 0.5-0.999 (z a positive sum of features, so phi(q).z is a
+    sum of squares, as it is in a served row)."""
+    kv, hd = spec.n_kv_heads, spec.head_dim
+    k, v = (jnp.asarray(rng.normal(size=(n_rows, n_tokens, kv, hd)),
+                        jnp.float32) for _ in range(2))
+    gate = jnp.log(jnp.asarray(rng.uniform(0.5, 0.999,
+                                           (n_rows, n_tokens, kv)),
+                               jnp.float32))
+    return decoder.retention_state(k, v, gate,
+                                   jnp.full((n_rows,), n_tokens))
+
+
 @pytest.mark.parametrize("step", ["jnp", "kernel"])
 def test_a_dead_lane_changes_only_the_scratch_row(model, step):
+    """A whole chunk of 3 token-steps, two dead lanes on row 0: the
+    token-steps before the last write no row but the scratch row, and
+    after the flush every row nobody holds is bit-equal to what it
+    was."""
     spec = model.decoder_spec()
     rng = np.random.default_rng(4)
     state = _random_state(rng, spec, 5)
-    fn = decoder.retention_step if step == "jnp" else functools.partial(
-        pk.retention_decode, interpret=True)
     rows = jnp.asarray([3, 0, 0], jnp.int32)
-    _, (s_new, z_new) = fn(state, rows, *_step_inputs(rng, spec, 3))
-    for old, new in ((state[0], s_new), (state[1], z_new)):
+    _, states = _run_chunk(state, rows, _chunk_inputs(rng, spec, 3, 3),
+                           _PASSES[step], spec)
+    for mid in states[:-1]:
+        for old, new in zip(state, mid):
+            assert (np.asarray(new)[1:] == np.asarray(old)[1:]).all()
+    for old, new in zip(state, states[-1]):
         old, new = np.asarray(old), np.asarray(new)
         for r in (1, 2, 4):
             assert (new[r] == old[r]).all()
         assert not (new[3] == old[3]).all()
+
+
+@pytest.mark.parametrize("decay", [0.05, 0.5, 0.999])
+@pytest.mark.parametrize("n_steps", [1, 2, 3, 4])
+def test_a_chunk_equals_its_token_steps_one_by_one(model, n_steps, decay):
+    """The chunked form (rows read every token-step, written at the
+    chunk's last) against n `retention_step`s that read and write every
+    token-step: each token-step's context, and S and z after the chunk,
+    within float32 rounding; dead lanes on row 0. A decay of 0.05 a
+    token is the fast gate under which a junk query's weights
+    underflow."""
+    spec = model.decoder_spec()
+    rng = np.random.default_rng(7 + n_steps)
+    state = _prefilled_state(rng, spec, 5)
+    rows = jnp.asarray([2, 0, 4, 0], jnp.int32)
+    steps = _chunk_inputs(rng, spec, 4, n_steps, decay)
+    ctxs, states = _run_chunk(state, rows, steps, decoder.retention_pass,
+                              spec)
+    ref = state
+    for (q, k, v, g), got in zip(steps, ctxs):
+        want, ref = decoder.retention_step(ref, rows, q, k, v, g)
+        live = np.asarray(want)[[0, 2]]
+        np.testing.assert_allclose(np.asarray(got)[[0, 2]], live,
+                                   rtol=1e-4, atol=1e-5 * np.abs(live).max())
+    for new, old, before in zip(states[-1], ref, state):
+        new = np.asarray(new)
+        np.testing.assert_allclose(new[[2, 4]], np.asarray(old)[[2, 4]],
+                                   rtol=1e-5, atol=1e-5)
+        assert (new[[1, 3]] == np.asarray(before)[[1, 3]]).all()
 
 
 def test_a_row_given_to_a_new_request_starts_from_that_request_alone(
@@ -355,27 +442,41 @@ def test_a_row_given_to_a_new_request_starts_from_that_request_alone(
 
 # -- the kernel against the jax.numpy step ------------------------------------
 
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
 @pytest.mark.parametrize("heads", [(4, 2, 16), (10, 2, 128), (8, 8, 32)],
                          ids=["toy", "grp5_hd128", "grp1_hd32"])
-def test_retention_decode_kernel_equals_the_jnp_step(heads):
-    """Interpret mode: context, S and z of the visited rows within
-    float32 rounding of `decoder.retention_step`, every other row
-    bit-equal; z's padding rows stay zero."""
+def test_retention_decode_kernel_equals_the_jnp_pass(heads, write):
+    """Interpret mode, a chunk of 3 keys at its last token-step:
+    phi(q)^T S and phi(q).z of the visited rows within float32 rounding
+    of `decoder.retention_pass`; S and z written as the jnp pass writes
+    them, or, in a read-only step, not at all (bit-equal); every row
+    nobody visits bit-equal; z's padding rows stay zero."""
     nh, kv, hd = heads
     spec = RetentionConfig(num_heads=nh, num_kv_heads=kv, head_dim=hd
                            ).decoder_spec()
     rng = np.random.default_rng(5)
     state = _random_state(rng, spec, 4)
     rows = jnp.asarray([2, 0, 3], jnp.int32)
-    args = _step_inputs(rng, spec, 3)
-    want, (s_w, z_w) = decoder.retention_step(state, rows, *args)
-    got, (s_g, z_g) = pk.retention_decode(state, rows, *args,
-                                          interpret=True)
-    scale = float(np.abs(np.asarray(want)).max())
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=2e-5 * scale)
-    np.testing.assert_allclose(np.asarray(s_g), np.asarray(s_w), atol=1e-5)
-    np.testing.assert_allclose(np.asarray(z_g), np.asarray(z_w), atol=1e-5)
+    q = _step_inputs(rng, spec, 3)[0]
+    keys, vals = (jnp.asarray(rng.normal(size=(3, kv, 3, hd)), jnp.float32)
+                  for _ in range(2))
+    decay, w = decoder.retention_chunk_weights(
+        jnp.asarray(-rng.uniform(0.01, 0.3, (3, kv, 3)), jnp.float32), 2)
+    args = (state, rows, q, keys, vals, decay, w, write)
+    n_w, d_w, (s_w, z_w) = decoder.retention_pass(*args)
+    n_g, d_g, (s_g, z_g) = pk.retention_decode(*args, interpret=True)
+    for got, want in ((n_g, n_w), (d_g, d_w)):
+        scale = float(np.abs(np.asarray(want)).max())
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6 * scale)
+    if write:
+        np.testing.assert_allclose(np.asarray(s_g), np.asarray(s_w),
+                                   atol=1e-5)
+        np.testing.assert_allclose(np.asarray(z_g), np.asarray(z_w),
+                                   atol=1e-5)
+    else:       # the scratch row 0 may take a block of junk
+        assert (np.asarray(s_g)[1:] == np.asarray(state[0])[1:]).all()
+        assert (np.asarray(z_g)[1:] == np.asarray(state[1])[1:]).all()
     assert (np.asarray(s_g[1]) == np.asarray(state[0][1])).all()
     assert (np.asarray(z_g)[:, :, hd // 2 + 1:] == 0.0).all()
 
@@ -384,9 +485,11 @@ def test_retention_decode_refuses_a_state_that_is_not_float32(model):
     spec = model.decoder_spec()
     rng = np.random.default_rng(6)
     state = _cast(_random_state(rng, spec, 2), jnp.bfloat16)
+    q, k, v, g = _step_inputs(rng, spec, 1)
     with pytest.raises(ValueError, match="float32"):
-        pk.retention_decode(state, jnp.zeros((1,), jnp.int32),
-                            *_step_inputs(rng, spec, 1), interpret=True)
+        pk.retention_decode(state, jnp.zeros((1,), jnp.int32), q,
+                            k[:, :, None], v[:, :, None], g, g[..., None],
+                            True, interpret=True)
 
 
 def test_greedy_tokens_equal_through_the_kernel_and_the_jnp_step(
@@ -396,7 +499,7 @@ def test_greedy_tokens_equal_through_the_kernel_and_the_jnp_step(
                                      prompts, N_NEW, 32)
     t_ker, lg_ker, _ = _serve_logits(
         spec, params, _pools(spec, 2, 6), prompts, N_NEW, 32,
-        step=functools.partial(pk.retention_decode, interpret=True))
+        one_pass=_PASSES["kernel"])
     assert (t_jnp == t_ker).all()
     np.testing.assert_allclose(lg_ker, lg_jnp, atol=1e-5)
 
@@ -433,15 +536,18 @@ def _engine(model, **kw):
     return ServingEngine(model, ServingConfig(**{**cfg, **kw}))
 
 
+@pytest.mark.parametrize("decode_chunk", [1, 2, 4])
 def test_engine_serves_the_reference_s_greedy_tokens(reference, cfg,
                                                      weights, model,
-                                                     prompts):
+                                                     prompts, decode_chunk):
     """Through `ServingEngine.step()`, the FIFO scheduler and the
     bucket ladder: four requests over three slots (the fourth waits
     for a row, and gets a used one), admitted two at a time, decoded
-    in chunks of 2: each request's tokens are the reference's argmax
-    at every served position. Rows come back; the ladder holds."""
-    eng = _engine(model).warmup()
+    in chunks of 1, 2 and 4 token-steps (a row written every
+    token-step, every second, every fourth): each request's tokens are
+    the reference's argmax at every served position. Rows come back;
+    the ladder holds."""
+    eng = _engine(model, decode_chunk=decode_chunk).warmup()
     assert isinstance(eng.cache, StateCache)
     assert eng.executable_count() == eng.expected_executables == 3
     budgets = [6, 5, 7, 4]
@@ -510,3 +616,35 @@ def test_step_span_carries_the_live_rows(model, prompts):
     assert steps and all("state_rows_live" in e and "executables" in e
                          for e in steps)
     assert max(e["state_rows_live"] for e in steps) == 2
+
+
+def test_step_span_counts_the_row_writes(model, prompts):
+    """A step that decodes writes `state_row_writes` on its span: live
+    lanes x layers x writes a dispatch (one: a chunk of 2 is written
+    once). A step that does not decode writes none."""
+    from paddle_tpu.observability import reqtrace
+    eng = _engine(model).warmup()
+    reqtrace.enable(True, capacity=4096)
+    try:
+        eng.generate_tokens(prompts[:2], 5)
+        evts = reqtrace.get_tracer().events()
+    finally:
+        reqtrace.disable()
+    steps = [e for e in evts if e.get("comp") == "step"]
+    writes = [e["state_row_writes"] for e in steps
+              if "state_row_writes" in e]
+    # one `decode` span a live lane and dispatch; 2 layers
+    lanes = sum(1 for e in evts if e.get("comp") == "decode")
+    assert writes and sum(writes) == 2 * lanes
+    assert len(writes) < len(steps)     # the last step only retires
+
+
+@pytest.mark.parametrize("n_steps,writes", [(1, 1), (4, 1), (8, 1),
+                                            (10, 2), (17, 3)])
+def test_a_retention_decode_program_states_its_writes(model, n_steps,
+                                                      writes):
+    """A row is written once a chunk, and at least once every
+    RETENTION_CHUNK token-steps (the kernel's tile of keys)."""
+    run = programs.make_decode_fn(model.decoder_spec(), 8,
+                                  (0.0, None, None), n_steps)
+    assert run.writes_per_dispatch == writes
