@@ -39,14 +39,18 @@ marks are points:
 Engine steps are on the same ring (``open_step`` and the handle it
 returns, the ONE writer): every ``ServingEngine.step()`` that has work
 is one ``step`` span (rid None; step, replica, ``executables``, the
-engine's compile count at that boundary, and ``state_rows_live`` where
-the engine's cache is state rows, with ``state_row_writes`` on a step
-that decodes them) over contiguous phase
-spans (``STEP_PHASES``; step, replica, parent="step", and the
-program's ``kind`` on a dispatch's phases). The same call that writes
-a phase to the ring enters ``jax.profiler.TraceAnnotation(
-"serve:<phase>", step=n)``, so a profiler capture shows the phases on
-the device ops' clock and ring and capture join by (name, step). Step
+engine's compile count at that boundary; what the cache's
+``span_counts()`` gives, ``pages_live`` or ``state_rows_live``; and on
+a step that decodes ``lanes``, the requests its decode dispatch
+carried, with ``state_row_writes`` where they are state rows) over
+contiguous phase spans (``STEP_PHASES``; step, replica,
+parent="step", and the program's ``kind`` on a dispatch's phases; a
+``sync`` also carries ``ready``, when the fetched output's device
+buffer was ready). The same call that writes a phase to the ring
+enters ``jax.profiler.TraceAnnotation("serve:<phase>", step=n)``, so a
+profiler capture shows the phases on the device ops' clock and ring
+and capture join by (name, step); inside a ``sync``, ``serve:fetch``
+covers ``ready`` to the phase's end, the copy to the host. Step
 events are not requests: ``timelines`` (so ``attribute`` and
 ``explain_tail``) skips them and ``chrome_trace_events`` draws them on
 lanes of their own. They share the ring, 9 to 18 slots a step, which
@@ -54,8 +58,9 @@ is why its default capacity is what it is.
 
 Cost discipline is the flight recorder's, verbatim: one module bool
 (``_enabled``) gates everything; a disabled ``record_span()`` or
-``open_step()`` is a function call plus a bool read, a ``phase()`` of
-the ``NO_STEP`` it returned an empty method (<1 µs, tier-1-guarded);
+``open_step()`` is a function call plus a bool read, a ``phase()`` or
+``ready()`` of the ``NO_STEP`` it returned an empty method (<1 µs,
+tier-1-guarded);
 enabled writes claim a ring slot from an ``itertools.count`` (atomic
 under the GIL — no hot-path lock). The module imports no numpy, and no
 jax until ``enable()`` turns tracing on (for the annotation): the ring
@@ -218,17 +223,21 @@ class _Step:
     ends the step; the engine calls it in a ``finally``, so a step
     that raised leaves no annotation entered."""
 
-    __slots__ = ("step", "replica", "t0", "_whole", "_open")
+    __slots__ = ("step", "replica", "t0", "_whole", "_open", "_fetch")
 
     def __init__(self, step: int, replica):
         self.step, self.replica = step, replica
         self._open = None       # (name, t0, meta, annotation)
+        self._fetch = None      # serve:fetch, nested in the open sync
         self._whole = _TraceAnnotation("serve:step", step=step)
         self._whole.__enter__()
         self.t0 = time.perf_counter()
 
     def _close_phase(self, t: Optional[float]) -> float:
         now = time.perf_counter() if t is None else t
+        if self._fetch is not None:
+            self._fetch.__exit__(None, None, None)
+            self._fetch = None
         if self._open is not None:
             name, t0, meta, annotation = self._open
             annotation.__exit__(None, None, None)
@@ -249,12 +258,25 @@ class _Step:
         annotation.__enter__()
         self._open = (name, now, meta, annotation)
 
+    def ready(self):
+        """Stamp the open ``sync`` with ``ready``, now: the fetched
+        output's device buffer is ready, and what is left of the phase
+        is its copy to the host, which ``serve:fetch`` covers on the
+        profiler's clock until the phase closes."""
+        now = time.perf_counter()
+        meta = self._open[2]
+        self._fetch = _TraceAnnotation("serve:fetch", step=self.step,
+                                       **meta)
+        self._fetch.__enter__()
+        meta["ready"] = now
+
     def close(self, executables: Optional[int] = None, **counts):
         """End the step: its last phase, then the ``step`` span with
         the engine's compile count at this boundary and what else the
-        engine counts there (``state_rows_live`` where its cache is
-        state rows, and ``state_row_writes`` where a step decodes
-        them)."""
+        engine counts there (the cache's ``span_counts()``:
+        ``pages_live`` or ``state_rows_live``; on a step that decodes
+        ``lanes``, and ``state_row_writes`` where the lanes are state
+        rows)."""
         now = self._close_phase(None)
         self._whole.__exit__(None, None, None)
         _tracer.record_span(None, "step", self.t0, now, step=self.step,
@@ -268,6 +290,9 @@ class _NoStep:
     __slots__ = ()
 
     def phase(self, name, kind=None, t=None):
+        pass
+
+    def ready(self):
         pass
 
     def close(self, executables=None, **counts):

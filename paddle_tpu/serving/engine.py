@@ -490,8 +490,6 @@ class ServingEngine:
                 _rt.mark(req.rid, "submit", t=req.arrival)
             _rt.mark(req.rid, "dispatch", replica=self.trace_replica)
         self.sched.submit(req)
-        if _obs._enabled:
-            _obs.gauge("serving.queue_depth").set(self.sched.queue_depth)
         return req.rid
 
     def has_work(self) -> bool:
@@ -619,6 +617,8 @@ class ServingEngine:
                 else:
                     prefill_sig = shape
             active = self.sched.active()
+            if active:
+                counts["lanes"] = len(active)
             if active and self._spec_k:
                 decode_sig = (self._speculate(active, dec_key, tr),)
                 chunk_sigs.append((decode_sig[0], self._spec_k + 1))
@@ -638,10 +638,6 @@ class ServingEngine:
                                                     decode_sig,
                                                     chunk_sigs))
             if rec:
-                _obs.gauge("serving.queue_depth").set(
-                    self.sched.queue_depth)
-                _obs.gauge("serving.active_slots").set(
-                    len(self.sched.active()))
                 _obs.gauge("serving.pages_free").set(self.cache.n_free)
                 _obs.gauge("serving.pages_live").set(self.cache.n_live)
                 if cfg.prefix_sharing:
@@ -649,7 +645,8 @@ class ServingEngine:
                         self.cache.n_shared)
         finally:
             # also when a dispatch raised: no annotation stays entered
-            tr.close(n_exec, **self.cache.span_counts(), **counts)
+            if tr is not _rt.NO_STEP:
+                tr.close(n_exec, **self.cache.span_counts(), **counts)
         return finished
 
     def _dispatch(self, tr, kind, bucket, width, fn, cache, args,
@@ -658,7 +655,8 @@ class ServingEngine:
         is its shape) over ``cache``'s pools and fetch output ``take``
         (None: nothing is fetched — the draft's prompt prefill). The
         one place that writes the ``dispatch`` (the jitted call, until
-        it returns) and ``sync`` (the fetch that waits for the device)
+        it returns) and ``sync`` (the fetch that waits for the device;
+        on a traced step stamped ``ready`` when the output's buffer is)
         phases and holds the OOM sentry (zero cost on the success
         path): a RESOURCE_EXHAUSTED leaves the breadcrumb + post-mortem
         receipt before the engine dies."""
@@ -674,7 +672,16 @@ class ServingEngine:
         if take is None:
             return None
         tr.phase("sync", kind)
-        return np.asarray(out[take])
+        if tr is _rt.NO_STEP:
+            return np.asarray(out[take])
+        # the copy np.asarray would ask for, asked for first, so that
+        # waiting for the buffer adds no round trip: `ready` splits the
+        # phase into the device's work and the copy to the host
+        res = out[take]
+        res.copy_to_host_async()
+        res.block_until_ready()
+        tr.ready()
+        return np.asarray(res)
 
     def _pack_prompts(self, batch, bucket: int, skip=None):
         """[max_admit, bucket] ids and true lengths of each request's
@@ -929,8 +936,6 @@ class ServingEngine:
         evicted = running + queued
         if _obs._enabled and evicted:
             _obs.counter("serving.evicted_total").add(len(evicted))
-            _obs.gauge("serving.queue_depth").set(0)
-            _obs.gauge("serving.active_slots").set(0)
             _obs.gauge("serving.pages_free").set(self.cache.n_free)
         return evicted
 

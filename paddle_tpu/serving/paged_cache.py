@@ -442,8 +442,8 @@ class PagedKVCache:
 
     def span_counts(self) -> dict:
         """What the engine writes of this cache on its ``step`` span:
-        nothing yet (no reader asks for the pages' counts there)."""
-        return {}
+        the distinct live pages."""
+        return {"pages_live": self.n_live}
 
     def warm_copy(self):
         """Compile the COW page-copy program up front (scratch ->

@@ -18,10 +18,13 @@ Receipts pinned here:
 - serving_breach_verdict priorities: replica death (kill > covert
   stall) > recompile > overload shed > swap flip > dominant component;
 - engine steps: every working step is one ``step`` span over
-  contiguous, ordered phases that share its ``step``; the requests'
-  spans keep the fields and stamps they had; step events stay out of
-  the request readers; one ``serve:`` annotation per ring phase.
-  Structure only: no duration is asserted.
+  contiguous, ordered phases that share its ``step``; a ``sync``'s
+  ``ready`` lies inside it; a step's ``lanes`` are the requests its
+  decode dispatch carried and its ``pages_live`` the cache's live pages
+  at its close; the requests' spans keep the fields and stamps they
+  had; step events stay out of the request readers; one ``serve:``
+  annotation per ring phase, and a ``serve:fetch`` inside each
+  ``sync``. Structure only: no duration is asserted.
 """
 import time
 
@@ -91,13 +94,14 @@ def test_disabled_record_under_one_microsecond():
 def test_disabled_step_helpers_under_one_microsecond():
     """The step writer's sites are in ``ServingEngine.step()`` for
     good: off, ``open_step`` is a bool read and hands out ``NO_STEP``,
-    whose ``phase`` and ``close`` are empty methods."""
+    whose ``phase``, ``ready`` and ``close`` are empty methods."""
     assert not rt.enabled()
     n = 10000
     off = rt.open_step(1, 0)
     assert off is rt.NO_STEP
     for name, call in (("open_step", lambda: rt.open_step(1, 0)),
                        ("phase", lambda: off.phase("build", "decode")),
+                       ("ready", off.ready),
                        ("close", lambda: off.close(3))):
         medians = []
         for _ in range(5):
@@ -425,8 +429,11 @@ def test_every_working_step_is_one_step_span_over_ordered_phases(
     kinds_seen = set()
     for n, (whole, phases) in steps.items():
         assert whole is not None and whole["rid"] is None
+        decodes = any(p.get("kind") in ("decode", "verify")
+                      for p in phases)
         assert set(whole) == {"i", "rid", "comp", "t0", "t1", "step",
-                              "replica", "executables"}
+                              "replica", "executables", "pages_live"} \
+            | ({"lanes"} if decodes else set())
         names = [p["comp"] for p in phases]
         assert names[:3] == ["retire", "admit", "keys"]
         assert names[-1] == "observe" and names.count("observe") == 1
@@ -449,6 +456,9 @@ def test_every_working_step_is_one_step_span_over_ordered_phases(
             if ph["comp"] in ("build", "dispatch", "sync", "accept"):
                 fields.add("kind")
                 kinds_seen.add(ph["kind"])
+            if ph["comp"] == "sync":
+                fields.add("ready")
+                assert ph["t0"] <= ph["ready"] <= ph["t1"]
             assert set(ph) == fields
     assert kinds_seen == ({"draft", "verify", "prefill"}
                           if lever == "speculative"
@@ -512,6 +522,76 @@ def test_request_spans_keep_their_fields_and_stamps(model):
     assert n_admission == n_prefill == 4 and n_decode > 4
 
 
+def test_a_steps_lanes_are_the_requests_its_decode_dispatch_carried(
+        model):
+    """`lanes` counts where the work happens: after the step's retire
+    and admit, so a step that retires one request and admits another
+    still names exactly the `decode` spans of its dispatch; and
+    `pages_live` is the cache's count at the step's close."""
+    rt.enable()
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.reset()
+    rng = np.random.RandomState(3)
+    for i, (L, n) in enumerate([(3, 2), (5, 6), (4, 3), (6, 5), (7, 4),
+                                (3, 6), (9, 2)]):
+        eng.submit(rng.randint(0, 97, (L,)).astype(np.int32), n,
+                   rid=f"q{i}")
+    turnover = {}          # step -> (rids retired, rids admitted)
+    pages = {}             # step -> cache.n_live after it
+    while eng.has_work():
+        before = set(eng.sched.running)
+        eng.step()
+        after = set(eng.sched.running)
+        turnover[eng._step_no] = (before - after, after - before)
+        pages[eng._step_no] = eng.cache.n_live
+    evts = rt.get_tracer().events()
+    carried = {}
+    for e in evts:
+        if e.get("comp") == "decode":
+            carried[e["tick"]] = carried.get(e["tick"], 0) + 1
+    steps = {n: w for n, (w, _) in _steps_of(evts).items()}
+    assert set(steps) == set(pages)
+    assert {n for n, w in steps.items() if "lanes" in w} == set(carried)
+    for n, w in steps.items():
+        assert w["pages_live"] == pages[n]
+        if n in carried:
+            assert w["lanes"] == carried[n]
+    both = [n for n, (gone, new) in turnover.items() if gone and new]
+    assert both and all(n in carried for n in both)
+    assert max(w["pages_live"] for w in steps.values()) > 0
+    assert steps[max(steps)]["pages_live"] == 0 == eng.cache.n_live
+
+
+def test_a_fetch_annotation_nests_inside_its_sync(model, monkeypatch):
+    """`serve:fetch` opens after its `sync` and closes before it, and
+    the ring's `ready` stamp is what divides the two."""
+    stack, fetches = [], []
+
+    class Nesting:
+        def __init__(self, name, **kw):
+            self.name, self.kw = name, kw
+
+        def __enter__(self):
+            if self.name == "serve:fetch":
+                fetches.append((stack[-1], self.kw))
+            stack.append(self.name)
+
+        def __exit__(self, *exc):
+            assert stack.pop() == self.name
+
+    eng = ServingEngine(model, engine_config()).warmup()
+    rt.enable()
+    monkeypatch.setattr(rt, "_TraceAnnotation", Nesting)
+    rt.reset()
+    _drive(eng)
+    assert stack == []
+    syncs = [e for e in rt.get_tracer().events() if e.get("comp") == "sync"]
+    assert len(fetches) == len(syncs) > 0
+    assert all(outer == "serve:sync" for outer, _ in fetches)
+    assert sorted((kw["step"], kw["kind"]) for _, kw in fetches) == \
+        sorted((e["step"], e["kind"]) for e in syncs)
+
+
 def _fixed_request_events():
     return [
         {"i": 0, "rid": "fast", "mark": "submit", "t": 0.0},
@@ -571,7 +651,8 @@ def test_serve_annotation_entered_once_per_ring_phase(model,
                                                       monkeypatch):
     """One writer, two clocks: each ring phase (and each step) entered
     and left exactly one `serve:<name>` profiler annotation carrying
-    the same `step`; the requests' spans entered none."""
+    the same `step`, and each `sync` one `serve:fetch` besides; the
+    requests' spans entered none."""
     entered, left = [], []
 
     class Counting:
@@ -593,6 +674,7 @@ def test_serve_annotation_entered_once_per_ring_phase(model,
     ring = [("serve:" + e["comp"], e["step"])
             for e in rt.get_tracer().events() if e.get("rid") is None
             and "comp" in e]
+    ring += [("serve:fetch", n) for name, n in ring if name == "serve:sync"]
     assert ring and sorted(entered) == sorted(ring) == sorted(left)
     # and with tracing off the engine enters none
     rt.disable()
