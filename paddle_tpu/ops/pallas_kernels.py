@@ -696,13 +696,13 @@ def flash_prefill_attention(q, k, v, lengths, scale, interpret=False):
 # K/V pages a grid step: each is one operand window of its pool, so a
 # step's pages are fetched by the pipeline's own page-sized copies
 # while the step before is attended. A slot's last step is padded to
-# this many windows, and an inactive lane is a step of its own. On the
-# v5e a step costs about 0.8 us whatever it holds (two small matmuls
-# and the accumulator's update) and a window about 0.02 us, so more
-# pages a step means fewer steps for long slots and a dearer step for
-# an inactive lane: 4, 8 and 16 read 25, 28 and 35 us a call with no
-# slot of 32 live, 30, 30 and 36 with 4 live, 53, 41 and 38 with 18,
-# 303, 175 and 123 with every table full (PERF.md, PR 29).
+# this many windows; an inactive lane has no step. On the v5e a step
+# costs about 0.8 us whatever it holds (two small matmuls and the
+# accumulator's update) and a window about 0.02 us, so more pages a
+# step means fewer steps for long slots and a dearer last step: 4, 8
+# and 16 read 303, 175 and 123 us a call with every table full; 8
+# reads 2.2, 2.8, 7.4, 28.8 and 49.6 us with 0, 1, 4, 18 and 32 of 32
+# lanes live at 200 tokens (PERF.md).
 _PAGES_PER_STEP = 8
 
 
@@ -747,7 +747,9 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
     length still holds an older page (no copy was made for it): its
     positions are masked, so it weighs exactly 0. q and the output are
     whole in VMEM, f32 so that one row can be read and written at a
-    dynamic sublane."""
+    dynamic sublane. The output is zeroed at the first step: the row of
+    an inactive lane (length 0, no round of its own) stays 0, and the
+    one step of a call with no live lane writes nothing."""
     del fetch_ref                               # the index maps read it
     k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
@@ -756,6 +758,10 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
     length = lengths_ref[slot]
     sel = sel_ref[...]                                         # [nhp, D]
     nhp, t_step = sel.shape[0], pps * bs
+
+    @pl.when(t == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
 
     @pl.when(rnd == 0)
     def _():
@@ -778,7 +784,7 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = alpha * acc_ref[...] + _dot_f32(p, v, ((1,), (0,)))
 
-    @pl.when((rnd + 1) * t_step >= length)
+    @pl.when((length > 0) & ((rnd + 1) * t_step >= length))
     def _():
         # one head's row is non-zero in a lane, so the sums over the
         # heads are that row's value: acc / l, element by element
@@ -789,17 +795,24 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
 
 def _decode_rounds(tables, lengths, bs, pps):
     """The kernel's work list, from the tables and lengths alone (the
-    same for every layer of a token-step). A slot has
-    ceil(pages held / pps) rounds, at least one. Returns the number of
-    rounds in all, and per round (padded to the most there can be) its
-    slot, its index within the slot, and the page each of its `pps`
-    windows holds: the table entry where that is live (it holds a
-    position under the slot's length), elsewhere the page the same
-    window held the round before, so the pipeline, which copies only
-    when a window's index changes, fetches live pages and nothing else
-    (the first round may fetch pages it does not need)."""
+    same for every layer of a token-step). A lane whose first table
+    entry is the scratch page 0 is inactive: it is taken to hold
+    nothing (length 0) and has no round. A live slot has
+    ceil(pages held / pps) rounds. Returns the lengths so taken, the
+    number of rounds in all (at least one, so that a call with no live
+    lane still zeroes its output), and per round (padded to the most
+    there can be) its slot, its index within the slot, and the page
+    each of its `pps` windows holds: the table entry where that is
+    live (it holds a position under the slot's length), elsewhere the
+    page the same window held the round before, so the pipeline, which
+    copies only when a window's index changes, fetches live pages and
+    nothing else (the first round may fetch pages it does not need)."""
     b, w = tables.shape
     most = b * (w // pps)
+    # each row's first entry, by a reduction along the row: a column
+    # slice has XLA copy the tables to column-major order first
+    first = jnp.max(jnp.where(jnp.arange(w) == 0, tables, 0), axis=1)
+    lengths = jnp.where(first == 0, 0, lengths)
     pages = -(-lengths // bs)
     rounds = -(-pages // pps)
     ends = jnp.cumsum(rounds)
@@ -812,7 +825,8 @@ def _decode_rounds(tables, lengths, bs, pps):
     last = jax.lax.cummax(jnp.where(live, at[:, None], 0), axis=0)
     entry = tables[slot[:, None], jnp.minimum(col, w - 1)]
     fetch = jnp.take_along_axis(entry, last, axis=0)
-    return ends[-1], slot, rnd.astype(jnp.int32), fetch.reshape(-1)
+    return (lengths, jnp.maximum(ends[-1], 1), slot, rnd.astype(jnp.int32),
+            fetch.reshape(-1))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -824,12 +838,15 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
     token, heads side by side: the shape whose default device layout
     is the row-major one that the serving programs write, so nothing
     converts a pool around the call); tables [B, W] int32 (table order
-    is logical order); lengths [B] int32, each at least 1: slot b
-    attends positions 0..lengths[b]-1, exactly. Returns [B, nh, hd] in
-    the pools' dtype. The pools stay in HBM: only pages that hold a
-    live position are read, and the grid has as many steps as the
-    slots hold rounds of pages, so the work grows with the tokens held
-    and not with the table's width. f32 scores, softmax statistics and
+    is logical order); lengths [B] int32: slot b attends positions
+    0..lengths[b]-1, exactly, each at least 1 where the slot is live. A
+    lane whose first table entry is the scratch page 0 is inactive: it
+    has no round and its output row is 0, whatever its length. Returns
+    [B, nh, hd] in the pools' dtype. The pools stay in HBM: only pages
+    that hold a live position are read, and the grid has as many steps
+    as the live slots hold rounds of pages (one, if none is live), so
+    the work grows with the tokens held and not with the table's width
+    or the lanes. f32 scores, softmax statistics and
     accumulation; the result is rounded once, here. `interpret=True`
     runs the kernel under the Pallas interpreter (the CPU suite).
     Jitted, so that a program that calls it once a layer traces and
@@ -843,7 +860,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
     pad = -tables.shape[1] % pps
     if pad:
         tables = jnp.pad(tables, ((0, 0), (0, pad)))
-    n_rounds, slot, rnd, fetch = _decode_rounds(tables, lengths, bs, pps)
+    lengths, n_rounds, slot, rnd, fetch = _decode_rounds(tables, lengths,
+                                                         bs, pps)
     # heads padded to whole 16-bit sublane tiles; the padding's rows of
     # `sel` are zero, so they score 0 everywhere and are never picked
     nhp = _ceil_to(nh, 16)

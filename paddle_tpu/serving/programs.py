@@ -39,7 +39,8 @@ paged_cache.py); logical position ``p`` of a request lives in page
 ``table[p // block_size]`` at offset ``p % block_size``. The programs
 write whole rows and split heads only after a gather. Masked or padded
 lanes carry an all-zeros table row — their writes land in the reserved
-scratch page 0 and their reads are iota-masked, so inactive lanes cost
+scratch page 0 and their reads are iota-masked (the decode kernel gives
+such a lane no step at all, and a context of 0), so inactive lanes cost
 no conditional scatter. Junk K/V (pad positions a bucketed prefill
 computes past a row's true length) is routed to scratch by table
 padding or overwritten by the decode scatter, and never attended:

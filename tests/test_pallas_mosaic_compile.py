@@ -546,7 +546,7 @@ def test_gelu_is_not_expanded_in_fc2s_operand(program, monkeypatch, capsys):
 _GPT2_PROGRAMS = {
     "prefill_1024": (1, 2, 61, 2_774_016, 9_286_656),
     "prefill_128": (0, 2, 59, 2_709_504, 5_707_264),
-    "decode_32x4": (8, 2, 83, 5_709_312, 2_771_456),
+    "decode_32x4": (8, 2, 85, 5_677_056, 2_783_744),
     "chunk_32x5": (19, 0, 86, 205_663_232, 8_350_208)}
 
 
