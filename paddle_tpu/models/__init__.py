@@ -9,4 +9,5 @@ from .ernie import (ErnieConfig, ErnieModel, ErnieForPretraining,
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .generation import generate_gpt  # noqa: F401
 from .retention import RetentionConfig, RetentionForCausalLM  # noqa: F401
+from .moe import MoEConfig, MoEForCausalLM  # noqa: F401
 from .yolo import YOLOv3, DarkNetTiny, yolov3_default_anchors  # noqa: F401

@@ -11,7 +11,7 @@ stands here once, described by a `DecoderSpec`, so f32 greedy through
 any cache is the same arithmetic in the same order
 (tests/test_decoder_block.py).
 
-A `DecoderSpec` describes two blocks today. The first (its defaults):
+A `DecoderSpec` describes two trees of leaves today. The first (its defaults):
 LayerNorm with bias, learned positions, one fused qkv of equal heads,
 softmax attention, an erf-GELU 4h MLP, a head tied to the embedding;
 its tree is `generation._gpt_params`'s: `wte`, `wpe`, `lnf_w`, `lnf_b`
@@ -22,7 +22,12 @@ per-head RMSNorm on q and k, rotary positions, a SwiGLU MLP
 `retention`: a gated linear attention with the kernel `(q.k)^2`
 (`retained_attention`, the functions below it, and DESIGN.md "How a
 retention layer is served"), whose `attend` also gets the block's
-log-decay `gate` (`g_w`, `g_b`). Training's copy of the first block
+log-decay `gate` (`g_w`, `g_b`). The second tree's MLP may instead be
+`moe`, a dropless routed-expert layer (`router_w` [H, E], the experts
+stacked as `experts_gate_up_w` [E_held, H, 2 F] and `experts_down_w`
+[E_held, F, H]: "the expert layer" below); and a spec may give each
+layer its own attention window and rotary form (`windows`, `ropes`),
+which `blocks` hands to the layer. Training's copy of the first block
 (models/gpt.py::GPTBlock.forward, on the framework's Tensor ops) is
 held to this one by the same test file.
 """
@@ -31,7 +36,9 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -39,6 +46,7 @@ import jax.numpy as jnp
 from ..observability.anatomy import scope as _scope
 
 __all__ = ["DecoderSpec", "block", "blocks", "embed", "final_logits",
+           "grouped_matmul",
            "masked_attention", "prefix_mask", "causal_mask",
            "retention_features", "retained_attention", "retention_state",
            "retention_step", "retention_chunk_push",
@@ -69,9 +77,24 @@ class DecoderSpec:
     n_kv_heads: Optional[int] = None    # None: one fused qkv of n_heads
     qk_norm: bool = False               # RMSNorm of each q and k head
     rope_theta: Optional[float] = None  # None: learned positions (wpe)
-    mlp: str = "gelu"                   # | "swiglu"
+    mlp: str = "gelu"                   # | "swiglu" | "moe"
     tied_head: bool = True              # False: `head_w`
     mixer: str = "softmax"              # | "retention": attend gets a gate
+    # mlp="moe": routed experts, each a SwiGLU of `expert_width`; a
+    # token takes `top_k` of `n_experts`, its weights renormalised over
+    # them where `norm_topk`. `experts_held` is the [lo, hi) range of
+    # expert ids whose weights this block holds (None: all of them)
+    n_experts: int = 0
+    top_k: int = 0
+    expert_width: int = 0
+    norm_topk: bool = True
+    experts_held: Optional[Tuple[int, int]] = None
+    # per layer: the attention window (None: full attention; query t
+    # sees key s iff 0 <= t - s < window) and the rotary form (None:
+    # plain `rope_theta`; else YaRN's (factor, original max positions,
+    # beta_fast, beta_slow, attention factor))
+    windows: Optional[Tuple[Optional[int], ...]] = None
+    ropes: Optional[Tuple[Optional[Tuple[float, ...]], ...]] = None
 
     def __post_init__(self):
         # `block` knows the two trees of the module's header. A mix it
@@ -79,7 +102,7 @@ class DecoderSpec:
         # inside a trace
         fused = self.n_kv_heads is None
         for field, known in (("norm", ("layer", "rms")),
-                             ("mlp", ("gelu", "swiglu")),
+                             ("mlp", ("gelu", "swiglu", "moe")),
                              ("mixer", ("softmax", "retention"))):
             if getattr(self, field) not in known:
                 raise ValueError(f"DecoderSpec.{field}="
@@ -99,6 +122,31 @@ class DecoderSpec:
                 "DecoderSpec: the retention mixer's gate and the q/k norm "
                 "are leaves of the tree with separate q, k, v: set "
                 "n_kv_heads")
+        if (self.mlp == "moe") != (self.n_experts > 0):
+            raise ValueError(
+                "DecoderSpec: n_experts is the moe MLP's, and it needs "
+                f"them; got mlp={self.mlp!r}, n_experts={self.n_experts}")
+        if self.mlp == "moe":
+            lo, hi = self.held
+            if not (1 <= self.top_k <= self.n_experts
+                    and self.expert_width > 0
+                    and 0 <= lo < hi <= self.n_experts):
+                raise ValueError(
+                    f"DecoderSpec: top_k={self.top_k} of n_experts="
+                    f"{self.n_experts}, expert_width={self.expert_width}"
+                    f", experts_held={self.experts_held}: need 1 <= top_k"
+                    " <= n_experts, a width, and a range of ids inside")
+        if (self.windows is not None or self.ropes is not None) and \
+                (self.mixer != "softmax" or self.rope_theta is None):
+            raise ValueError(
+                "DecoderSpec: per-layer windows and rotary forms are the "
+                "softmax mixer's with rotary positions")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """The [lo, hi) ids of the experts this block holds."""
+        return self.experts_held or (0, self.n_experts)
+
     @classmethod
     def of(cls, config) -> "DecoderSpec":
         """The unsharded block of a model's config: a GPTConfig's, or
@@ -136,16 +184,45 @@ def _norm(spec, x, p, name):
     return _ln(x, p[name + "_w"], p[name + "_b"], spec.eps)
 
 
-def _rope_table(spec, positions):
+def _yarn_inv_freq(theta, hd, form):
+    """YaRN's frequencies (transformers' `_compute_yarn_parameters`):
+    the dimensions below the correction range (the fast ones) keep
+    their frequency, those above it are divided by `factor`, and the
+    ones inside move linearly between."""
+    factor, orig, beta_fast, beta_slow, _ = form
+    pos = theta ** (np.arange(0, hd, 2, dtype=np.float32) / hd)
+
+    def corr(rotations):
+        return hd * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), hd - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(hd // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    keep = (1.0 - ramp).astype(np.float32)
+    return (1.0 / (factor * pos)) * (1.0 - keep) + (1.0 / pos) * keep
+
+
+def _rope_table(spec, positions, form=None):
     """(cos, sin), each `positions.shape + (1, head_dim)` in f32: the
     rotate-half form, both halves of a head turning by the same
-    angles `position * theta^(-2i / head_dim)`."""
+    angles `position * theta^(-2i / head_dim)`; under YaRN's `form`
+    (`DecoderSpec.ropes`) its frequencies, cos and sin scaled by its
+    attention factor."""
     hd = spec.head_dim
-    inv = 1.0 / (spec.rope_theta
-                 ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    if form is None:
+        inv = 1.0 / (spec.rope_theta
+                     ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
+    else:
+        inv = jnp.asarray(_yarn_inv_freq(spec.rope_theta, hd, form))
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     ang = jnp.concatenate([ang, ang], axis=-1)
-    return jnp.cos(ang), jnp.sin(ang)
+    if form is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    return jnp.cos(ang) * form[4], jnp.sin(ang) * form[4]
 
 
 def _rope(x, table):
@@ -212,10 +289,13 @@ def prefix_mask(n_keys, n_valid):
             < jnp.reshape(n_valid, (-1, 1, 1, 1)))
 
 
-def causal_mask(s, prompt_lens=None):
-    """[S, S] lower triangle; with prompt_lens [B] (right-padded rows)
-    [B, 1, S, S], keys past each row's true length masked too."""
+def causal_mask(s, prompt_lens=None, window=None):
+    """[S, S] lower triangle (with a `window`, the band 0 <= t - s <
+    window); with prompt_lens [B] (right-padded rows) [B, 1, S, S],
+    keys past each row's true length masked too."""
     cm = jnp.tril(jnp.ones((s, s), bool))
+    if window is not None:
+        cm = cm & jnp.triu(jnp.ones((s, s), bool), 1 - window)
     if prompt_lens is None:
         return cm
     live = jnp.arange(s)[None, :] < prompt_lens[:, None]
@@ -226,7 +306,11 @@ def masked_attention(q, kc, vc, mask, scale):
     """Queries [B, S, N, hd] (the block's view) over keys and values
     [B, N, T, hd] (a cache's), `mask` broadcastable to [B, N, S, T];
     the softmax in f32 whatever the storage dtype. Returns
-    [B, S, N, hd]."""
+    [B, S, N, hd]. A cache of fewer key-value heads serves query head i
+    from its head i // (N / heads)."""
+    if kc.shape[1] != q.shape[2]:
+        group = q.shape[2] // kc.shape[1]
+        kc, vc = (jnp.repeat(c, group, axis=1) for c in (kc, vc))
     q = jnp.einsum("bsnh->bnsh", q)
     att = jnp.einsum("bnqh,bnkh->bnqk", q, kc) * scale
     att = jnp.where(mask, att, -1e30)
@@ -234,8 +318,64 @@ def masked_attention(q, kc, vc, mask, scale):
     return jnp.einsum("bnsh->bsnh", jnp.einsum("bnqk,bnkh->bnqh", p, vc))
 
 
-def block(spec, bp, x, attend, rope=None):
-    """One decoder block over x [B, S, H] -> (x', cache').
+def grouped_matmul(lhs, rhs, sizes, out_dtype):
+    """lhs [M, K] in consecutive groups of rows, `sizes[g]` rows of
+    group g, each times `rhs[g]` [K, N]; what the rows past the groups
+    hold is not defined. On a TPU the Pallas kernel
+    (`ops/pallas_kernels.expert_gmm`), elsewhere `lax.ragged_dot`."""
+    from ..ops import pallas_kernels as _pk
+    if _pk.pallas_available():
+        return _pk.expert_gmm(lhs, rhs, sizes, out_dtype)
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=out_dtype)
+
+
+def _experts(spec, bp, h, live):
+    """The expert layer over h [B, S, H] (ln2's output): each token's
+    router probabilities over ALL the experts (softmax in f32), its
+    top_k (ties to the lower id), renormalised; the (token, choice)
+    rows routed to the experts this block holds, sorted by expert,
+    through one grouped matmul for gate|up and one for down, no
+    capacity and no row dropped; silu(g) * u in f32, rounded once;
+    each row weighted and summed back to its token. Rows of tokens
+    not `live` ([B, S] or None) are routed nowhere. Returns (the held
+    experts' part [B, S, H] in f32, the rows routed to each expert
+    [n_experts] int32, 0 outside the held range)."""
+    b, s, hid = h.shape
+    n, k, f = b * s, spec.top_k, spec.expert_width
+    lo, hi = spec.held
+    h = h.reshape(n, hid)
+    with _scope("router"):
+        logits = jnp.matmul(h, bp["router_w"],
+                            preferred_element_type=jnp.float32)
+        w, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if spec.norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+    e, w = e.reshape(-1), w.reshape(-1)
+    held = (e >= lo) & (e < hi)
+    if live is not None:
+        held = held & jnp.repeat(live.reshape(-1), k)
+    key = jnp.where(held, e - lo, hi - lo)
+    order = jnp.argsort(key, stable=True)
+    bounds = jnp.searchsorted(key[order], jnp.arange(hi - lo + 1),
+                              side="left").astype(jnp.int32)
+    sizes = bounds[1:] - bounds[:-1]
+    gu = grouped_matmul(h[order // k], bp["experts_gate_up_w"], sizes,
+                        jnp.float32)
+    act = (jax.nn.silu(gu[:, :f]) * gu[:, f:]).astype(h.dtype)
+    y = grouped_matmul(act, bp["experts_down_w"], sizes, h.dtype)
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(n * k, dtype=order.dtype))
+    y = jnp.where(held[:, None], y[back].astype(jnp.float32), 0.0)
+    out = jnp.sum((y * jnp.where(held, w, 0.0)[:, None]).reshape(
+        n, k, hid), axis=1)
+    load = jnp.zeros((spec.n_experts,), jnp.int32).at[lo:hi].set(sizes)
+    return out.reshape(b, s, hid), load
+
+
+def block(spec, bp, x, attend, rope=None, live=None):
+    """One decoder block over x [B, S, H] -> (x', cache'), and for the
+    moe MLP (x', cache', rows routed to each expert [n_experts]).
 
     `attend(q, k, v) -> (ctx, cache')` gets [B, S, heads, head_dim]
     views of the projections (`n_kv_heads` of them for k and v where
@@ -244,7 +384,9 @@ def block(spec, bp, x, attend, rope=None):
     mixer `retention` it is `attend(q, k, v, gate)`, `gate`
     [B, S, n_kv_heads] the f32 log of each token's decay. `ctx` comes
     back in q's shape; `cache'` is passed through. `rope` is
-    `_rope_table`'s pair for x's positions (`blocks` makes it)."""
+    `_rope_table`'s pair for x's positions (`blocks` makes it).
+    `live` [B, S] marks the tokens the expert layer routes (None:
+    all)."""
     b, s, _ = x.shape
     nh, hd = spec.n_heads, spec.head_dim
     with _scope("attn"):
@@ -287,6 +429,10 @@ def block(spec, bp, x, attend, rope=None):
         x = x + proj
         if spec.bias:
             x = x + bp["proj_b"]
+    if spec.mlp == "moe":
+        with _scope("experts"):
+            ff, load = _experts(spec, bp, _norm(spec, x, bp, "ln2"), live)
+            return x + ff.astype(x.dtype), cache, load
     with _scope("mlp"):
         ff = _norm(spec, x, bp, "ln2")
         if spec.mlp == "swiglu":
@@ -303,20 +449,34 @@ def block(spec, bp, x, attend, rope=None):
     return x, cache
 
 
-def blocks(spec, params, x, caches, attend, positions=None):
+def blocks(spec, params, x, caches, attend, positions=None, live=None):
     """Every block in turn. `attend(cache, q, k, v)` is `block`'s with
     that layer's cache in front (None throughout for a prefill, which
-    has none to read). `positions` (broadcastable to x's [B, S]) are
-    what a spec with rotary positions turns q and k by."""
-    new = []
+    has none to read); where the spec gives `windows`, it also gets the
+    layer's as `window=`. `positions` (broadcastable to x's [B, S]) are
+    what a spec with rotary positions turns q and k by: one table for
+    each rotary form, made once. Returns (x, caches), and for the moe
+    MLP (x, caches, rows routed to each expert [n_layers, n_experts])
+    with `live` as `block` takes it."""
+    new, loads, tables = [], [], {}
     if caches is None:
         caches = [None] * len(params["blocks"])
-    rope = None if spec.rope_theta is None \
-        else _rope_table(spec, positions)
-    for bp, cache in zip(params["blocks"], caches):
-        x, cache = block(spec, bp, x, functools.partial(attend, cache),
-                         rope)
+    for i, (bp, cache) in enumerate(zip(params["blocks"], caches)):
+        rope = None
+        if spec.rope_theta is not None:
+            form = None if spec.ropes is None else spec.ropes[i]
+            if form not in tables:
+                tables[form] = _rope_table(spec, positions, form)
+            rope = tables[form]
+        att = functools.partial(attend, cache)
+        if spec.windows is not None:
+            att = functools.partial(att, window=spec.windows[i])
+        out = block(spec, bp, x, att, rope, live)
+        x, cache = out[:2]
         new.append(cache)
+        loads.extend(out[2:])
+    if spec.mlp == "moe":
+        return x, tuple(new), jnp.stack(loads)
     return x, tuple(new)
 
 

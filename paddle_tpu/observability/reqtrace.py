@@ -270,6 +270,13 @@ class _Step:
         self._fetch.__enter__()
         meta["ready"] = now
 
+    def count(self, **fields):
+        """Write `fields` on the open phase: what the engine counts of a
+        dispatch's fetched output (an expert model's `sync`:
+        `expert_rows`, `expert_rows_peak`, `experts_hit`,
+        serving/engine.py::expert_counts)."""
+        self._open[2].update(fields)
+
     def close(self, executables: Optional[int] = None, **counts):
         """End the step: its last phase, then the ``step`` span with
         the engine's compile count at this boundary and what else the
@@ -293,6 +300,9 @@ class _NoStep:
         pass
 
     def ready(self):
+        pass
+
+    def count(self, **fields):
         pass
 
     def close(self, executables=None, **counts):

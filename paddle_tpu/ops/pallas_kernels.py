@@ -34,7 +34,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_mha", "flash_attention_mha_sharded",
            "flash_prefill_attention", "paged_decode_attention",
-           "retention_decode", "pallas_available"]
+           "paged_decode_work", "retention_decode", "expert_gmm",
+           "pallas_available"]
 
 # Max block sizes along the q/k sequence dims. Large blocks amortize the
 # per-grid-step overhead (DMA setup + Mosaic loop) — with head_dim 64 a
@@ -551,7 +552,8 @@ _PREFILL_CHUNK = 128
 
 
 def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, scale, bq, nk, hd):
+                    acc_ref, m_ref, l_ref, *, scale, bq, nk, hd,
+                    window=None):
     """One grid step is one (query block, key block) of one prompt for
     the `g` heads that share a block of lanes: a row of q, k, v and o is
     a token with its heads side by side (the serving layout, read and
@@ -575,14 +577,22 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
     (query and key blocks are as long), and there a chunk takes the
     keys up to its own last row and no further. The accumulator,
     zeroed at a query block's first step, over a floored sum at its
-    last makes a block that never ran exactly 0."""
+    last makes a block that never ran exactly 0.
+
+    With a `window` the grid's last axis runs over the band only: its
+    `nk` steps are the key blocks i - nk + 1 .. i, those before block 0
+    skipped, and a link is live iff 0 <= t - s < window (the edge
+    blocks, and a diagonal block narrower than the window, masked)."""
     a, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    step_of_i = j
+    if window is not None:
+        j = i - (nk - 1) + step_of_i
     lb = q_ref.shape[-1]
     g = lb // hd
     chunk = min(_PREFILL_CHUNK, bq)
     head_of_lane = jax.lax.broadcasted_iota(jnp.int32, (1, lb), 1) // hd
 
-    @pl.when(j == 0)
+    @pl.when(step_of_i == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG)
@@ -604,6 +614,12 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
                 mask = (r % bq + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 0)
                     >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            if window is not None and (window < bq or not on_diagonal):
+                band = ((i - j) * bq + r % bq
+                        + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                        < window)
+                mask = band if mask is None else mask & band
             p, m_new, l_new, corr = _online_softmax(
                 s, mask, m_ref[rows, 0], l_ref[rows, 0])
             acc_ref[rows] = acc_ref[rows] * corr[:, None] + _dot(
@@ -614,9 +630,12 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
     live = i * bq < lens_ref[a]
     pl.when(live & (j == i))(functools.partial(step, True))
     if nk > 1:
-        pl.when(live & (j < i))(functools.partial(step, False))
+        below = j < i
+        if window is not None:
+            below = below & (j >= 0)
+        pl.when(live & below)(functools.partial(step, False))
 
-    @pl.when(j == nk - 1)
+    @pl.when(step_of_i == nk - 1)
     def _():
         o = acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)
         out = o[:bq]
@@ -625,13 +644,19 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def flash_prefill_attention(q, k, v, lengths, scale, interpret=False):
+@functools.partial(jax.jit, static_argnames=("scale", "window",
+                                             "interpret"))
+def flash_prefill_attention(q, k, v, lengths, scale, interpret=False,
+                            window=None):
     """Causal attention of right-padded prompts over their own K/V,
     the scores never leaving the chip.
 
-    q, k, v [A, S, nh, hd] (views of the block's one qkv result);
-    lengths [A] int32, each row's true length. Returns [A, S, nh, hd]
+    q [A, S, nh, hd], k, v [A, S, n_kv, hd] (n_kv dividing nh: query
+    head i reads key-value head i // (nh / n_kv), which needs whole
+    128-lane heads where n_kv < nh); lengths [A] int32, each row's true
+    length. With a `window`, query t sees key s iff 0 <= t - s <
+    window, and key blocks wholly outside that band get no grid step.
+    Returns [A, S, nh, hd]
     in q's dtype: query t < lengths[a] of row a attended keys 0..t,
     exactly; a query past the length holds something finite that
     nothing may read (0 where its whole block lies past the length).
@@ -647,16 +672,25 @@ def flash_prefill_attention(q, k, v, lengths, scale, interpret=False):
     once a layer traces and lowers it once."""
     a, s, nh, hd = q.shape
     d = nh * hd
+    group = nh // k.shape[2]
     lb = math.lcm(hd, 128)
     if d % lb:
         lb = d
     g = lb // hd
+    if group > 1 and g != 1:
+        raise ValueError(
+            f"flash_prefill_attention: {nh} query heads over "
+            f"{k.shape[2]} key-value heads of {hd} lanes: a grouped "
+            "read takes whole heads of a multiple of 128 lanes")
     s_p, bq = _pick_block(s, max(128, min(_PREFILL_BLOCK,
                                           _PREFILL_ROWS // g // 128 * 128)))
     nq = s_p // bq
+    # key blocks a query block reads: all up to the diagonal, or under
+    # a window the band's, ceil((window - 1) / bq) below the diagonal
+    nk = nq if window is None else min(nq, -(-(window - 1) // bq) + 1)
 
     def rows(x):
-        x = x.reshape(a, s, d)
+        x = x.reshape(a, s, x.shape[2] * hd)
         return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0))) if s_p != s else x
 
     def last_key_block(i, lens, row):
@@ -664,16 +698,24 @@ def flash_prefill_attention(q, k, v, lengths, scale, interpret=False):
         windows stay where they are and nothing is fetched for them."""
         return jnp.where(i * bq < lens[row], i, 0)
 
+    if window is None:
+        def key_block(i, j, lens, r):
+            return jnp.minimum(j, last_key_block(i, lens, r))
+    else:
+        def key_block(i, j, lens, r):
+            return jnp.clip(i - (nk - 1) + j, 0,
+                            last_key_block(i, lens, r))
+    kv_lanes = (lambda c: c) if group == 1 else (lambda c: c // group)
     q_spec = pl.BlockSpec((1, bq, lb), lambda r, c, i, j, lens: (r, i, c))
     kv_spec = pl.BlockSpec(
         (1, bq, lb), lambda r, c, i, j, lens:
-        (r, jnp.minimum(j, last_key_block(i, lens, r)), c))
+        (r, key_block(i, j, lens, r), kv_lanes(c)))
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=float(scale), bq=bq,
-                          nk=nq, hd=hd),
+                          nk=nk, hd=hd, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(a, d // lb, nq, nq),
+            grid=(a, d // lb, nq, nk),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
             scratch_shapes=[
@@ -728,7 +770,8 @@ def _dot_f32(a, b, dims, exact=False):
 
 
 def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
-                         q_ref, sel_ref, *refs, scale, bs, pps, q_exact):
+                         q_ref, sel_ref, *refs, scale, bs, pps, q_exact,
+                         hd, group=1, window=None):
     """One grid step is one round of one slot: table entries
     round*pps .. round*pps+pps-1 in the K and V windows. A page is
     [bs, nh*hd], one row a token with the heads side by side in the
@@ -749,7 +792,16 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
     whole in VMEM, f32 so that one row can be read and written at a
     dynamic sublane. The output is zeroed at the first step: the row of
     an inactive lane (length 0, no round of its own) stays 0, and the
-    one step of a call with no live lane writes nothing."""
+    one step of a call with no live lane writes nothing.
+
+    Where a page holds fewer key-value heads than there are query heads
+    (`group` query heads a K/V head, whole heads of a multiple of 128
+    lanes), `sel` [nhp, D] puts query head h on its K/V head h // group
+    and q is [B, nhp, hd]: tiled across the K/V heads' lanes, sel keeps
+    each head's own; the output is [B, nhp, hd], the picked lanes
+    summed over the K/V heads. With a `window`, a slot's rounds start
+    at the page of its position length - window, and positions under
+    it are masked."""
     del fetch_ref                               # the index maps read it
     k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pps:]
@@ -771,11 +823,22 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
 
     k = jnp.concatenate([r[0] for r in k_refs], axis=0)        # [T, D]
     v = jnp.concatenate([r[0] for r in v_refs], axis=0)
-    qh = sel * q_ref[pl.ds(slot, 1), :]                        # [nhp, D]
+    if group == 1:
+        qh = sel * q_ref[pl.ds(slot, 1), :]                    # [nhp, D]
+    else:
+        qh = sel * jnp.concatenate([q_ref[slot]] * (sel.shape[1] // hd),
+                                   axis=1)
     s = _dot_f32(qh, k, ((1,), (1,)), q_exact) * scale         # [nhp, T]
     pos = rnd * t_step + jax.lax.broadcasted_iota(
         jnp.int32, (nhp, t_step), 1)
-    s = jnp.where(pos < length, s, _NEG)
+    end = (rnd + 1) * t_step            # past this round's last position
+    if window is None:
+        s = jnp.where(pos < length, s, _NEG)
+    else:
+        first = length - window
+        base = jnp.maximum(first, 0) // bs * bs
+        pos, end = pos + base, end + base
+        s = jnp.where((pos < length) & (pos >= first), s, _NEG)
     m = m_ref[...]
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [nhp, 1]
     alpha = jnp.exp(m - m_new)
@@ -784,16 +847,23 @@ def _paged_decode_kernel(slot_ref, round_ref, fetch_ref, lengths_ref,
     l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
     acc_ref[...] = alpha * acc_ref[...] + _dot_f32(p, v, ((1,), (0,)))
 
-    @pl.when((length > 0) & ((rnd + 1) * t_step >= length))
+    @pl.when((length > 0) & (end >= length))
     def _():
-        # one head's row is non-zero in a lane, so the sums over the
-        # heads are that row's value: acc / l, element by element
-        ctx = jnp.sum(acc_ref[...] * sel, axis=0, keepdims=True)
-        norm = jnp.sum(l_ref[...] * sel, axis=0, keepdims=True)
-        o_ref[pl.ds(slot, 1), :] = ctx / norm
+        if group == 1:
+            # one head's row is non-zero in a lane, so the sums over
+            # the heads are that row's value: acc / l, element by element
+            ctx = jnp.sum(acc_ref[...] * sel, axis=0, keepdims=True)
+            norm = jnp.sum(l_ref[...] * sel, axis=0, keepdims=True)
+            o_ref[pl.ds(slot, 1), :] = ctx / norm
+        else:
+            picked = acc_ref[...] * sel
+            ctx = picked[:, :hd]
+            for kv in range(1, sel.shape[1] // hd):
+                ctx = ctx + picked[:, kv * hd:(kv + 1) * hd]
+            o_ref[slot] = ctx / l_ref[...]
 
 
-def _decode_rounds(tables, lengths, bs, pps):
+def _decode_rounds(tables, lengths, bs, pps, window=None):
     """The kernel's work list, from the tables and lengths alone (the
     same for every layer of a token-step). A lane whose first table
     entry is the scratch page 0 is inactive: it is taken to hold
@@ -806,7 +876,9 @@ def _decode_rounds(tables, lengths, bs, pps):
     live (it holds a position under the slot's length), elsewhere the
     page the same window held the round before, so the pipeline, which
     copies only when a window's index changes, fetches live pages and
-    nothing else (the first round may fetch pages it does not need)."""
+    nothing else (the first round may fetch pages it does not need).
+    With a `window` a slot's rounds start at the page that holds its
+    position length - window."""
     b, w = tables.shape
     most = b * (w // pps)
     # each row's first entry, by a reduction along the row: a column
@@ -814,13 +886,18 @@ def _decode_rounds(tables, lengths, bs, pps):
     first = jnp.max(jnp.where(jnp.arange(w) == 0, tables, 0), axis=1)
     lengths = jnp.where(first == 0, 0, lengths)
     pages = -(-lengths // bs)
-    rounds = -(-pages // pps)
+    start = 0
+    if window is not None:
+        start = jnp.maximum(lengths - window, 0) // bs
+    rounds = -(-(pages - start) // pps)
     ends = jnp.cumsum(rounds)
     at = jnp.arange(most, dtype=jnp.int32)
     slot = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1),
                        b - 1).astype(jnp.int32)
     rnd = at - (ends - rounds)[slot]
     col = rnd[:, None] * pps + jnp.arange(pps, dtype=jnp.int32)[None, :]
+    if window is not None:
+        col = col + start[slot][:, None]
     live = (col < pages[slot][:, None]) & (at < ends[-1])[:, None]
     last = jax.lax.cummax(jnp.where(live, at[:, None], 0), axis=0)
     entry = tables[slot[:, None], jnp.minimum(col, w - 1)]
@@ -829,13 +906,29 @@ def _decode_rounds(tables, lengths, bs, pps):
             fetch.reshape(-1))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def paged_decode_work(tables, lengths, block_size, window=None):
+    """The decode kernel's work list (`_decode_rounds`) for one token-step
+    and one kind of layer: every layer of that kind reads the same
+    pages, so a program makes it once a kind and hands it to each call
+    of `paged_decode_attention` as `work`."""
+    pps = _PAGES_PER_STEP
+    tables = tables.astype(jnp.int32)
+    pad = -tables.shape[1] % pps
+    if pad:
+        tables = jnp.pad(tables, ((0, 0), (0, pad)))
+    return _decode_rounds(tables, lengths.astype(jnp.int32), block_size,
+                          pps, window)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window",
+                                             "interpret"))
 def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
-                           interpret=False):
+                           interpret=False, window=None, work=None):
     """Single-token attention over a paged K/V cache, read in place.
 
-    q [B, nh, hd]; k_pool, v_pool [n_blocks, bs, nh*hd] (one row a
-    token, heads side by side: the shape whose default device layout
+    q [B, nh, hd]; k_pool, v_pool [n_blocks, bs, n_kv*hd] (n_kv = nh,
+    or fewer key-value heads, each read by nh / n_kv query heads; one
+    row a token, heads side by side: the shape whose default device layout
     is the row-major one that the serving programs write, so nothing
     converts a pool around the call); tables [B, W] int32 (table order
     is logical order); lengths [B] int32: slot b attends positions
@@ -846,60 +939,71 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lengths, scale,
     that hold a live position are read, and the grid has as many steps
     as the live slots hold rounds of pages (one, if none is live), so
     the work grows with the tokens held and not with the table's width
-    or the lanes. f32 scores, softmax statistics and
-    accumulation; the result is rounded once, here. `interpret=True`
+    or the lanes. With a `window`, slot b attends positions
+    lengths[b]-window .. lengths[b]-1 and reads their pages only.
+    `work` is `paged_decode_work(tables, lengths, bs, window)` where
+    the caller made it (None: made here). f32 scores, softmax
+    statistics and accumulation; the result is rounded once, here.
+    `interpret=True`
     runs the kernel under the Pallas interpreter (the CPU suite).
     Jitted, so that a program that calls it once a layer traces and
     lowers it once: 36 separate calls added 4-6 s to every process's
     set-up."""
     b, nh, hd = q.shape
     bs, d = k_pool.shape[1:]
+    group = nh * hd // d
     pps = _PAGES_PER_STEP
-    tables = tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    pad = -tables.shape[1] % pps
-    if pad:
-        tables = jnp.pad(tables, ((0, 0), (0, pad)))
-    lengths, n_rounds, slot, rnd, fetch = _decode_rounds(tables, lengths,
-                                                         bs, pps)
+    if work is None:
+        work = paged_decode_work(tables, lengths, bs, window)
+    lengths, n_rounds, slot, rnd, fetch = work
     # heads padded to whole 16-bit sublane tiles; the padding's rows of
     # `sel` are zero, so they score 0 everywhere and are never picked
     nhp = _ceil_to(nh, 16)
-    sel = (jnp.arange(d)[None, :] // hd
-           == jnp.arange(nhp)[:, None]).astype(jnp.float32)
+    heads = jnp.arange(nhp)[:, None]
+    if group > 1:
+        heads = heads // group
+    sel = (jnp.arange(d)[None, :] // hd == heads).astype(jnp.float32)
+    if group == 1:
+        q_in, out_rows = q.astype(jnp.float32).reshape(b, d), (b, d)
+    else:
+        q_in = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, nhp - nh), (0, 0)))
+        out_rows = (b, nhp, hd)
 
     def page(i):
         return pl.BlockSpec(
             (1, bs, d),
             lambda t, slot, rnd, fetch, lens: (fetch[t * pps + i], 0, 0))
 
-    def whole(rows):
-        return pl.BlockSpec((rows, d), lambda t, *_: (0, 0))
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda t, *_: (0,) * len(shape))
 
     kern = functools.partial(_paged_decode_kernel, scale=float(scale),
                              bs=bs, pps=pps,
-                             q_exact=q.dtype == k_pool.dtype)
+                             q_exact=q.dtype == k_pool.dtype, hd=hd,
+                             group=group, window=window)
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(n_rounds,),
-            in_specs=[whole(b), whole(nhp)]
+            in_specs=[whole(out_rows), whole((nhp, d))]
             + [page(i) for i in range(pps)] * 2,
-            out_specs=whole(b),
+            out_specs=whole(out_rows),
             scratch_shapes=[
                 pltpu.VMEM((nhp, 1), jnp.float32),
                 pltpu.VMEM((nhp, 1), jnp.float32),
                 pltpu.VMEM((nhp, d), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(out_rows, jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(slot, rnd, fetch, lengths, q.astype(jnp.float32).reshape(b, d), sel,
+    )(slot, rnd, fetch, lengths, q_in, sel,
       *([k_pool] * pps), *([v_pool] * pps))
-    return out.astype(k_pool.dtype).reshape(b, nh, hd)
+    if group == 1:
+        return out.astype(k_pool.dtype).reshape(b, nh, hd)
+    return out[:, :nh].astype(k_pool.dtype)
 
 
 # -- retention decode: one pass over a lane's state row -----------------------
@@ -1097,3 +1201,33 @@ def retention_decode(state, rows, q, keys, vals, decay, weights, write,
     )(rows.astype(jnp.int32), jnp.asarray(write, jnp.int32).reshape(1),
       x, s_all, z_all)
     return y[:, :, :grp], y[:, :, 7, :grp], (s_all, z_all)
+
+
+# -- the expert layer's grouped matmul -----------------------------------------
+
+def _gmm_tile(n: int, most: int) -> int:
+    """The largest multiple of 128 that divides n and is at most
+    `most` (n itself where no such multiple exists)."""
+    for t in range(most // 128 * 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def expert_gmm(lhs, rhs, sizes, out_dtype, interpret=False):
+    """Rows of lhs [M, K] in groups sorted by expert, group g's
+    `sizes[g]` rows times rhs[g] [K, N], f32 accumulation, out_dtype
+    out; rows past the groups are not computed. JAX's megablox kernel
+    (`jax.experimental.pallas.ops.tpu.megablox.gmm`, `gmm_*` in a
+    device trace): a grid step is one row tile of one group, so an
+    expert's weights are read once a row tile it has rows in, and a
+    group's rows ride the tiles of their neighbours. Row tiles of 512
+    for a prefill's many rows (each expert sees hundreds), of 32 for a
+    decode step's few."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    tm = 512 if m >= 8192 and m % 512 == 0 else 32 if m % 32 == 0 else m
+    tiling = (tm, _gmm_tile(k, 1024), _gmm_tile(n, 1024))
+    return gmm(lhs, rhs, sizes.astype(jnp.int32), out_dtype, tiling,
+               interpret=interpret)

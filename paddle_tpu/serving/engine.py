@@ -281,11 +281,41 @@ def _refuse_for_retention(cfg):
             "quant.")
 
 
+def _refuse_for_experts(cfg):
+    """What the engine cannot do for a decoder whose MLP is routed
+    experts, each with its reason."""
+    if cfg.tp > 1:
+        raise ValueError(
+            "a tp plan is not supported for an expert model: "
+            "SERVING_TP_RULES has no expert axis and knows neither the "
+            "router's nor the stacked experts' leaves, and the pools of "
+            "grouped key-value heads are not sharded. Drop the plan.")
+    if cfg.quant is not None:
+        raise ValueError(
+            "quant is not supported for an expert model: the int8 "
+            "snapshot (QUANT_WEIGHT_KEYS) knows the fused-qkv block's "
+            "matmuls only, not the router's or the stacked experts'. "
+            "Drop quant.")
+
+
+def expert_counts(load) -> dict:
+    """What a traced `sync` carries of a dispatch's expert load
+    ([2, n_layers, n_experts]: the rows routed to each expert, and the
+    token-steps in which it got any): the rows in all, the sum over
+    layers of the busiest expert's rows, and the sum over layers and
+    token-steps of the experts that got any (whose weights were
+    read)."""
+    rows, hit = load
+    return {"expert_rows": int(rows.sum()),
+            "expert_rows_peak": int(rows.max(axis=1).sum()),
+            "experts_hit": int(hit.sum())}
+
+
 class ServingEngine:
     """Continuous-batching serving over one decoder LM: a
     GPTForCausalLM, or a model that describes its own block
     (``config.decoder_spec()``, ``decoder_params()``:
-    models/retention.py).
+    models/retention.py, models/moe.py).
 
     ``draft_model`` (required iff ``config.speculative_k >= 1``): the
     small proposer — any GPTForCausalLM over the same vocab; its own
@@ -297,9 +327,15 @@ class ServingEngine:
         self.config = cfg = config or ServingConfig()
         own = hasattr(model, "decoder_params")
         mcfg = model.config if own else model.gpt.config
-        retention = DecoderSpec.of(mcfg).mixer == "retention"
+        whole = DecoderSpec.of(mcfg)
+        retention = whole.mixer == "retention"
         if retention:
             _refuse_for_retention(cfg)
+        # an expert model's programs return, last, the rows routed to
+        # each expert: a traced step's `sync` counts them
+        self._expert_rows = whole.mlp == "moe"
+        if self._expert_rows:
+            _refuse_for_experts(cfg)
         spec = serving_decoder_spec(mcfg, cfg)
         if cfg.max_total_tokens > mcfg.max_seq_len:
             raise ValueError(
@@ -346,10 +382,12 @@ class ServingEngine:
                 n_layers=int(mcfg.num_layers), n_rows=cfg.n_blocks,
                 n_kv_heads=spec.n_kv_heads, head_dim=spec.head_dim)
         else:
+            # a page's row is as wide as the key-value heads
             self.cache = PagedKVCache(
                 n_layers=int(mcfg.num_layers), n_blocks=cfg.n_blocks,
-                block_size=cfg.block_size, n_heads=self.n_heads,
-                head_dim=int(mcfg.hidden_size) // self.n_heads,
+                block_size=cfg.block_size,
+                n_heads=whole.n_kv_heads or whole.n_heads,
+                head_dim=whole.head_dim,
                 dtype=pool_dtype, prefix_sharing=cfg.prefix_sharing,
                 pool_sharding=pool_sharding, tp=self.tp)
         self.ladder = BucketLadder(cfg.prefill_buckets,
@@ -520,28 +558,28 @@ class ServingEngine:
             # (starts=0 on a full miss IS a dense prefill, junk routed
             # to scratch instead of page-scattered); plus the COW copy
             for s in self.ladder.prefill:
-                self.cache.pools, _, _ = self._chunk(
+                self.cache.pools = self._chunk(
                     self.cache.pools, dummy(a),
                     np.zeros((a, s), np.int32),
                     np.zeros((a,), np.int32), np.ones((a,), np.int32),
-                    self.params, key)
+                    self.params, key)[0]
             self.cache.warm_copy()
         else:
             for s in self.ladder.prefill:
-                self.cache.pools, _ = self._prefill(
+                self.cache.pools = self._prefill(
                     self.cache.pools, dummy(a),
                     np.zeros((a, s), np.int32),
-                    np.ones((a,), np.int32), self.params, key)
+                    np.ones((a,), np.int32), self.params, key)[0]
         if self._spec_k:
             # speculation replaces the plain decode with the draft's
             # prefill + k-proposal scan and the target's [b, k+1]
             # chunk verify, per decode bucket
             for b in self.ladder.decode:
-                self.cache.pools, _, _ = self._chunk(
+                self.cache.pools = self._chunk(
                     self.cache.pools, dummy(b),
                     np.zeros((b, self._spec_k + 1), np.int32),
                     np.zeros((b,), np.int32), np.ones((b,), np.int32),
-                    self.params, key)
+                    self.params, key)[0]
             for s in self.ladder.prefill:
                 self.draft_cache.pools, _ = self._draft_prefill(
                     self.draft_cache.pools, np.zeros((a, W), np.int32),
@@ -554,10 +592,10 @@ class ServingEngine:
                     self.draft_params, key)
         else:
             for b in self.ladder.decode:
-                self.cache.pools, _ = self._decode(
+                self.cache.pools = self._decode(
                     self.cache.pools, dummy(b),
                     np.zeros((b,), np.int32), np.zeros((b,), np.int32),
-                    self.params, key)
+                    self.params, key)[0]
         self.sentinel.observe(self.executable_count(),
                               expected=self.expected_executables,
                               signature=self._shape_signature(None, None))
@@ -678,9 +716,13 @@ class ServingEngine:
         # waiting for the buffer adds no round trip: `ready` splits the
         # phase into the device's work and the copy to the host
         res = out[take]
-        res.copy_to_host_async()
+        fetched = (res,) + ((out[-1],) if self._expert_rows else ())
+        for a in fetched:
+            a.copy_to_host_async()
         res.block_until_ready()
         tr.ready()
+        if self._expert_rows:
+            tr.count(**expert_counts(np.asarray(out[-1])))
         return np.asarray(res)
 
     def _pack_prompts(self, batch, bucket: int, skip=None):

@@ -10,7 +10,9 @@ into fixed-size PAGES:
 
 - device side: per layer, one K pool and one V pool of shape
   ``[n_blocks, block_size, n_heads * head_dim]`` — one row a token,
-  heads side by side in the lanes — allocated once at engine build,
+  the KEY-VALUE heads side by side in the lanes (`n_heads` is the
+  model's key-value heads: fewer than its query heads where groups of
+  them share one) — allocated once at engine build,
   donated through every compiled prefill/decode call so XLA updates
   the pages in place (graph_lint's donation rule proves the aliasing).
   Heads and head size are merged because of the TPU's default layouts:

@@ -32,8 +32,10 @@ own K/V rows in place (no `[A, heads, S, S]` scores, mask or
 probabilities in HBM), and `masked_attention` under the dense causal
 mask elsewhere (tests/test_flash_prefill_attention.py).
 
-A pool is ``[n_blocks, block_size, n_heads * head_dim]``, one row a
-token with the heads side by side (the shape whose default TPU layout
+A pool is ``[n_blocks, block_size, n_kv_heads * head_dim]`` (the
+key-value heads: as many as the query heads where q, k and v are fused,
+fewer where groups of query heads share one), one row a token with the
+heads side by side (the shape whose default TPU layout
 is the row-major one the scatters and the kernel address:
 paged_cache.py); logical position ``p`` of a request lives in page
 ``table[p // block_size]`` at offset ``p % block_size``. The programs
@@ -82,6 +84,21 @@ __all__ = ["make_decode_fn", "make_prefill_fn", "make_chunk_fn",
            "jit_with_donated_pools", "jit_tp_with_donated_pools"]
 
 
+def _kv_heads(spec) -> int:
+    """Key-value heads of a pool's row: the spec's, or one a query head
+    where q, k and v are fused."""
+    return spec.n_kv_heads or spec.n_heads
+
+
+def _live_lanes(tables):
+    """[B] bool: a lane holds a request iff its table's first entry is
+    not the scratch page 0 (read by a reduction along the row, as the
+    decode kernel reads it)."""
+    first = jnp.max(jnp.where(jnp.arange(tables.shape[1]) == 0, tables, 0),
+                    axis=1)
+    return first != 0
+
+
 def _gathered(pool, tables, n_heads, hd):
     """Pages -> contiguous logical cache: [n_blocks, bs, nh*hd]
     gathered by [B, W] tables into [B, nh, W*bs, hd] (table order IS
@@ -104,7 +121,7 @@ def _written(pools, index, shape, k, v):
 def _gather_attention(spec, pools, tables, q, mask):
     """The portable paged attention: every slot's whole table gathered
     into a contiguous cache, then `masked_attention`."""
-    kc, vc = (_gathered(p, tables, spec.n_heads, spec.head_dim)
+    kc, vc = (_gathered(p, tables, _kv_heads(spec), spec.head_dim)
               for p in pools)
     return decoder.masked_attention(q, kc, vc, mask, spec.scale)
 
@@ -114,7 +131,8 @@ def _decode_addressing(spec, block_size, tables, positions):
     slot i's table and its query attends over the `positions[i] + 1`
     rows the slot then holds — on a TPU through the paged kernel,
     elsewhere through the gather, the reference the kernel is tested
-    against."""
+    against. A layer with a `window` attends the last `window` of
+    them; the kernel's work list is made once a kind of layer."""
     from ..ops import pallas_kernels as _pk
     on_tpu = _pk.pallas_available()
     bi = jnp.arange(tables.shape[0])
@@ -122,14 +140,30 @@ def _decode_addressing(spec, block_size, tables, positions):
           positions % block_size)                        # row, [B]
     mask = decoder.prefix_mask(tables.shape[1] * block_size,
                                positions + 1)
+    works = {}
 
-    def attend(pools, q, k, v):
+    def attend(pools, q, k, v, window=None):
         pools = _written(pools, at, at[0].shape + (-1,), k, v)
-        if on_tpu:
+        if on_tpu and spec.windows is None:
+            # a stack without windows keeps the program it had: the
+            # kernel makes its own work list (one list handed to every
+            # layer compiles GPT-2's decode program to other
+            # temporaries, 5.2 MB for 5.7 at 2 layers)
             return _pk.paged_decode_attention(
                 q[:, 0], *pools, tables, positions + 1,
                 spec.scale)[:, None], pools
-        return _gather_attention(spec, pools, tables, q, mask), pools
+        if on_tpu:
+            if window not in works:
+                works[window] = _pk.paged_decode_work(
+                    tables, positions + 1, block_size, window)
+            return _pk.paged_decode_attention(
+                q[:, 0], *pools, tables, positions + 1, spec.scale,
+                window=window, work=works[window])[:, None], pools
+        live = mask
+        if window is not None:
+            live = live & (jnp.arange(mask.shape[-1])[None, None, None, :]
+                           > positions[:, None, None, None] - window)
+        return _gather_attention(spec, pools, tables, q, live), pools
 
     return attend
 
@@ -144,16 +178,19 @@ def _prefill_addressing(spec, block_size, tables, s, prompt_lens):
     S / block_size pages of each row's table."""
     from ..ops import pallas_kernels as _pk
     on_tpu = _pk.pallas_available()
-    mask = None if on_tpu else decoder.causal_mask(s, prompt_lens)
+    masks = {None: None if on_tpu else decoder.causal_mask(s, prompt_lens)}
 
-    def attend(pools, q, k, v):
+    def attend(pools, q, k, v, window=None):
         if on_tpu:
             ctx = _pk.flash_prefill_attention(q, k, v, prompt_lens,
-                                              spec.scale)
+                                              spec.scale, window=window)
         else:
+            if window not in masks:
+                masks[window] = decoder.causal_mask(s, prompt_lens, window)
             kc = jnp.einsum("bsnh->bnsh", k)
             vc = jnp.einsum("bsnh->bnsh", v)
-            ctx = decoder.masked_attention(q, kc, vc, mask, spec.scale)
+            ctx = decoder.masked_attention(q, kc, vc, masks[window],
+                                           spec.scale)
         nblk = s // block_size
         return ctx, _written(pools, tables[:, :nblk],
                              (k.shape[0], nblk, block_size, -1), k, v)
@@ -178,9 +215,13 @@ def _chunk_addressing(spec, block_size, tables, positions, valid):
     keys = jnp.arange(tables.shape[1] * block_size)
     mask = keys[None, None, None, :] <= positions[:, None, :, None]
 
-    def attend(pools, q, k, v):
+    def attend(pools, q, k, v, window=None):
         pools = _written(pools, at, at[0].shape + (-1,), k, v)
-        return _gather_attention(spec, pools, tables, q, mask), pools
+        live = mask
+        if window is not None:
+            live = live & (keys[None, None, None, :]
+                           > positions[:, None, :, None] - window)
+        return _gather_attention(spec, pools, tables, q, live), pools
 
     return attend
 
@@ -230,7 +271,7 @@ def _retention_decode_run(spec, step, n_steps):
             caches, toks, positions = carry
             step_key, t = xs
             at = t % span
-            caches, tok = step(
+            caches, tok, _ = step(
                 caches, lambda: _retention_decode_addressing(
                     rows, at, (at == span - 1) | (t == n_steps - 1)),
                 toks, positions, params, step_key)
@@ -290,36 +331,63 @@ def make_decode_fn(spec, block_size: int, sampling, n_steps: int = 1):
 
     A retention decoder's program is `_retention_decode_run`: its rows
     are read every token-step and written once a chunk.
+
+    Where the spec's MLP is `moe`, every program here also returns, last,
+    what its live tokens routed (`_expert_load`): int32 [2, n_layers,
+    n_experts], the rows each expert got and the token-steps in which
+    it got any (one for a prefill), a decode dispatch's summed over its
+    token-steps; fetched with the tokens, no sync of its own.
     """
 
-    def step(pools, address, toks, positions, params, key):
-        """One token-step; `address()` gives the layers' `attend`."""
+    def step(pools, address, toks, positions, params, key, live=None):
+        """One token-step; `address()` gives the layers' `attend`.
+        Returns (pools', tokens, () or (expert rows,))."""
         with _scope("embed"):
             x = decoder.embed(params, toks, positions)[:, None]
-        x, pools = decoder.blocks(spec, params, x, pools, address(),
-                                  positions[:, None])
+        out = decoder.blocks(spec, params, x, pools, address(),
+                             positions[:, None], live)
+        x, pools = out[:2]
         with _scope("lm_head"):
             tok = _pick(decoder.final_logits(spec, params, x)[:, 0], key,
                         *sampling)
-        return pools, tok
+        return pools, tok, out[2:]
 
     if spec.mixer == "retention":
         return _retention_decode_run(spec, step, n_steps)
 
     def run(pools, tables, toks, positions, params, key):
+        live = _live_lanes(tables)[:, None] if spec.mlp == "moe" else None
+
         def body(carry, step_key):
-            pools, toks, positions = carry
-            pools, tok = step(
+            pools, toks, positions = carry[:3]
+            pools, tok, load = step(
                 pools, lambda: _decode_addressing(spec, block_size, tables,
                                                   positions),
-                toks, positions, params, step_key)
-            return (pools, tok, positions + 1), tok
+                toks, positions, params, step_key, live)
+            return (pools, tok, positions + 1) + tuple(
+                a + _expert_load(b) for a, b in zip(carry[3:], load)), tok
         keys = jax.random.split(key, n_steps)
-        (pools, _, _), out = jax.lax.scan(
-            body, (pools, toks, positions), keys)
-        return pools, out                              # [n_steps, B]
+        carry, out = jax.lax.scan(
+            body, (pools, toks, positions) + _no_load(spec, params), keys)
+        return (carry[0], out) + carry[3:]             # [n_steps, B]
 
     return run
+
+
+def _expert_load(rows):
+    """[2, n_layers, n_experts] of one token-step's rows routed to each
+    expert: the rows, and 1 where an expert got any (its weights are
+    read)."""
+    return jnp.stack([rows, (rows > 0).astype(rows.dtype)])
+
+
+def _no_load(spec, params):
+    """The expert load's running sum a decode dispatch starts from: ()
+    where the MLP has no experts."""
+    if spec.mlp != "moe":
+        return ()
+    return (jnp.zeros((2, len(params["blocks"]), spec.n_experts),
+                      jnp.int32),)
 
 
 def make_prefill_fn(spec, block_size: int, sampling):
@@ -347,14 +415,19 @@ def make_prefill_fn(spec, block_size: int, sampling):
         else:
             attend = _prefill_addressing(spec, block_size, tables, s,
                                          prompt_lens)
-        x, pools = decoder.blocks(spec, params, x, pools, attend,
-                                  jnp.arange(s))
+        live = None
+        if spec.mlp == "moe":
+            live = (jnp.arange(s)[None, :] < prompt_lens[:, None]) \
+                & _live_lanes(tables)[:, None]
+        out = decoder.blocks(spec, params, x, pools, attend,
+                             jnp.arange(s), live)
+        x, pools = out[:2]
         with _scope("lm_head"):
             idx = (prompt_lens - 1).astype(jnp.int32)
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
             tok = _pick(decoder.final_logits(spec, params, last)[:, 0],
                         key, *sampling)
-        return pools, tok
+        return (pools, tok) + tuple(_expert_load(r) for r in out[2:])
 
     return run
 
@@ -391,12 +464,18 @@ def make_chunk_fn(spec, block_size: int, sampling):
         positions = starts[:, None] + offs[None, :]        # [B, S]
         valid = offs[None, :] < lens[:, None]              # [B, S]
         with _scope("embed"):
-            n_pos = params["wpe"].shape[0]
-            x = decoder.embed(params, toks,
-                              jnp.clip(positions, 0, n_pos - 1))
-        x, pools = decoder.blocks(
+            if "wpe" in params:
+                n_pos = params["wpe"].shape[0]
+                x = decoder.embed(params, toks,
+                                  jnp.clip(positions, 0, n_pos - 1))
+            else:
+                x = decoder.embed(params, toks, positions)
+        out = decoder.blocks(
             spec, params, x, pools, _chunk_addressing(
-                spec, block_size, tables, positions, valid))
+                spec, block_size, tables, positions, valid), positions,
+            valid & _live_lanes(tables)[:, None]
+            if spec.mlp == "moe" else None)
+        x, pools = out[:2]
         with _scope("lm_head"):
             logits = decoder.final_logits(spec, params, x)  # [B, S, V]
             all_tok = jnp.argmax(logits.astype(jnp.float32),
@@ -405,7 +484,8 @@ def make_chunk_fn(spec, block_size: int, sampling):
             last = jnp.take_along_axis(
                 logits, idx[:, None, None], axis=1)[:, 0]  # [B, V]
             picked = _pick(last, key, *sampling)
-        return pools, all_tok, picked
+        return (pools, all_tok, picked) + tuple(_expert_load(r)
+                                                for r in out[2:])
 
     return run
 

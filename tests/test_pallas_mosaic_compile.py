@@ -757,3 +757,85 @@ def test_retention_programs_keep_their_state_in_place(program, monkeypatch,
               f"{mem.temp_size_in_bytes / 1e6:.1f} MB, arguments "
               f"{mem.argument_size_in_bytes / 1e9:.2f} GB")
     assert mem.temp_size_in_bytes < 400e6
+
+
+# ------------------------------------------- the expert model's programs
+
+# the code cell's engine shape (perfbench/configs/mellum2-12b-a2.5b.json):
+# 11,085 pages of [16, 4 x 128], 32 lanes, tables of 326 pages, 2 prompts
+# a prefill; 32 query heads over 4 K/V heads, 64 experts of 896
+M_PAGES, M_KV, M_HEADS, M_WIDTH, M_TABLE = 11085, 4, 32, 128, 326
+M_HIDDEN, M_EXPERTS, M_FFN, M_VOCAB = 2304, 64, 896, 98304
+
+
+def _moe_spec(n_layers):
+    """Mellum2's block at published widths, layers [sliding, full]."""
+    from paddle_tpu.models import MoEConfig
+    return MoEConfig(
+        num_layers=n_layers,
+        layer_types=["sliding_attention", "full_attention"][:n_layers],
+        rope_scaling={"factor": 16, "original_max_position_embeddings": 8192,
+                      "beta_fast": 32, "beta_slow": 1,
+                      "attention_factor": 1.2772588722239782}
+    ).decoder_spec()
+
+
+def _moe_avals(n_layers, one):
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    h, d = M_HIDDEN, M_HEADS * M_WIDTH
+    block = {"ln1_w": s((h,)), "q_w": s((h, d)),
+             "k_w": s((h, M_KV * M_WIDTH)), "v_w": s((h, M_KV * M_WIDTH)),
+             "proj_w": s((d, h)), "qn_w": s((M_WIDTH,)),
+             "kn_w": s((M_WIDTH,)), "ln2_w": s((h,)),
+             "router_w": s((h, M_EXPERTS)),
+             "experts_gate_up_w": s((M_EXPERTS, h, 2 * M_FFN)),
+             "experts_down_w": s((M_EXPERTS, M_FFN, h))}
+    params = {"wte": s((M_VOCAB, h)), "lnf_w": s((h,)),
+              "head_w": s((h, M_VOCAB)), "blocks": [block] * n_layers}
+    pool = s((M_PAGES, PAGE, M_KV * M_WIDTH))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    return (((pool, pool),) * n_layers, params,
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=one))
+
+
+@pytest.mark.parametrize("program", ["decode_32x4", "prefill_2x2048"])
+def test_moe_programs_compile_for_one_v5e_chip(program, monkeypatch,
+                                               capsys):
+    """The expert model's decode (32 lanes x 4 token-steps) and prefill
+    (2 x 2,048) programs at the published widths, a sliding and a full
+    layer, the kernels chosen as on a TPU: three Mosaic calls a layer
+    (the attention kernel and the two grouped matmuls), the pools of 4
+    K/V heads (512 lanes) neither copied nor converted, the expert load
+    returned beside the tokens, temporaries printed and held under 2 GB
+    (a prefill's 32,768 expert rows: their gate|up in f32 is 235 MB)."""
+    from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_decode_fn,
+                                             make_prefill_fn)
+    n_layers = 2
+    one = SingleDeviceSharding(_v5e_devices()[0])
+    monkeypatch.setattr(pk, "pallas_available", lambda: True)
+    pools, params, key = _moe_avals(n_layers, one)
+    s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one)
+    spec = _moe_spec(n_layers)
+    with _engine_precision():
+        if program == "decode_32x4":
+            fn = jit_with_donated_pools(make_decode_fn(spec, PAGE, _GREEDY,
+                                                       n_steps=4))
+            args = (pools, s32(SLOTS, M_TABLE), s32(SLOTS), s32(SLOTS))
+        else:
+            fn = jit_with_donated_pools(make_prefill_fn(spec, PAGE,
+                                                        _GREEDY))
+            args = (pools, s32(2, M_TABLE), s32(2, 2048), s32(2))
+        traced = fn.trace(*args, params, key)
+        compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    assert traced.out_info[-1].shape == (2, n_layers, M_EXPERTS)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3 * n_layers
+    assert not _pool_copies(text, M_PAGES, M_KV * M_WIDTH)
+    mem = compiled.memory_analysis()
+    with capsys.disabled():
+        print(f"\nexpert model {program}, {n_layers} layers: temporaries "
+              f"{mem.temp_size_in_bytes / 1e6:.1f} MB")
+    assert mem.temp_size_in_bytes < 2e9
