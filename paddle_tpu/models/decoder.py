@@ -47,7 +47,7 @@ from ..observability.anatomy import scope as _scope
 
 __all__ = ["DecoderSpec", "block", "blocks", "embed", "final_logits",
            "grouped_matmul",
-           "masked_attention", "prefix_mask", "causal_mask",
+           "masked_attention", "prefix_mask", "causal_mask", "packed_mask",
            "retention_features", "retained_attention", "retention_state",
            "retention_step", "retention_chunk_push",
            "retention_chunk_weights", "retention_pass",
@@ -300,6 +300,18 @@ def causal_mask(s, prompt_lens=None, window=None):
         return cm
     live = jnp.arange(s)[None, :] < prompt_lens[:, None]
     return cm[None, None] & live[:, None, None, :]
+
+
+def packed_mask(s, starts, window=None):
+    """[1, 1, S, S] over one row of prompts laid end to end, prompt p
+    from `starts[p]` (ascending, from 0) to the next start: query t
+    sees key s iff both are of one prompt and s <= t (with a `window`,
+    t - s < window besides). Rows after the last start are the last
+    prompt's."""
+    at = jnp.arange(s)
+    prompt = jnp.sum(at[:, None] >= starts[None, 1:], axis=1)
+    return (causal_mask(s, window=window)
+            & (prompt[:, None] == prompt[None, :]))[None, None]
 
 
 def masked_attention(q, kc, vc, mask, scale):

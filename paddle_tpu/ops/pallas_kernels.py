@@ -551,10 +551,18 @@ _PREFILL_ROWS = 1024
 _PREFILL_CHUNK = 128
 
 
-def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, scale, bq, nk, hd,
-                    window=None):
-    """One grid step is one (query block, key block) of one prompt for
+def _segment_start(starts, row):
+    """Where the prompt that holds `row` (a scalar, an array of rows)
+    starts: the last of a packed row's ascending prompt starts at or
+    before it (`starts` a list of scalars or an array)."""
+    start = starts[0]
+    for p in range(1, len(starts)):
+        start = jnp.where(row >= starts[p], starts[p], start)
+    return start
+
+
+def _prefill_kernel(*refs, scale, bq, nk, hd, n_seg, window=None):
+    """One grid step is one (query block, key block) of one row for
     the `g` heads that share a block of lanes: a row of q, k, v and o is
     a token with its heads side by side (the serving layout, read and
     written in place), and a step's window is [bq, g * hd] lanes of it
@@ -570,23 +578,39 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
     q (exact where it is a power of two, as 1/8 is for a head of 64;
     one more rounding of q elsewhere).
 
-    `lens` decides what is skipped, never what is masked: a true query
-    sees only keys at or before it, which are true too. A step runs
-    iff it is on or under the diagonal and its query block starts
-    before the prompt's end. Only a diagonal block has links to mask
-    (query and key blocks are as long), and there a chunk takes the
-    keys up to its own last row and no further. The accumulator,
-    zeroed at a query block's first step, over a floored sum at its
-    last makes a block that never ran exactly 0.
+    The grid's last axis runs over a band of `nk` key blocks, i - nk + 1
+    .. i, those before block 0 skipped. `lens` decides what is skipped,
+    never what is masked: a step runs iff its query block starts before
+    the row's end. A row is one prompt, or (`n_seg` > 0) one packed row
+    of `n_seg` prompts laid end to end, whose query sees the keys of its
+    own prompt only: there `blocks` holds, per query block, the first
+    key block it reads (those before it end before the block's first
+    prompt starts, and are skipped as wholly masked) and the first key
+    block that no prompt of the query block starts after (a key block
+    before it holds keys of a prompt before some query's own). Two
+    bodies: the masked one runs on the diagonal and, in a packed row,
+    on the key blocks before that bound; every other step takes all its
+    keys unmasked. On the diagonal of a one-prompt row a chunk takes
+    the keys up to its own last row and no further, the chunks
+    unrolled (`step`). In a packed row every chunk takes the whole key
+    block, a link live iff its key lies between the start of the
+    query's prompt and the query, one more comparison than the causal
+    one; the chunks then go through a loop (`packed_step`), as a body
+    unrolled over whole blocks holds half again the code, which every
+    process loads at every layer. The accumulator, zeroed at a query
+    block's first step, over a floored sum at its last makes a block
+    that never ran exactly 0.
 
-    With a `window` the grid's last axis runs over the band only: its
-    `nk` steps are the key blocks i - nk + 1 .. i, those before block 0
-    skipped, and a link is live iff 0 <= t - s < window (the edge
-    blocks, and a diagonal block narrower than the window, masked)."""
-    a, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    step_of_i = j
-    if window is not None:
-        j = i - (nk - 1) + step_of_i
+    With a `window` a link is live iff 0 <= t - s < window besides (the
+    edge blocks, and a diagonal block narrower than the window,
+    masked)."""
+    if n_seg:
+        (lens_ref, starts_ref, blocks_ref, q_ref, k_ref, v_ref, o_ref,
+         acc_ref, m_ref, l_ref, qs_ref) = refs
+    else:
+        lens_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    a, i, step_of_i = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    j = i - (nk - 1) + step_of_i
     lb = q_ref.shape[-1]
     g = lb // hd
     chunk = min(_PREFILL_CHUNK, bq)
@@ -598,42 +622,100 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[:] = jnp.full_like(m_ref, _NEG)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    def step(on_diagonal):
-        k, v = k_ref[0], v_ref[0]
+    def update(rows, s, mask, v):
+        """One chunk of stacked query rows through the online softmax
+        against the key block's scores `s`."""
+        p, m_new, l_new, corr = _online_softmax(
+            s, mask, m_ref[rows, 0], l_ref[rows, 0])
+        acc_ref[rows] = acc_ref[rows] * corr[:, None] + _dot(
+            p.astype(v.dtype), v, 1, 0)
+        m_ref[rows] = jnp.broadcast_to(m_new[:, None], (chunk, 128))
+        l_ref[rows] = jnp.broadcast_to(l_new[:, None], (chunk, 128))
+
+    def band(offset, shape):
+        """Links within the window, at a chunk's row `offset` into the
+        query block."""
+        return ((i - j) * bq + offset
+                + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+                - jax.lax.broadcasted_iota(jnp.int32, shape, 1) < window)
+
+    def stacked_queries():
         q = q_ref[0].astype(jnp.float32) * scale
-        qs = jnp.concatenate(
+        return jnp.concatenate(
             [jnp.where(head_of_lane == h, q, 0.0) for h in range(g)],
-            axis=0).astype(k.dtype)                        # [g * bq, lb]
+            axis=0).astype(k_ref.dtype)                    # [g * bq, lb]
+
+    def step(masked):
+        """A one-prompt row: the chunks unrolled, a diagonal chunk
+        taking the keys up to its own last row."""
+        k, v = k_ref[0], v_ref[0]
+        qs = stacked_queries()
         for r in range(0, g * bq, chunk):
             rows = slice(r, r + chunk)
-            # i * bq == j * bk on the diagonal: the offsets cancel
-            keys = r % bq + chunk if on_diagonal else bq
+            # on the diagonal i * bq == j * bk: the offsets cancel
+            keys = r % bq + chunk if masked else bq
             s = _dot(qs[rows], k[:keys], 1, 1)             # [chunk, keys]
             mask = None
-            if on_diagonal:
+            if masked:
                 mask = (r % bq + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 0)
                     >= jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
-            if window is not None and (window < bq or not on_diagonal):
-                band = ((i - j) * bq + r % bq
-                        + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-                        - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                        < window)
-                mask = band if mask is None else mask & band
-            p, m_new, l_new, corr = _online_softmax(
-                s, mask, m_ref[rows, 0], l_ref[rows, 0])
-            acc_ref[rows] = acc_ref[rows] * corr[:, None] + _dot(
-                p.astype(v.dtype), v[:keys], 1, 0)
-            m_ref[rows] = jnp.broadcast_to(m_new[:, None], (chunk, 128))
-            l_ref[rows] = jnp.broadcast_to(l_new[:, None], (chunk, 128))
+            if window is not None and (window < bq or not masked):
+                mask = band(r % bq, s.shape) if mask is None \
+                    else mask & band(r % bq, s.shape)
+            update(rows, s, mask, v[:keys])
+
+    def packed_step(masked):
+        """A packed row: every chunk takes the whole key block, so a
+        loop of one body goes down the query block, over the stacked
+        queries in a scratch, two chunks an iteration (every head's
+        copy of a chunk of rows, with one mask for the copies, or two
+        chunks of rows of one head) so that one chunk's matmul can
+        overlap the other's softmax. Unrolled over whole key blocks the
+        body would be set-up in every process: a prompt's diagonal no
+        longer stops at a chunk's last row."""
+        k, v = k_ref[0], v_ref[0]
+        qs_ref[:] = stacked_queries()
+        bounds = [starts_ref[p] for p in range(n_seg)]
+        per = min(max(1, 2 // g), bq // chunk)
+
+        def rows_at(c, carry):
+            for u in range(per):
+                offset = pl.multiple_of((c * per + u) * chunk, chunk)
+                shape = (chunk, bq)
+                mask = None
+                if masked:
+                    # a query row sees the keys from its prompt's start
+                    # to itself: the first and last live column
+                    at = offset + jax.lax.broadcasted_iota(
+                        jnp.int32, (chunk, 1), 0)
+                    last = (i - j) * bq + at
+                    first = _segment_start(bounds, i * bq + at) - j * bq
+                    if window is not None:
+                        first = jnp.maximum(first, last - (window - 1))
+                    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+                    mask = (col >= first) & (col <= last)
+                elif window is not None:
+                    mask = band(offset, shape)
+                for h in range(g):
+                    rows = pl.ds(h * bq + offset, chunk)
+                    update(rows, _dot(qs_ref[rows], k, 1, 1), mask, v)
+            return carry
+
+        jax.lax.fori_loop(0, bq // chunk // per, rows_at, 0)
 
     live = i * bq < lens_ref[a]
-    pl.when(live & (j == i))(functools.partial(step, True))
+    if n_seg:
+        run = live & (j >= blocks_ref[i])
+        masked = (j == i) | (j < blocks_ref[pl.num_programs(2) + i])
+    else:
+        run = live & (j >= 0)
+        masked = j == i
+    body = packed_step if n_seg else step
+    pl.when(run & masked)(functools.partial(body, True))
     if nk > 1:
-        below = j < i
-        if window is not None:
-            below = below & (j >= 0)
-        pl.when(live & below)(functools.partial(step, False))
+        pl.when(run & jnp.logical_not(masked))(
+            functools.partial(body, False))
 
     @pl.when(step_of_i == nk - 1)
     def _():
@@ -645,25 +727,34 @@ def _prefill_kernel(lens_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "window",
-                                             "interpret"))
+                                             "interpret", "longest"))
 def flash_prefill_attention(q, k, v, lengths, scale, interpret=False,
-                            window=None):
-    """Causal attention of right-padded prompts over their own K/V,
-    the scores never leaving the chip.
+                            window=None, starts=None, longest=None):
+    """Causal attention of right-padded rows over their own K/V, the
+    scores never leaving the chip.
 
     q [A, S, nh, hd], k, v [A, S, n_kv, hd] (n_kv dividing nh: query
     head i reads key-value head i // (nh / n_kv), which needs whole
     128-lane heads where n_kv < nh); lengths [A] int32, each row's true
-    length. With a `window`, query t sees key s iff 0 <= t - s <
-    window, and key blocks wholly outside that band get no grid step.
+    length. A row is one prompt; or, with `starts` [n] int32 (A = 1),
+    the one row packs n prompts end to end, prompt p taking rows
+    starts[p] .. starts[p + 1] - 1 (the last up to the length), the
+    starts ascending and starts[0] = 0, and query t of a prompt sees key
+    s iff s is of the same prompt and s <= t. `longest` (static; None:
+    S) is the most rows a prompt takes: key blocks further back from a
+    query block get no grid step, nor do those that end before the
+    query block's first prompt starts or lie past the length. With a
+    `window`, query t sees key s iff 0 <= t - s < window besides, and
+    key blocks wholly outside that band get no grid step.
     Returns [A, S, nh, hd]
-    in q's dtype: query t < lengths[a] of row a attended keys 0..t,
+    in q's dtype: query t < lengths[a] of row a attended its keys,
     exactly; a query past the length holds something finite that
     nothing may read (0 where its whole block lies past the length).
     Scores, the running max and sum and the accumulator are f32, the
     probabilities rounded to v's dtype for their matmul: what
-    `decoder.masked_attention` does under `causal_mask(S, lengths)`,
-    less its rounding of the scores to the storage dtype.
+    `decoder.masked_attention` does under `causal_mask(S, lengths)` (a
+    packed row: under `decoder.packed_mask`), less its rounding of the
+    scores to the storage dtype.
 
     Rows are read as [A, S, nh * hd], heads side by side, in blocks of
     lcm(hd, 128) lanes (two heads of 64), or the whole row where that
@@ -682,39 +773,54 @@ def flash_prefill_attention(q, k, v, lengths, scale, interpret=False,
             f"flash_prefill_attention: {nh} query heads over "
             f"{k.shape[2]} key-value heads of {hd} lanes: a grouped "
             "read takes whole heads of a multiple of 128 lanes")
+    if starts is not None and a != 1:
+        raise ValueError(
+            f"flash_prefill_attention: `starts` packs one row, not {a}")
     s_p, bq = _pick_block(s, max(128, min(_PREFILL_BLOCK,
                                           _PREFILL_ROWS // g // 128 * 128)))
     nq = s_p // bq
-    # key blocks a query block reads: all up to the diagonal, or under
-    # a window the band's, ceil((window - 1) / bq) below the diagonal
-    nk = nq if window is None else min(nq, -(-(window - 1) // bq) + 1)
+    # key blocks a query block reads: all up to the diagonal, under a
+    # window the band's, ceil((window - 1) / bq) below the diagonal,
+    # and no further back than a prompt reaches
+    nk = nq
+    for reach in (window, longest):
+        if reach is not None:
+            nk = min(nk, -(-(reach - 1) // bq) + 1)
+    lengths = lengths.astype(jnp.int32)
+    prefetch = (lengths,)
+    n_seg = 0
+    if starts is not None:
+        starts = starts.astype(jnp.int32)
+        n_seg = starts.shape[0]
+        # per query block: the key block of its first row's prompt
+        # start, and one past the last key block that a prompt starting
+        # inside the block, or in a key block's later rows, cuts
+        top = jnp.arange(nq, dtype=jnp.int32) * bq
+        bottom = jnp.minimum(top + bq, lengths[0]) - 1
+        prefetch += (starts, jnp.concatenate(
+            [_segment_start(starts, top) // bq,
+             -(-_segment_start(starts, bottom) // bq)]))
 
     def rows(x):
         x = x.reshape(a, s, x.shape[2] * hd)
         return jnp.pad(x, ((0, 0), (0, s_p - s), (0, 0))) if s_p != s else x
 
-    def last_key_block(i, lens, row):
-        """Past it a query block's steps are skipped, so their key
-        windows stay where they are and nothing is fetched for them."""
-        return jnp.where(i * bq < lens[row], i, 0)
+    def kv_block(r, c, i, j, lens, *packed):
+        """The band's block j, clipped to the blocks the query block
+        reads: where a step is skipped its key windows stay where they
+        are and nothing is fetched for it."""
+        first = packed[1][i] if packed else 0
+        return (r, jnp.clip(i - (nk - 1) + j, first,
+                            jnp.where(i * bq < lens[r], i, 0)),
+                c if group == 1 else c // group)
 
-    if window is None:
-        def key_block(i, j, lens, r):
-            return jnp.minimum(j, last_key_block(i, lens, r))
-    else:
-        def key_block(i, j, lens, r):
-            return jnp.clip(i - (nk - 1) + j, 0,
-                            last_key_block(i, lens, r))
-    kv_lanes = (lambda c: c) if group == 1 else (lambda c: c // group)
-    q_spec = pl.BlockSpec((1, bq, lb), lambda r, c, i, j, lens: (r, i, c))
-    kv_spec = pl.BlockSpec(
-        (1, bq, lb), lambda r, c, i, j, lens:
-        (r, key_block(i, j, lens, r), kv_lanes(c)))
+    q_spec = pl.BlockSpec((1, bq, lb), lambda r, c, i, j, *_: (r, i, c))
+    kv_spec = pl.BlockSpec((1, bq, lb), kv_block)
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=float(scale), bq=bq,
-                          nk=nk, hd=hd, window=window),
+                          nk=nk, hd=hd, n_seg=n_seg, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(a, d // lb, nq, nk),
             in_specs=[q_spec, kv_spec, kv_spec],
             out_specs=q_spec,
@@ -722,14 +828,14 @@ def flash_prefill_attention(q, k, v, lengths, scale, interpret=False,
                 pltpu.VMEM((g * bq, lb), jnp.float32),
                 pltpu.VMEM((g * bq, 128), jnp.float32),
                 pltpu.VMEM((g * bq, 128), jnp.float32),
-            ]),
+            ] + ([pltpu.VMEM((g * bq, lb), k.dtype)] if n_seg else [])),
         out_shape=jax.ShapeDtypeStruct((a, s_p, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="flash_prefill_attention",
-    )(lengths.astype(jnp.int32), rows(q), rows(k), rows(v))
+    )(*prefetch, rows(q), rows(k), rows(v))
     return out[:, :s].reshape(a, s, nh, hd)
 
 

@@ -9,9 +9,11 @@ retention layers — the FIFO continuous-batching scheduler, and the
 per-engine compiled programs (programs.py). One ``step()`` is one
 token boundary:
 
-  retire finished -> admit queued (one bucketed prefill for the whole
-  mixed-length admit batch) -> one decode step for every active slot
-  -> sentinel check (executable count must stay == ladder size)
+  retire finished -> admit queued (one prefill for the whole
+  mixed-length admit batch: its prompts packed end to end in one row,
+  or for a retention decoder a bucketed row a prompt) -> one decode
+  step for every active slot -> sentinel check (executable count must
+  stay == ladder size)
 
 The engine is single-threaded and host-driven by design: continuous
 batching NEEDS a host decision point every token (who retires, who
@@ -73,7 +75,8 @@ from .paged_cache import PagedKVCache
 from .state_cache import StateCache
 from .programs import (jit_tp_with_donated_pools,
                        jit_with_donated_pools, make_chunk_fn,
-                       make_decode_fn, make_prefill_fn)
+                       make_decode_fn, make_packed_prefill_fn,
+                       make_prefill_fn)
 from .scheduler import BucketLadder, FifoScheduler, Request
 
 __all__ = ["ServingConfig", "ServingEngine", "build_serving_snapshot",
@@ -363,7 +366,11 @@ class ServingEngine:
         self.vocab_size = int(mcfg.vocab_size)
         pool_dtype = cfg.dtype or "float32"
         pool_sharding = None
-        jit = jit_with_donated_pools
+
+        def jit(fn, n_plain=3):
+            """`n_plain`: the host arrays between the pools and the
+            parameters, which only a tp plan's shard_map reads."""
+            return jit_with_donated_pools(fn)
         if self.tp > 1:
             from jax.sharding import NamedSharding
             from ..distributed.sharding import (SERVING_POOL_SPEC,
@@ -391,23 +398,35 @@ class ServingEngine:
                 dtype=pool_dtype, prefix_sharing=cfg.prefix_sharing,
                 pool_sharding=pool_sharding, tp=self.tp)
         self.ladder = BucketLadder(cfg.prefill_buckets,
-                                   cfg.decode_buckets, cfg.block_size)
+                                   cfg.decode_buckets, cfg.block_size,
+                                   cfg.max_admit)
+        # a softmax decoder's admit batch is prefilled as ONE packed row
+        # of its prompts; a retention decoder's as a row a prompt (each
+        # state row is written as of its own prompt's last token), and
+        # so is the speculative draft's
+        self._packed = not retention
         self.sched = FifoScheduler(cfg.max_slots, cfg.max_admit)
         sampling = (float(cfg.temperature),
                     None if cfg.top_k is None else int(cfg.top_k),
                     None if cfg.top_p is None else float(cfg.top_p))
 
-        def programs(spec, sampling, n_steps):
+        def programs(spec, sampling, n_steps, packed=False):
             """(prefill, decode, decode's row writes a dispatch or None)
             of one model: the target's or the draft's (which a tp plan
             refuses)."""
             decode = make_decode_fn(spec, cfg.block_size, sampling, n_steps)
-            return (jit(make_prefill_fn(spec, cfg.block_size, sampling)),
-                    jit(decode), getattr(decode, "writes_per_dispatch",
-                                         None))
+            if packed:
+                prefill = jit(make_packed_prefill_fn(
+                    spec, cfg.block_size, sampling, self.ladder.prefill[-1]),
+                    n_plain=4)
+            else:
+                prefill = jit(make_prefill_fn(spec, cfg.block_size,
+                                              sampling))
+            return (prefill, jit(decode),
+                    getattr(decode, "writes_per_dispatch", None))
 
         self._prefill, self._decode, writes = programs(
-            spec, sampling, int(cfg.decode_chunk))
+            spec, sampling, int(cfg.decode_chunk), self._packed)
         # state rows a live lane's decode dispatch writes (all layers):
         # the step span's `state_row_writes`
         self._row_writes_per_lane = None if writes is None \
@@ -487,7 +506,8 @@ class ServingEngine:
                 chunk_shapes.add((self.sched.max_admit, s))
             n += 1                       # the COW page-copy program
         else:
-            n += len(self.ladder.prefill)
+            n += len(self.ladder.packed if self._packed
+                     else self.ladder.prefill)
         if self._spec_k:
             for b in self.ladder.decode:
                 chunk_shapes.add((b, self._spec_k + 1))
@@ -564,6 +584,13 @@ class ServingEngine:
                     np.zeros((a,), np.int32), np.ones((a,), np.int32),
                     self.params, key)[0]
             self.cache.warm_copy()
+        elif self._packed:
+            # no prompt: every lane empty, every row padding
+            for w in self.ladder.packed:
+                self.cache.pools = self._prefill(
+                    self.cache.pools, dummy(a), np.zeros((1, w), np.int32),
+                    np.zeros((a,), np.int32), np.zeros((a,), np.int32),
+                    self.params, key)[0]
         else:
             for s in self.ladder.prefill:
                 self.cache.pools = self._prefill(
@@ -648,8 +675,7 @@ class ServingEngine:
             prefill_sig = decode_sig = None
             chunk_sigs: List[Tuple[int, int]] = []
             if batch:
-                shape = (self.sched.max_admit,
-                         self._prefill_batch(batch, pf_key, tr))
+                shape = self._prefill_batch(batch, pf_key, tr)
                 if cfg.prefix_sharing:
                     chunk_sigs.append(shape)
                 else:
@@ -737,6 +763,24 @@ class ServingEngine:
             lens[i] = tail.size
         return ids, lens
 
+    def _lay_out(self, batch):
+        """The admit batch as one packed row: [1, width] ids, and each
+        prompt's start (a multiple of block_size, after the one before)
+        and length; an empty admit lane is a prompt of length 0 after
+        the last."""
+        a, bs = self.sched.max_admit, self.config.block_size
+        starts = np.zeros((a,), np.int32)
+        lens = np.zeros((a,), np.int32)
+        at = 0
+        for i, r in enumerate(batch):
+            starts[i], lens[i] = at, r.prompt_len
+            at += -(-r.prompt_len // bs) * bs
+        starts[len(batch):] = at
+        ids = np.zeros((1, self.ladder.pick_packed(at)), np.int32)
+        for i, r in enumerate(batch):
+            ids[0, starts[i]:starts[i] + lens[i]] = r.ids
+        return ids, starts, lens
+
     def _pack_slots(self, active, b: int):
         """Last token, position and rid of every active slot, padded to
         the decode bucket."""
@@ -752,9 +796,10 @@ class ServingEngine:
         return self._step_no if self.trace_tick is None \
             else self.trace_tick
 
-    def _prefill_batch(self, batch, key, tr) -> int:
-        """Pages for the admit batch, ONE bucketed prefill of it, and
-        each request's first token. Returns the bucket."""
+    def _prefill_batch(self, batch, key, tr) -> Tuple[int, int]:
+        """Pages for the admit batch, ONE prefill of it (a packed row,
+        or a bucketed row a prompt), and each request's first token.
+        Returns the prefill's shape, (rows, width)."""
         cfg = self.config
         a = self.sched.max_admit
         W = cfg.table_width
@@ -793,21 +838,26 @@ class ServingEngine:
         # with junk routed to scratch instead of page-scattered)
         skip = [r.shared_tokens if cfg.prefix_sharing else 0
                 for r in batch]
-        s = self.ladder.pick_prefill(
-            max(r.prompt_len - n for r, n in zip(batch, skip)))
         tr.phase("build", "prefill")
-        ids, lens = self._pack_prompts(batch, s, skip)
         tables = self.cache.table_array(rids, W)
         if cfg.prefix_sharing:
+            s = self.ladder.pick_prefill(
+                max(r.prompt_len - n for r, n in zip(batch, skip)))
+            ids, lens = self._pack_prompts(batch, s, skip)
             starts = np.zeros((a,), np.int32)
             starts[:len(batch)] = skip
-            tok = self._dispatch(tr, "prefill", s, a, self._chunk,
-                                 self.cache, (tables, ids, starts, lens),
-                                 self.params, key, 2)
+            fn, args, take = self._chunk, (tables, ids, starts, lens), 2
+        elif self._packed:
+            ids, starts, lens = self._lay_out(batch)
+            fn, args, take = self._prefill, (tables, ids, starts, lens), 1
         else:
-            tok = self._dispatch(tr, "prefill", s, a, self._prefill,
-                                 self.cache, (tables, ids, lens),
-                                 self.params, key, 1)
+            ids, lens = self._pack_prompts(
+                batch, self.ladder.pick_prefill(
+                    max(r.prompt_len for r in batch)))
+            fn, args, take = self._prefill, (tables, ids, lens), 1
+        rows, s = ids.shape
+        tok = self._dispatch(tr, "prefill", s, rows, fn, self.cache, args,
+                             self.params, key, take)
         now = time.perf_counter()
         tr.phase("accept", "prefill", now)
         for i, r in enumerate(batch):
@@ -834,7 +884,7 @@ class ServingEngine:
                         replica=self.trace_replica, tick=tick)
                 _rt.record_span(r.rid, "prefill",
                                 t_match if r.shared_tokens else t0,
-                                now, bucket=s, width=a,
+                                now, bucket=s, width=rows,
                                 replica=self.trace_replica, tick=tick)
         if _obs._enabled:
             _obs.counter("serving.admitted_total").add(len(batch))
@@ -846,7 +896,7 @@ class ServingEngine:
                         "serving.prefix_shared_pages_total").add(
                         sum(r.shared_tokens // cfg.block_size
                             for r in batch))
-        return s
+        return rows, s
 
     def _decode_chunk(self, active, key, tr) -> int:
         """One chunked decode dispatch for every active slot. Returns

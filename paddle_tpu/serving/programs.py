@@ -1,12 +1,13 @@
-"""The serving engine's three compiled programs: bucketed prefill, the
-decode step and the paged chunk, for whichever decoder the engine
-serves.
+"""The serving engine's three compiled programs: the admission
+prefill, the decode step and the paged chunk, for whichever decoder
+the engine serves.
 
 TVM's lesson (PAPERS.md) dictates the TPU shape: a SMALL, FIXED set of
 pre-compiled executables over static shapes, never a recompile per
 request. The steady-state serving loop is exactly
 
-  n_prefill_buckets   prefill executables   (admit width x bucket len)
+  n_prefill_buckets   prefill executables   (packed row widths, or
+                                             admit width x bucket len)
   n_decode_buckets    decode executables    (slot-count buckets)
 
 (plus the chunk program's shapes where speculation or prefix sharing is
@@ -26,11 +27,16 @@ live pages in place through the block table instead of gathering every
 table whole: chosen by the platform as the flash kernel is, and held to
 the gather and `masked_attention` by tests/test_paged_decode_attention.py
 (f32 within 1e-5, the greedy token the same). The chunk program
-(several queries a slot) keeps the gather. The prefill's is
-`flash_prefill_attention` on a TPU, a flash forward over the prompt's
-own K/V rows in place (no `[A, heads, S, S]` scores, mask or
-probabilities in HBM), and `masked_attention` under the dense causal
-mask elsewhere (tests/test_flash_prefill_attention.py).
+(several queries a slot) keeps the gather. A softmax decoder's
+prefill lays the admit batch's prompts end to end in ONE row
+(`make_packed_prefill_fn`, `_packed_addressing`): the per-token layers
+compute true tokens and no admit lane or bucket padding, and its
+attention is `flash_prefill_attention` on a TPU, a flash forward over
+the row's own K/V in place that skips and masks by the prompts' starts
+(no `[1, heads, T, T]` scores, mask or probabilities in HBM), and
+`masked_attention` under the dense `packed_mask` elsewhere
+(tests/test_flash_prefill_attention.py). The speculative draft's
+prefill keeps a row a prompt (`make_prefill_fn`, `_prefill_addressing`).
 
 A pool is ``[n_blocks, block_size, n_kv_heads * head_dim]`` (the
 key-value heads: as many as the query heads where q, k and v are fused,
@@ -80,7 +86,8 @@ from ..models import decoder
 from ..models.generation import _pick
 from ..observability.anatomy import scope as _scope
 
-__all__ = ["make_decode_fn", "make_prefill_fn", "make_chunk_fn",
+__all__ = ["make_decode_fn", "make_prefill_fn", "make_packed_prefill_fn",
+           "make_chunk_fn",
            "jit_with_donated_pools", "jit_tp_with_donated_pools"]
 
 
@@ -169,13 +176,14 @@ def _decode_addressing(spec, block_size, tables, positions):
 
 
 def _prefill_addressing(spec, block_size, tables, s, prompt_lens):
-    """A whole prompt a row, right-padded to `s`: the queries attend
-    over this call's own K/V, causally — on a TPU through the flash
-    kernel, which keeps the scores on the chip and takes `prompt_lens`
-    only to skip the blocks past a row's end; elsewhere through
-    `masked_attention` under the dense mask, the reference the kernel
-    is tested against — and the K/V rows go page-wise into the first
-    S / block_size pages of each row's table."""
+    """A row a prompt, right-padded to `s` (the speculative draft's
+    prefill; a softmax target's is `_packed_addressing`): the queries
+    attend over this call's own K/V, causally — on a TPU through the
+    flash kernel, which keeps the scores on the chip and takes
+    `prompt_lens` only to skip the blocks past a row's end; elsewhere
+    through `masked_attention` under the dense mask, the reference the
+    kernel is tested against — and the K/V rows go page-wise into the
+    first S / block_size pages of each row's table."""
     from ..ops import pallas_kernels as _pk
     on_tpu = _pk.pallas_available()
     masks = {None: None if on_tpu else decoder.causal_mask(s, prompt_lens)}
@@ -194,6 +202,41 @@ def _prefill_addressing(spec, block_size, tables, s, prompt_lens):
         nblk = s // block_size
         return ctx, _written(pools, tables[:, :nblk],
                              (k.shape[0], nblk, block_size, -1), k, v)
+
+    return attend
+
+
+def _packed_addressing(spec, block_size, pages, starts, end, longest):
+    """One row of prompts laid end to end, each from a page boundary:
+    a query attends over the keys of its own prompt, causally — on a
+    TPU through the flash kernel, which takes the prompts' `starts` to
+    skip and mask by them and the row's true `end` to skip the blocks
+    past it; elsewhere through `masked_attention` under the dense
+    `packed_mask`, the reference the kernel is tested against — and
+    the row's K/V go page-wise, block_size rows a page, into `pages`
+    (a prompt's own table entries, the scratch page 0 past the
+    prompts)."""
+    from ..ops import pallas_kernels as _pk
+    on_tpu = _pk.pallas_available()
+    masks = {}
+
+    def attend(pools, q, k, v, window=None):
+        view = q.shape
+        t = view[0] * view[1]
+        q, k, v = (x.reshape((1, t) + x.shape[2:]) for x in (q, k, v))
+        if on_tpu:
+            ctx = _pk.flash_prefill_attention(
+                q, k, v, end[None], spec.scale, window=window,
+                starts=starts, longest=longest)
+        else:
+            if window not in masks:
+                masks[window] = decoder.packed_mask(t, starts, window)
+            kc = jnp.einsum("bsnh->bnsh", k)
+            vc = jnp.einsum("bsnh->bnsh", v)
+            ctx = decoder.masked_attention(q, kc, vc, masks[window],
+                                           spec.scale)
+        return ctx.reshape(view), _written(
+            pools, pages, (t // block_size, block_size, -1), k, v)
 
     return attend
 
@@ -391,8 +434,12 @@ def _no_load(spec, params):
 
 
 def make_prefill_fn(spec, block_size: int, sampling):
-    """Bucketed admission prefill: the whole admit batch — MIXED true
-    lengths — shares ONE executable per (admit width, bucket len).
+    """Admission prefill a row a prompt: the admit batch — MIXED true
+    lengths — padded to the bucket of its longest prompt, ONE
+    executable per bucket. A retention decoder's prefill (each state
+    row is written as of its own prompt's last token) and the
+    speculative draft's; a softmax target's is the packed row
+    (`make_packed_prefill_fn`).
 
     run(pools, tables, ids, prompt_lens, params, key) -> (pools', tok)
 
@@ -427,6 +474,69 @@ def make_prefill_fn(spec, block_size: int, sampling):
             last = jnp.take_along_axis(x, idx[:, None, None], axis=1)
             tok = _pick(decoder.final_logits(spec, params, last)[:, 0],
                         key, *sampling)
+        return (pools, tok) + tuple(_expert_load(r) for r in out[2:])
+
+    return run
+
+
+def make_packed_prefill_fn(spec, block_size: int, sampling,
+                           longest: int):
+    """The admission prefill of a softmax decoder: the admit batch's
+    prompts laid end to end in ONE row of T tokens, one executable per
+    packed width (BucketLadder.packed).
+
+    run(pools, tables, ids, starts, lens, params, key) -> (pools', tok)
+
+    ids [1, T]: prompt p's tokens at starts[p] .. starts[p] + lens[p] - 1,
+    every start a multiple of block_size (the rows between a prompt's
+    end and the next start, and past the last, are padding); starts and
+    lens [A] int32, ascending, an empty admit lane a prompt of length 0
+    at the end of the last; tables [A, W] each prompt's pages (the
+    scratch page for an empty lane); `longest` the most tokens a prompt
+    holds (the largest bucket). Each prompt's positions restart at 0,
+    its queries attend causally over its own keys only, and its K/V
+    land page-wise in its own pages — position `pos` in page
+    `tables[p, pos // block_size]` at row `pos % block_size`, the rows
+    past its length in its last page junk that the decode step
+    overwrites — and its first generated token is picked from the
+    logits at its own last token. The projections, the MLP (or the
+    experts, which route the row's true tokens only) and the head run
+    over the one row, so no admit lane or bucket padding is computed.
+    """
+
+    def run(pools, tables, ids, starts, lens, params, key):
+        t = ids.shape[1]
+        # the row is computed as [A, T / A]: the per-token layers see
+        # the shape a row-a-prompt prefill has (as [1, T] XLA lays the
+        # stream out column-major and copies it around the head)
+        rows = starts.shape[0] if t % starts.shape[0] == 0 else 1
+        at = jnp.arange(t, dtype=jnp.int32)
+        # each token's prompt, and whether it lies inside that prompt's
+        # pages (the last prompt's reach past its pages is padding)
+        prompt = jnp.sum(at[:, None] >= starts[None, 1:], axis=1)
+        pos = at - starts[prompt]
+        held = pos < lens[prompt]
+        span = pos < -(-lens[prompt] // block_size) * block_size
+        pos = jnp.where(span, pos, 0)
+        pages = jnp.where(
+            span[::block_size],
+            tables[prompt[::block_size],
+                   jnp.minimum(pos[::block_size] // block_size,
+                               tables.shape[1] - 1)], 0)
+        view = (rows, t // rows)
+        pos = pos.reshape(view)
+        with _scope("embed"):
+            x = decoder.embed(params, ids.reshape(view), pos)
+        out = decoder.blocks(
+            spec, params, x, pools,
+            _packed_addressing(spec, block_size, pages, starts,
+                               starts[-1] + lens[-1], longest),
+            pos, held.reshape(view) if spec.mlp == "moe" else None)
+        x, pools = out[:2]
+        with _scope("lm_head"):
+            last = x.reshape(t, -1)[jnp.maximum(starts + lens - 1, 0)]
+            tok = _pick(decoder.final_logits(spec, params, last), key,
+                        *sampling)
         return (pools, tok) + tuple(_expert_load(r) for r in out[2:])
 
     return run

@@ -14,11 +14,13 @@ admission (see paged_cache), so admission control is the single
 backpressure point and a running request can never OOM.
 
 The bucket ladder quantizes dynamic shapes into the fixed executable
-set (PR 3's dynamic-shape bucketing policy applied to serving):
-prompts pad to the smallest prefill bucket that fits the LONGEST
-prompt in the admit batch, decode runs at the smallest slot-count
-bucket covering the active set. Executable count is therefore bounded
-by ladder size, not by the length mix of the traffic.
+set (the dynamic-shape bucketing policy applied to serving): the
+admit batch's prompts, laid end to end, pad to the narrowest packed
+width that holds them (or, a row a prompt, to the smallest prefill
+bucket that fits the LONGEST prompt in the batch), decode runs at the
+smallest slot-count bucket covering the active set. Executable count
+is therefore bounded by ladder size, not by the length mix of the
+traffic.
 
 Tensor parallelism changes NOTHING here — that is a load-bearing
 contract, not an accident. The scheduler's decisions are over
@@ -32,6 +34,7 @@ stream and break the shard_map programs' replicated-operand contract.
 from __future__ import annotations
 
 import itertools
+import math
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -44,6 +47,10 @@ from ..observability import reqtrace as _rt
 __all__ = ["Request", "BucketLadder", "FifoScheduler"]
 
 _rid_counter = itertools.count()
+
+# the packed prefill's widths are whole units of at most this many rows:
+# the prefill attention kernel's query block, so that a width keeps it
+_PACKED_UNIT = 512
 
 
 @dataclass
@@ -100,10 +107,24 @@ class Request:
 
 class BucketLadder:
     """The fixed shape ladder: prefill widths (multiples of
-    block_size, ascending) and decode slot-count buckets."""
+    block_size, ascending), the packed prefill's row widths, and decode
+    slot-count buckets.
+
+    A packed prefill lays the admit batch's prompts end to end in one
+    row, each from a page boundary. Its widths are derived from the
+    buckets and the admit width `max_admit`, and are as many as the
+    buckets: the narrowest holds `max_admit` prompts of the smallest
+    bucket (what the smallest per-row shape computes, so a lone short
+    prompt costs no more than it did), the widest `max_admit` prompts of
+    the largest (every admit batch the buckets take fits), and those
+    between are spaced evenly. Each is rounded up to whole units of
+    the narrowest's largest power-of-two part no wider than 512 rows (the
+    prefill attention's query block; and so to whole pages). GPT-2's
+    4 admits over buckets 128-1,024 give 512, 2,048, 3,072, 4,096;
+    2 admits over 2,048-5,120 give 4,096, 6,144, 8,192, 10,240."""
 
     def __init__(self, prefill: Sequence[int], decode: Sequence[int],
-                 block_size: int):
+                 block_size: int, max_admit: int = 1):
         self.prefill = tuple(sorted(int(b) for b in prefill))
         self.decode = tuple(sorted(int(b) for b in decode))
         if not self.prefill or not self.decode:
@@ -116,6 +137,13 @@ class BucketLadder:
         if any(b < 1 for b in self.decode):
             raise ValueError(f"decode buckets {self.decode} must be "
                              ">= 1")
+        narrow = max_admit * self.prefill[0]
+        wide = max_admit * self.prefill[-1]
+        unit = math.lcm(math.gcd(narrow, _PACKED_UNIT), int(block_size))
+        n = len(self.prefill)
+        self.packed = tuple(sorted({
+            -(-(narrow + (wide - narrow) * i // max(n - 1, 1)) // unit)
+            * unit for i in range(n)}))
 
     def pick_prefill(self, length: int) -> int:
         for b in self.prefill:
@@ -124,6 +152,15 @@ class BucketLadder:
         raise ValueError(
             f"prompt length {length} exceeds the largest prefill "
             f"bucket {self.prefill[-1]}")
+
+    def pick_packed(self, tokens: int) -> int:
+        """The narrowest packed width that holds `tokens` rows."""
+        for w in self.packed:
+            if w >= tokens:
+                return w
+        raise ValueError(
+            f"{tokens} packed rows exceed the widest packed prefill "
+            f"{self.packed[-1]}")
 
     def pick_decode(self, n_active: int) -> int:
         for b in self.decode:

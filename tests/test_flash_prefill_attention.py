@@ -11,8 +11,11 @@ lengths to skip blocks, not to mask); what it writes for pad queries
 must still be finite everywhere, and exactly zero where a whole query
 block lies past the row's end, because it flows through the layers
 above into K/V rows of live pages.
-`tests/test_pallas_mosaic_compile.py` compiles it with the real Mosaic
-for a v5e, alone and inside the prefill program.
+A packed row (the admit batch's prompts laid end to end, the engine's
+prefill for a softmax decoder) is held to the same reference under the
+block-diagonal causal mask of its prompts, and blocks it must skip are
+poisoned with NaN. `tests/test_pallas_mosaic_compile.py` compiles it
+with the real Mosaic for a v5e, alone and inside the prefill program.
 """
 import math
 
@@ -20,7 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.models.decoder import causal_mask, masked_attention
+from paddle_tpu.models.decoder import (causal_mask, masked_attention,
+                                       packed_mask)
 from paddle_tpu.ops import pallas_kernels as pk
 
 ROWS = 5
@@ -127,3 +131,96 @@ def test_a_bucket_of_no_whole_tile_is_padded():
         np.testing.assert_allclose(np.asarray(got)[r, :n],
                                    np.asarray(want)[r, :n],
                                    atol=1e-5, rtol=1e-5)
+
+
+# -- packed rows: prompts laid end to end in one row --------------------------
+
+# (row length, the prompts' lengths in order, most rows a prompt takes):
+# prompts that straddle 512-row blocks; prompts shorter than a chunk of
+# 128 rows (one of a single token); and the padding past the last
+PACKED = {"straddling_512": (2048, (300, 700, 400, 600), 1024),
+          "under_a_chunk": (1024, (100, 20, 1, 900, 3), 1024)}
+
+
+def _packed_reference(q, k, v, starts, scale, window=None):
+    """`masked_attention` under `packed_mask`: the block-diagonal
+    causal mask of the row's prompts."""
+    kc = jnp.einsum("bsnh->bnsh", k)
+    vc = jnp.einsum("bsnh->bnsh", v)
+    return masked_attention(q, kc, vc,
+                            packed_mask(q.shape[1], starts, window), scale)
+
+
+def _packed_case(dtype, s, lens, nh, n_kv, hd, seed=0):
+    rng = np.random.default_rng(seed)
+    mk = lambda n: jnp.asarray(rng.standard_normal((1, s, n, hd)), dtype)
+    starts = np.cumsum((0,) + tuple(lens[:-1])).astype(np.int32)
+    return mk(nh), mk(n_kv), mk(n_kv), starts, int(sum(lens))
+
+
+def _check_packed(got, want, end, dtype):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    assert np.isfinite(got).all()
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(got[0, :end], want[0, :end], atol=tol,
+                               rtol=tol)
+
+
+@pytest.mark.parametrize("case", list(PACKED))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_packed_row_matches_the_block_diagonal_mask(dtype, case):
+    """Every true query of every prompt against `masked_attention`
+    under the mask of the row's prompts: causal, and within its own
+    prompt only."""
+    s, lens, longest = PACKED[case]
+    q, k, v, starts, end = _packed_case(dtype, s, lens, 2, 2, 64)
+    got = pk.flash_prefill_attention(
+        q, k, v, jnp.asarray([end], jnp.int32), 0.125, interpret=True,
+        starts=jnp.asarray(starts), longest=longest)
+    want = _packed_reference(*(a.astype(jnp.float32) for a in (q, k, v)),
+                             jnp.asarray(starts), 0.125)
+    _check_packed(got, want, end, dtype)
+
+
+@pytest.mark.parametrize("window", [100, 700])
+def test_packed_row_with_a_window_and_grouped_heads(window):
+    """A window band on a packed row whose 4 query heads share 2 K/V
+    heads of 128 lanes, f32 within 1e-5."""
+    q, k, v, starts, end = _packed_case(jnp.float32, 2048,
+                                        (513, 900, 600), 4, 2, 128, window)
+    scale = 1.0 / math.sqrt(128)
+    got = pk.flash_prefill_attention(
+        q, k, v, jnp.asarray([end], jnp.int32), scale, interpret=True,
+        window=window, starts=jnp.asarray(starts), longest=1024)
+    want = _packed_reference(q, k, v, jnp.asarray(starts), scale, window)
+    _check_packed(got, want, end, jnp.float32)
+
+
+@pytest.mark.parametrize("window", [None, 300])
+def test_packed_row_reads_no_block_it_skips(window):
+    """Poison: NaN in the K/V of blocks that no step of a query block
+    may read — the key blocks wholly before its first prompt (the first
+    512-row block for queries from row 1,024 on, whose prompt starts
+    at 700) and the blocks wholly past the row's end (q too) — leaves
+    those queries finite and equal to the clean row's; the blocks past
+    the end read exactly 0."""
+    lens, s = (700, 1200), 2560
+    q, k, v, starts, end = _packed_case(jnp.float32, s, lens, 2, 2, 64, 3)
+    kw = dict(window=window, starts=jnp.asarray(starts), longest=1280,
+              interpret=True)
+    clean = pk.flash_prefill_attention(
+        q, k, v, jnp.asarray([end], jnp.int32), 0.125, **kw)
+    dead = -(-end // 512) * 512
+    poison = np.zeros((s,), bool)
+    poison[:512] = poison[dead:] = True
+    nan = lambda x: jnp.where(poison[None, :, None, None], jnp.nan, x)
+    got = np.asarray(pk.flash_prefill_attention(
+        nan(q), nan(k), nan(v), jnp.asarray([end], jnp.int32), 0.125, **kw))
+    assert np.isfinite(got[0, 1024:]).all()
+    np.testing.assert_array_equal(got[0, 1024:end],
+                                  np.asarray(clean)[0, 1024:end])
+    assert not got[0, dead:].any()
+    want = _packed_reference(q, k, v, jnp.asarray(starts), 0.125, window)
+    np.testing.assert_allclose(got[0, 1024:end], np.asarray(want)[0, 1024:end],
+                               atol=1e-5, rtol=1e-5)

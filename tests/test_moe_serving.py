@@ -205,6 +205,24 @@ def test_engine_greedy_tokens_are_the_served_argmax(cfg, weights, model,
     assert [list(t) for t in toks] == [list(g) for g in got]
 
 
+def test_packed_prefill_matches_the_row_prefill(cfg, model, prompts):
+    """The expert block with window layers (window 8, prompts of 5-30
+    tokens laid end to end from page boundaries): the packed row gives
+    each prompt the first token and the K/V rows of its pages that a
+    row a prompt gives; the padding between and after the prompts is
+    routed to no expert."""
+    from test_serving_engine import assert_same_prefill, packed_against_rows
+    assert max(PROMPT_LENS) > cfg["sliding_window"]
+    by_row, by_pack, tables = packed_against_rows(
+        model.decoder_spec(), model.decoder_params(), prompts, BLOCK,
+        WIDTH, 32)
+    assert_same_prefill(by_row, by_pack, tables, PROMPT_LENS, BLOCK)
+    rows = np.asarray(by_pack[2])[0]                 # [layers, experts]
+    np.testing.assert_array_equal(rows, np.asarray(by_row[2])[0])
+    routed = sum(PROMPT_LENS) * cfg["num_experts_per_tok"]
+    assert (rows.sum(axis=1) == routed).all()
+
+
 def test_forward_matches_the_reference(reference, cfg, weights, model,
                                        prompts):
     seq = prompts[2][None]

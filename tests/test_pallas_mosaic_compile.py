@@ -8,6 +8,7 @@ sandbox — what it refuses, the chip refuses (a three-value
 What the on-chip PRNG *produces* is only checked on a chip
 (`PD_TEST_TPU=1 pytest tests/test_pallas_attention.py`).
 """
+import base64
 import dataclasses
 import functools
 import re
@@ -245,11 +246,16 @@ def _compile_decode(n_layers, n_pages, monkeypatch):
                 lowering_platforms=("tpu",)).compile()
 
 
-def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024):
-    """make_prefill_fn (the flash kernel chosen as on a TPU, pools
-    donated) at 4 prompts of `bucket` tokens, the score cell's widest
-    shape by default, compiled for one v5e chip."""
+def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024,
+                     packed=True):
+    """The engine's prefill of GPT-2 (the flash kernel chosen as on a
+    TPU, pools donated) for 4 prompts of up to `bucket` tokens, the
+    score cell's widest shape by default, compiled for one v5e chip:
+    make_packed_prefill_fn's one row of 4 x `bucket` tokens, or
+    (`packed=False`) make_prefill_fn's 4 rows of `bucket`, the
+    speculative draft's."""
     from paddle_tpu.serving.programs import (jit_with_donated_pools,
+                                             make_packed_prefill_fn,
                                              make_prefill_fn)
     one = SingleDeviceSharding(_v5e_devices()[0])
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
@@ -258,11 +264,16 @@ def _compile_prefill(n_layers, n_pages, monkeypatch, bucket=1024):
     admit = 4
     s32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                               sharding=one)
-    fn = jit_with_donated_pools(make_prefill_fn(_SPEC, PAGE, _GREEDY))
+    if packed:
+        fn = jit_with_donated_pools(make_packed_prefill_fn(
+            _SPEC, PAGE, _GREEDY, 1024))
+        plain = (s32(1, admit * bucket), s32(admit), s32(admit))
+    else:
+        fn = jit_with_donated_pools(make_prefill_fn(_SPEC, PAGE, _GREEDY))
+        plain = (s32(admit, bucket), s32(admit))
     with _engine_precision():
-        return fn.trace(pools, s32(admit, TABLE_W), s32(admit, bucket),
-                        s32(admit), params, key).lower(
-                            lowering_platforms=("tpu",)).compile()
+        return fn.trace(pools, s32(admit, TABLE_W), *plain, params,
+                        key).lower(lowering_platforms=("tpu",)).compile()
 
 
 @pytest.mark.parametrize("n_pages", [1024, 2048])
@@ -381,44 +392,130 @@ def test_flash_prefill_kernel_compiles_for_one_v5e_chip(bucket, heads):
     assert "flash_prefill_attention" in text
 
 
+def _mosaic_ops(text):
+    """Operations in the Mosaic bodies of a lowered module's kernel
+    calls (the serialized module each `tpu_custom_call` carries), a
+    count a kernel's set-up grows with."""
+    from jax._src.interpreters import mlir
+    n = 0
+    for body in re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            module = mlir.ir.Module.parse(base64.b64decode(body))
+
+            def walk(op):
+                count = 0
+                for region in op.regions:
+                    for block in region.blocks:
+                        for inner in block.operations:
+                            count += 1 + walk(inner.operation)
+                return count
+            n += walk(module.operation)
+    return n
+
+
+# GPT-2-large's heads, and Mellum2's 32 over 4 K/V heads of 128 on a
+# full and on a window layer
+@pytest.mark.parametrize("heads,kv,hd,window", [
+    (HEADS, HEADS, HEAD, None), (32, 4, 128, None), (32, 4, 128, 1024)],
+    ids=["gpt2_nh20", "mellum_full", "mellum_window"])
+def test_packed_prefill_kernel_is_no_larger_than_the_row_kernel(
+        heads, kv, hd, window):
+    """The set-up guard: a packed row's kernel body (4 x 1,024 tokens
+    in one row, 4 prompt starts) holds at most 1.1 x the Mosaic
+    operations of the row-a-prompt kernel's at 4 x 1,024 and the same
+    heads: the segment's edge is one more comparison in the masked
+    body, the blocks a query block skips or masks come in as scalars,
+    and the chunks of whole key blocks go through a loop."""
+    def lowered(a, s, packed):
+        qd = jax.ShapeDtypeStruct((a, s, heads, hd), jnp.bfloat16)
+        kvd = jax.ShapeDtypeStruct((a, s, kv, hd), jnp.bfloat16)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        if packed:
+            fn = lambda q, k, v, n, st: pk.flash_prefill_attention(
+                q, k, v, n, 0.125, window=window, starts=st, longest=1024)
+            avals = (qd, kvd, kvd, i32(1), i32(4))
+        else:
+            fn = lambda q, k, v, n: pk.flash_prefill_attention(
+                q, k, v, n, 0.125, window=window)
+            avals = (qd, kvd, kvd, i32(4))
+        return jax.jit(fn).trace(*avals).lower(
+            lowering_platforms=("tpu",)).as_text()
+    rows = _mosaic_ops(lowered(4, 1024, False))
+    packed = _mosaic_ops(lowered(1, 4096, True))
+    assert rows > 500
+    assert packed <= 1.1 * rows, (packed, rows)
+
+
+def test_packed_prefill_kernels_carry_no_more_code_than_the_row_kernels():
+    """A kernel's compiled code is loaded, and paid for, in every
+    process and for every layer of every program, compile cache or not
+    (PERF.md, "Findings"). GPT-2's four packed widths (512,
+    2,048, 3,072, 4,096 rows, 512-row blocks) hold no more code in all
+    than its four row-a-prompt buckets (4 x 128 to 4 x 1,024, blocks of
+    128 to 512 rows): with the chunks unrolled over whole key blocks
+    they held 56% more, which set-up paid at every layer."""
+    one = SingleDeviceSharding(_v5e_devices()[0])
+
+    def code(a, s, packed):
+        qkv = jax.ShapeDtypeStruct((a, s, HEADS, HEAD), jnp.bfloat16)
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        if packed:
+            fn = lambda q, k, v, n, st: pk.flash_prefill_attention(
+                q, k, v, n, 0.125, starts=st, longest=1024)
+            avals = (qkv, qkv, qkv, i32(1), i32(4))
+        else:
+            fn = lambda q, k, v, n: pk.flash_prefill_attention(
+                q, k, v, n, 0.125)
+            avals = (qkv, qkv, qkv, i32(4))
+        compiled = _compile(fn, avals, (one,) * len(avals))
+        return compiled.memory_analysis().generated_code_size_in_bytes
+    rows = sum(code(4, b, False) for b in (128, 256, 512, 1024))
+    packed = sum(code(1, w, True) for w in (512, 2048, 3072, 4096))
+    assert packed <= rows, (packed, rows)
+
+
 @pytest.mark.parametrize("bucket", [128, 1024])
 def test_prefill_program_keeps_its_scores_on_the_chip(bucket, monkeypatch,
                                                       capsys):
-    """The whole prefill program (36 layers cut to 2 for time) with
-    the kernel chosen as on a TPU: one Mosaic call a layer and no
-    [4, 20, s, s] tensor of scores, mask or probabilities, nor the
-    heads-first [4, 20, s, 64] copies the dense attention read. The
-    temporaries (175 MB at 2 layers of 4 x 1,024 with the dense
-    attention) are printed, and held under 20 MB."""
-    n_layers = 2
+    """The whole packed prefill program (36 layers cut to 2 for time)
+    with the kernel chosen as on a TPU, one row of 4 x `bucket` tokens:
+    one Mosaic call a layer and no [1, 20, T, T] tensor of scores, mask
+    or probabilities, nor the heads-first [1, 20, T, 64] copies the
+    dense attention read. The temporaries (175 MB at 2 layers of
+    4 x 1,024 with the dense attention) are printed, and held under
+    20 MB."""
+    n_layers, t = 2, 4 * bucket
     compiled = _compile_prefill(n_layers, 1024, monkeypatch, bucket)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == n_layers
     assert "flash_prefill_attention" in text
-    assert f"[4,{HEADS},{bucket},{bucket}]" not in text
-    assert f"[4,{HEADS},{bucket},{HEAD}]" not in text
+    assert f"[1,{HEADS},{t},{t}]" not in text
+    assert f"[1,{HEADS},{t},{HEAD}]" not in text
     mem = compiled.memory_analysis()
     with capsys.disabled():
-        print(f"\nprefill program, {n_layers} layers, 4 x {bucket}: "
+        print(f"\nprefill program, {n_layers} layers, a row of {t}: "
               f"temporaries {mem.temp_size_in_bytes / 1e6:.1f} MB")
     assert mem.temp_size_in_bytes < 20e6
 
 
 def test_tp2_prefill_program_runs_the_kernel_on_local_heads(monkeypatch):
-    """Under tp=2 the prefill body's kernel call takes this chip's 10
-    heads of every row, [4, 128, 640], and no tensor of scores is
-    left; the pools are neither gathered nor converted."""
-    from paddle_tpu.serving.programs import make_prefill_fn
+    """Under tp=2 the packed prefill body's kernel call takes this
+    chip's 10 heads of every row, [1, 512, 640], and no tensor of
+    scores is left; the pools are neither gathered nor converted."""
+    from paddle_tpu.serving.programs import make_packed_prefill_fn
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
-    n_layers, tp, bucket = 2, 2, 128
+    n_layers, tp, t = 2, 2, 512
     text = _tp2(
-        n_layers, lambda spec: make_prefill_fn(spec, PAGE, _GREEDY), 3,
-        lambda s32: (s32(4, TABLE_W), s32(4, bucket), s32(4)))
+        n_layers,
+        lambda spec: make_packed_prefill_fn(spec, PAGE, _GREEDY, 128), 4,
+        lambda s32: (s32(4, TABLE_W), s32(1, t), s32(4), s32(4)))
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     assert len(calls) == n_layers
-    local = f"bf16[4,{bucket},{HEADS // tp * HEAD}]"
+    local = f"bf16[1,{t},{HEADS // tp * HEAD}]"
     assert all(ln.count(local) >= 4 for ln in calls), calls[0][:400]
-    assert f"[4,{HEADS // tp},{bucket},{bucket}]" not in text
+    assert f"[1,{HEADS // tp},{t},{t}]" not in text
     assert "all-gather" not in text
     assert not _pool_copies(text, 1024, HEADS // tp * HEAD)
 
@@ -486,8 +583,9 @@ def _mlp_fusions(comps):
 
 
 # (most non-trivial instructions in fc2's fusion, `copy` instructions
-# of the parent of the change that moved the activation)
-_ACTIVATION_CASES = {"prefill_1024": (16, 1), "prefill_128": (40, 0),
+# of the parent of the change that moved the activation; of the packed
+# prefill's row of 4,096, two small int32 ones of its token addressing)
+_ACTIVATION_CASES = {"prefill_1024": (16, 2), "prefill_128": (40, 0),
                      "decode_32x4": (40, 8)}
 
 
@@ -541,11 +639,14 @@ def test_gelu_is_not_expanded_in_fc2s_operand(program, monkeypatch, capsys):
 
 
 # (copies, Mosaic calls, fusions, temporaries B, generated code B) of
-# GPT-2's four serving programs at 2 layers, as the retention decoder's
-# changes found them: what those changes touch is shared with them
+# GPT-2's serving programs at 2 layers, as the retention decoder's
+# changes found them (the prefill as the packed row found it: a row of
+# 4 x 1,024 or 4 x 128 tokens; `rows_1024` the speculative draft's
+# row-a-prompt prefill): what those changes touch is shared with them
 _GPT2_PROGRAMS = {
-    "prefill_1024": (1, 2, 61, 2_774_016, 9_286_656),
-    "prefill_128": (0, 2, 59, 2_709_504, 5_707_264),
+    "prefill_1024": (2, 2, 76, 3_354_624, 8_993_280),
+    "prefill_128": (0, 2, 71, 3_032_064, 6_159_872),
+    "prefill_rows_1024": (1, 2, 61, 2_774_016, 9_287_168),
     "decode_32x4": (8, 2, 85, 5_677_056, 2_783_744),
     "chunk_32x5": (19, 0, 86, 205_663_232, 8_350_208)}
 
@@ -555,9 +656,10 @@ def test_gpt2_programs_compile_as_before(program, monkeypatch):
     """The program makers and `decoder.block` are shared with the
     retention decoder; a change to them that looks local can re-lay a
     whole program (a scatter's index shape once added a copy of a
-    weight every token-step). GPT-2's prefill (4 x 1,024 and 4 x 128),
-    decode (32 x 4) and chunk (32 x 5) programs compile for v5e to the
-    same copies, Mosaic calls, fusions, temporaries and code size."""
+    weight every token-step). GPT-2's packed prefill (rows of 4 x 1,024
+    and 4 x 128), row-a-prompt prefill (4 x 1,024), decode (32 x 4) and
+    chunk (32 x 5) programs compile for v5e to the same copies, Mosaic
+    calls, fusions, temporaries and code size."""
     n_layers = 2
     if program == "decode_32x4":
         compiled = _compile_decode(n_layers, 1024, monkeypatch)
@@ -576,7 +678,8 @@ def test_gpt2_programs_compile_as_before(program, monkeypatch):
                                     lowering_platforms=("tpu",)).compile()
     else:
         compiled = _compile_prefill(n_layers, 1024, monkeypatch,
-                                    int(program.split("_")[1]))
+                                    int(program.split("_")[-1]),
+                                    packed="rows" not in program)
     text = compiled.as_text()
     ops = [op for instrs in _computations(text).values()
            for _, _, op, _ in instrs]
@@ -802,8 +905,8 @@ def _moe_avals(n_layers, one):
 @pytest.mark.parametrize("program", ["decode_32x4", "prefill_2x2048"])
 def test_moe_programs_compile_for_one_v5e_chip(program, monkeypatch,
                                                capsys):
-    """The expert model's decode (32 lanes x 4 token-steps) and prefill
-    (2 x 2,048) programs at the published widths, a sliding and a full
+    """The expert model's decode (32 lanes x 4 token-steps) and packed
+    prefill (a row of 2 x 2,048) programs at the published widths, a sliding and a full
     layer, the kernels chosen as on a TPU: three Mosaic calls a layer
     (the attention kernel and the two grouped matmuls), the pools of 4
     K/V heads (512 lanes) neither copied nor converted, the expert load
@@ -811,7 +914,7 @@ def test_moe_programs_compile_for_one_v5e_chip(program, monkeypatch,
     (a prefill's 32,768 expert rows: their gate|up in f32 is 235 MB)."""
     from paddle_tpu.serving.programs import (jit_with_donated_pools,
                                              make_decode_fn,
-                                             make_prefill_fn)
+                                             make_packed_prefill_fn)
     n_layers = 2
     one = SingleDeviceSharding(_v5e_devices()[0])
     monkeypatch.setattr(pk, "pallas_available", lambda: True)
@@ -825,9 +928,10 @@ def test_moe_programs_compile_for_one_v5e_chip(program, monkeypatch,
                                                        n_steps=4))
             args = (pools, s32(SLOTS, M_TABLE), s32(SLOTS), s32(SLOTS))
         else:
-            fn = jit_with_donated_pools(make_prefill_fn(spec, PAGE,
-                                                        _GREEDY))
-            args = (pools, s32(2, M_TABLE), s32(2, 2048), s32(2))
+            # the engine's packed row of two prompts of up to 2,048
+            fn = jit_with_donated_pools(make_packed_prefill_fn(
+                spec, PAGE, _GREEDY, 5120))
+            args = (pools, s32(2, M_TABLE), s32(1, 4096), s32(2), s32(2))
         traced = fn.trace(*args, params, key)
         compiled = traced.lower(lowering_platforms=("tpu",)).compile()
     assert traced.out_info[-1].shape == (2, n_layers, M_EXPERTS)

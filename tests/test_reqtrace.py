@@ -373,9 +373,10 @@ def test_engine_spans_and_export_determinism(model):
         assert {"admission", "prefill", "decode"} <= comps
         att = rt.attribute(tl)
         assert abs(att["share_sum"] - 1.0) <= 0.02
-        # prefill bucket quantizes the admit batch's longest prompt
+        # the prefill's width is the packed row's that holds the admit
+        # batch (widths 16 and 32 for buckets 8 and 16, two admits)
         pf = [s for s in tl["spans"] if s["comp"] == "prefill"]
-        assert len(pf) == 1 and pf[0]["bucket"] in (8, 16)
+        assert len(pf) == 1 and pf[0]["bucket"] in (16, 32)
     seqs_b, _ = _run_traced(model, rids)
     assert seqs_a == seqs_b
 
@@ -503,9 +504,10 @@ def test_request_spans_keep_their_fields_and_stamps(model):
         if e["comp"] == "prefill":
             n_prefill += 1
             assert set(e) == base | {"width"}
-            # two to a batch; the bucket is its longest prompt's
+            # two to a batch, packed into one row from page boundaries
+            # (pages of 4): 4 + 8 rows in a width of 16, 8 + 12 in 32
             assert (e["bucket"], e["width"]) == (
-                {"q0": 8, "q1": 8, "q2": 16, "q3": 16}[e["rid"]], 2)
+                {"q0": 16, "q1": 16, "q2": 32, "q3": 32}[e["rid"]], 1)
             assert e["t0"] == phases["alloc"]["t0"]
             assert e["t1"] == phases["sync"]["t1"]
         else:
@@ -630,9 +632,9 @@ def test_explain_tail_unchanged_by_interleaved_step_events():
 
 def test_executables_rises_by_one_on_a_new_bucket_shape(model):
     """`executables` on the step span is the engine's compile count at
-    that boundary: a cold engine's first bucket-8 prefill and its
-    decode make 2, the first bucket-16 prompt one more, and a step that
-    only decodes none."""
+    that boundary: a cold engine's first prefill (a packed row of 16)
+    and its decode make 2, the first admit batch of two prompts that
+    needs a row of 32 one more, and a step that only decodes none."""
     rt.enable()
     eng = ServingEngine(model, engine_config())      # no warmup
     rng = np.random.RandomState(1)
@@ -640,6 +642,7 @@ def test_executables_rises_by_one_on_a_new_bucket_shape(model):
     eng.step()
     eng.step()
     eng.submit(rng.randint(0, 97, (12,)).astype(np.int32), 2, rid="b")
+    eng.submit(rng.randint(0, 97, (5,)).astype(np.int32), 2, rid="c")
     eng.step()
     eng.step()
     counts = [w["executables"] for _, (w, _) in

@@ -618,6 +618,28 @@ def test_step_span_carries_the_live_rows(model, prompts):
     assert max(e["state_rows_live"] for e in steps) == 2
 
 
+def test_a_retention_engine_keeps_the_row_a_prompt_prefill(model, prompts):
+    """A retention decoder's admit batch is prefilled a row a prompt
+    (each state row is written as of its own prompt's last token), not
+    as the packed row of a softmax decoder: its prefill spans are
+    [max_admit, bucket], the bucket its longest prompt's."""
+    from paddle_tpu.observability import reqtrace
+    eng = _engine(model).warmup()
+    assert not eng._packed
+    assert eng._prefill._cache_size() == len(eng.ladder.prefill)
+    reqtrace.enable(True, capacity=4096)
+    try:
+        eng.generate_tokens(prompts[:2], 3)
+        spans = [e for e in reqtrace.get_tracer().events()
+                 if e.get("comp") == "prefill"]
+    finally:
+        reqtrace.disable()
+        reqtrace.reset()
+    longest = max(p.size for p in prompts[:2])
+    assert {(e["width"], e["bucket"]) for e in spans} == {
+        (2, eng.ladder.pick_prefill(longest))}
+
+
 def test_step_span_counts_the_row_writes(model, prompts):
     """A step that decodes writes `state_row_writes` on its span: live
     lanes x layers x writes a dispatch (one: a chunk of 2 is written

@@ -95,21 +95,124 @@ class TestDecodeParity:
         assert engine.sentinel.counter.value() == 0
 
 
+def packed_against_rows(spec, params, prompts, block, width, longest):
+    """The admission prefill twice over the same prompts, f32: one row
+    a prompt padded to `longest` (make_prefill_fn) and one packed row
+    (make_packed_prefill_fn, the prompts from page boundaries). Prompt
+    i holds pages 1 + i * width ... of its own. Returns both programs'
+    outputs ((pools, first tokens) and, for an expert model, the load)
+    and each prompt's page table."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.serving import programs
+    from paddle_tpu.serving.paged_cache import PagedKVCache
+    n = len(prompts)
+    cache = PagedKVCache(len(params["blocks"]), 1 + n * width, block,
+                         spec.n_kv_heads or spec.n_heads, spec.head_dim,
+                         "float32")
+    tables = np.stack([1 + i * width + np.arange(width, dtype=np.int32)
+                       for i in range(n)])
+    lens = np.asarray([p.size for p in prompts], np.int32)
+    rows = np.zeros((n, longest), np.int32)
+    starts = np.zeros((n,), np.int32)
+    at = 0
+    for i, p in enumerate(prompts):
+        rows[i, :p.size] = p
+        starts[i] = at
+        at += -(-p.size // block) * block
+    packed = np.zeros((1, -(-at // 32) * 32), np.int32)
+    for i, p in enumerate(prompts):
+        packed[0, starts[i]:starts[i] + p.size] = p
+    greedy, key = (0.0, None, None), jax.random.key(0)
+    row_fn = jax.jit(programs.make_prefill_fn(spec, block, greedy))
+    packed_fn = jax.jit(programs.make_packed_prefill_fn(
+        spec, block, greedy, longest))
+    pools = jax.tree_util.tree_map(jnp.copy, cache.pools)
+    by_row = row_fn(pools, tables, rows, lens, params, key)
+    by_pack = packed_fn(cache.pools, tables, packed, starts, lens, params,
+                        key)
+    return by_row, by_pack, tables
+
+
+def assert_same_prefill(by_row, by_pack, tables, lens, block):
+    """The same first token for every prompt, and the same K/V row at
+    every true position of every prompt's pages, in every layer."""
+    np.testing.assert_array_equal(np.asarray(by_pack[1]),
+                                  np.asarray(by_row[1]))
+    for layer_row, layer_pack in zip(by_row[0], by_pack[0]):
+        for pool_row, pool_pack in zip(layer_row, layer_pack):
+            for i, n in enumerate(lens):
+                pos = np.arange(n)
+                at = (tables[i, pos // block], pos % block)
+                np.testing.assert_allclose(
+                    np.asarray(pool_pack)[at], np.asarray(pool_row)[at],
+                    atol=2e-5, rtol=2e-5)
+
+
+class TestPackedPrefill:
+    def test_packed_row_matches_the_row_a_prompt_prefill(self, model):
+        """GPT-2's block: prompts of 3, 9, 16 and 1 tokens (one a
+        whole page, one a page and a token) laid end to end from page
+        boundaries give the first tokens and the K/V rows that one row
+        a prompt gives."""
+        from paddle_tpu.models.decoder import DecoderSpec
+        from paddle_tpu.models.generation import _cast_params, _gpt_params
+        rng = np.random.RandomState(21)
+        prompts = [rng.randint(0, 97, (n,)).astype(np.int32)
+                   for n in (3, 9, 16, 1)]
+        spec = DecoderSpec.of(model.gpt.config)
+        params = _cast_params(_gpt_params(model), None)
+        by_row, by_pack, tables = packed_against_rows(
+            spec, params, prompts, 4, 8, 16)
+        assert_same_prefill(by_row, by_pack, tables,
+                            [p.size for p in prompts], 4)
+
+    def test_warmup_builds_as_many_prefills_as_buckets(self, model):
+        """The packed widths are as many as the buckets (here 16 and
+        32 for buckets 8 and 16 with two admits): warmup compiles the
+        same number of prefill executables as a row a prompt did, and
+        the sentinel's budget is unchanged."""
+        eng = ServingEngine(model, f32_config()).warmup()
+        assert eng.ladder.packed == (16, 32)
+        assert eng._prefill._cache_size() == len(eng.ladder.prefill) == 2
+        assert eng.executable_count() == eng.expected_executables == 3
+
+    @pytest.mark.parametrize("buckets,admit,block,want", [
+        ((128, 256, 512, 1024), 4, 16, (512, 2048, 3072, 4096)),
+        ((2048, 3072, 4096, 5120), 2, 16, (4096, 6144, 8192, 10240)),
+        ((16, 32, 64), 2, 8, (32, 96, 128)),
+        ((8,), 1, 4, (8,))])
+    def test_packed_widths_follow_the_buckets(self, buckets, admit, block,
+                                              want):
+        """The narrowest holds max_admit prompts of the smallest
+        bucket, the widest max_admit of the largest, the rest evenly
+        between, each rounded up to whole blocks of up to 512 rows."""
+        lad = BucketLadder(buckets, (4,), block, admit)
+        assert lad.packed == want
+        assert lad.pick_packed(want[0]) == want[0]
+        if len(want) > 1:
+            assert lad.pick_packed(want[0] + 1) == want[1]
+        with pytest.raises(ValueError, match="packed"):
+            lad.pick_packed(want[-1] + 1)
+
+
 class TestBucketedPrefill:
     @pytest.mark.slow  # ~8 s: tier-1 rebalance (PR 17); sibling
     # test_mixed_lengths_share_one_admit_prefill keeps the bucketed
     # ragged-admit contract in tier-1
     def test_five_length_mix_pins_executable_count(self, model):
         """The ragged-prompt batching fix: 5 DISTINCT prompt lengths
-        admit through shared bucketed prefill programs — executable
-        count is the bucket count (2 here), not one per length."""
+        admit through shared packed prefill programs — executable
+        count is the packed widths hit (2 here), not one per length."""
         eng = ServingEngine(model, f32_config())
-        lens = [3, 5, 6, 9, 12]          # -> buckets {8, 16} only
+        # admitted two at a time, 3 + 5, 6 + 9, 12: rows of 12, 20 and
+        # 12 at whole pages of 4 -> packed widths {16, 32} only
+        lens = [3, 5, 6, 9, 12]
         rng = np.random.RandomState(4)
         prompts = [rng.randint(0, 97, (L,)).astype(np.int32)
                    for L in lens]
         outs = eng.generate_tokens(prompts, [4] * 5)
-        assert eng._prefill._cache_size() == 2      # == buckets hit
+        assert eng._prefill._cache_size() == 2      # == widths hit
         assert eng._decode._cache_size() == 1
         assert eng.sentinel.fired == 0
         for p, o in zip(prompts, outs):             # and still exact
@@ -309,9 +412,10 @@ class TestGraphLintDonation:
         assert len(donated) == 2 * 2       # n_layers x (k, v) pools
         findings = run_rules(audit, only=["donation"])
         assert findings == [], [f.message for f in findings]
-        lowered_p = engine._prefill.lower(
+        lowered_p = engine._prefill.lower(          # the packed row
             engine.cache.pools, np.zeros((2, W), np.int32),
-            np.zeros((2, 8), np.int32), np.ones((2,), np.int32),
+            np.zeros((1, engine.ladder.packed[0]), np.int32),
+            np.zeros((2,), np.int32), np.ones((2,), np.int32),
             engine.params, key)
         audit_p = ProgramAudit("serving_prefill", lowered=lowered_p,
                                config=lint_cfg)

@@ -475,6 +475,8 @@ class TestTailTaxonomy:
         rng = np.random.RandomState(19)
         prefix = rng.randint(0, V, (8,)).astype(np.int32)
         rt.enable()
+        rt.reset()      # only this test's two requests: an earlier test
+        #                 in the same process may have left its spans
         try:
             eng.submit(np.concatenate(
                 [prefix, rng.randint(0, V, (2,)).astype(np.int32)]), 4)

@@ -208,10 +208,10 @@ def build_serving(args):
     W = eng.config.table_width
     a, s, b = eng.sched.max_admit, 32, eng.config.max_slots
     key = jax.random.key(0)
-    prefill = eng._prefill.lower(
+    prefill = eng._prefill.lower(          # the admit batch, packed
         eng.cache.pools, np.zeros((a, W), np.int32),
-        np.zeros((a, s), np.int32), np.ones((a,), np.int32),
-        eng.params, key)
+        np.zeros((1, a * s), np.int32), np.zeros((a,), np.int32),
+        np.ones((a,), np.int32), eng.params, key)
     decode = eng._decode.lower(
         eng.cache.pools, np.zeros((b, W), np.int32),
         np.zeros((b,), np.int32), np.zeros((b,), np.int32),
@@ -245,10 +245,10 @@ def build_serving_tp(args):
     W = eng.config.table_width
     a, s, b = eng.sched.max_admit, 32, eng.config.max_slots
     key = jax.random.key(0)
-    prefill = eng._prefill.lower(
+    prefill = eng._prefill.lower(          # the admit batch, packed
         eng.cache.pools, np.zeros((a, W), np.int32),
-        np.zeros((a, s), np.int32), np.ones((a,), np.int32),
-        eng.params, key)
+        np.zeros((1, a * s), np.int32), np.zeros((a,), np.int32),
+        np.ones((a,), np.int32), eng.params, key)
     decode = eng._decode.lower(
         eng.cache.pools, np.zeros((b, W), np.int32),
         np.zeros((b,), np.int32), np.zeros((b,), np.int32),
